@@ -17,19 +17,6 @@ import sys
 from pathlib import Path
 from typing import Optional
 
-# Honor an explicit JAX_PLATFORMS before any backend initializes: some
-# accelerator rigs install a sitecustomize that re-pins JAX to the
-# hardware plugin through the config API (which beats the env var), so a
-# child process asked to run on CPU would instead block on an
-# unavailable accelerator.  The config API also wins for us.
-if os.environ.get("JAX_PLATFORMS"):
-    try:
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    except Exception:  # pragma: no cover - jax not installed / exotic rig
-        pass
-
 
 def _addr_host(addr: str) -> str:
     """Host part of a ``host:port`` address, handling bracketed IPv6
@@ -62,6 +49,11 @@ def _install_excepthook(messenger) -> None:
 async def _run_client(args) -> int:
     from .app import ClientApp
     from .ui import cli as ui_cli
+    from .utils.jaxcache import enable_compilation_cache
+
+    # before the engine is built: its first device programs compile for
+    # minutes cold, and every later start of the client finds them cached
+    enable_compilation_cache()
     from .ui.messenger import Messenger
     from .ui.server import UIServer
     from .store import Store

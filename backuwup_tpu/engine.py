@@ -288,17 +288,11 @@ class Engine:
 
     @staticmethod
     def _default_mesh():
-        """Single-axis mesh over every local device; None off-accelerator."""
-        try:
-            import jax
-            import numpy as _np
-            from jax.sharding import Mesh
-            devices = jax.devices()
-            if not devices:
-                return None
-            return Mesh(_np.array(devices), ("data",))
-        except Exception:
-            return None
+        """Single-axis mesh over every local device."""
+        import jax
+        import numpy as _np
+        from jax.sharding import Mesh
+        return Mesh(_np.array(jax.devices()), ("data",))
 
     # --- paths -------------------------------------------------------------
 

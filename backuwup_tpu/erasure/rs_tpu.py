@@ -41,12 +41,25 @@ def _matmul_batched():
     return jax.jit(jax.vmap(one, in_axes=(None, 0)))
 
 
+def _length_bucket(n: int) -> int:
+    """Shard lengths are padded to a power of two (>= 4 KiB): every
+    packfile has its own length, and an exact-length program would be
+    compiled anew for each one (a served backup on the chip spent its
+    send stage compiling).  The product is column-wise, so zero columns
+    change nothing and are sliced off again."""
+    return max(4096, 1 << (n - 1).bit_length())
+
+
 def gf_matmul_stripes(mat: np.ndarray, stripes: np.ndarray) -> np.ndarray:
     """Device GF(2^8) matmul over a batch of stripes; returns host uint8."""
     mat = np.asarray(mat, dtype=np.uint8)
     stripes = np.asarray(stripes, dtype=np.uint8)
+    ln = stripes.shape[2]
+    pad = _length_bucket(ln) - ln
+    if pad:
+        stripes = np.pad(stripes, ((0, 0), (0, 0), (0, pad)))
     out = _matmul_batched()(jnp.asarray(mat), jnp.asarray(stripes))
-    return np.asarray(jax.device_get(out), dtype=np.uint8)
+    return np.asarray(jax.device_get(out), dtype=np.uint8)[:, :, :ln]
 
 
 def encode_stripes(stripes: np.ndarray, m: int) -> np.ndarray:

@@ -1,16 +1,22 @@
 """ctypes binding for the native single-thread dedup pipeline.
 
-``libbkw_native.so`` (built by the Makefile here) plays the role of the
+The library (built by the Makefile here) plays the role of the
 reference's native `fastcdc` + SIMD `blake3` crates
 (``dir_packer.rs:246-311``): the honest single-thread CPU baseline for the
-device pipeline's throughput target, and a fast host fallback.  The library
-is built on first import when a C compiler is available.
+device pipeline's throughput target, and a fast host fallback.  It is
+built on first use when a C compiler is available, with
+``-march=native``, so the file's name carries a hash of the tracked
+sources and of this host's CPU flags: a library built from other sources
+or for another CPU (a working tree copied to another machine) has
+another name and is never loaded.
 """
 
 from __future__ import annotations
 
 import ctypes
-import logging
+import hashlib
+import os
+import platform
 import subprocess
 from pathlib import Path
 from typing import List, Optional, Tuple
@@ -18,7 +24,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 _DIR = Path(__file__).resolve().parent
-_LIB = _DIR / "libbkw_native.so"
+_SOURCES = ("cdc_blake3.c", "Makefile")
 
 
 class NativeUnavailable(RuntimeError):
@@ -26,38 +32,65 @@ class NativeUnavailable(RuntimeError):
 
 
 _lib: Optional[ctypes.CDLL] = None
+_failed: Optional[NativeUnavailable] = None  # one build attempt per process
 
 
-def _build() -> None:
-    subprocess.run(["make", "-C", str(_DIR), "-s"], check=True,
-                   capture_output=True)
+def _cpu_flags() -> str:
+    """This host's CPU feature line (what ``-march=native`` compiles
+    for); the machine type alone where /proc/cpuinfo is not readable."""
+    try:
+        with open("/proc/cpuinfo") as fh:
+            for line in fh:
+                if line.startswith(("flags", "Features")):
+                    return platform.machine() + line
+    except OSError:
+        pass
+    return platform.machine()
 
 
-def _stale() -> bool:
-    if not _LIB.exists():
-        return True
-    mtime = _LIB.stat().st_mtime
-    return any(src.stat().st_mtime > mtime
-               for src in (_DIR / "cdc_blake3.c", _DIR / "Makefile")
-               if src.exists())
+def _lib_path() -> Path:
+    h = hashlib.sha256()
+    for name in _SOURCES:
+        h.update((_DIR / name).read_bytes())
+    h.update(_cpu_flags().encode())
+    return _DIR / f"libbkw_native-{h.hexdigest()[:16]}.so"
+
+
+def _build(path: Path) -> None:
+    """Build to a private name, then rename: several processes (xdist
+    workers) may find the library missing at once."""
+    tmp = path.with_name(f"{path.stem}.{os.getpid()}.tmp.so")
+    try:
+        subprocess.run(["make", "-C", str(_DIR), "-s", f"LIB={tmp.name}"],
+                       check=True, capture_output=True)
+        os.replace(tmp, path)
+    finally:
+        tmp.unlink(missing_ok=True)
+    for old in _DIR.glob("libbkw_native*.so"):
+        if old != path and ".tmp" not in old.name:
+            old.unlink(missing_ok=True)
 
 
 def load() -> ctypes.CDLL:
-    """Load (building if missing or stale) the native library; raises
-    :class:`NativeUnavailable` when no compiler/library exists."""
-    global _lib
+    """Load (building if missing) the native library for these sources
+    and this CPU; raises :class:`NativeUnavailable` when it cannot be
+    built."""
+    global _lib, _failed
     if _lib is not None:
         return _lib
-    if _stale():
+    if _failed is not None:
+        raise _failed
+    path = _lib_path()
+    if not path.exists():
         try:
-            _build()
+            _build(path)
         except (OSError, subprocess.CalledProcessError) as e:
-            if not _LIB.exists():
-                raise NativeUnavailable(f"cannot build native library: {e}")
-            logging.getLogger(__name__).warning(
-                "native library is stale and rebuild failed (%s); "
-                "loading the outdated binary", e)
-    lib = ctypes.CDLL(str(_LIB))
+            detail = getattr(e, "stderr", b"") or b""
+            _failed = NativeUnavailable(
+                f"cannot build native library: {e} "
+                f"{detail.decode(errors='replace')[-400:]}")
+            raise _failed
+    lib = ctypes.CDLL(str(path))
     u8p = ctypes.POINTER(ctypes.c_uint8)
     u64p = ctypes.POINTER(ctypes.c_uint64)
     lib.bkw_blake3.argtypes = [u8p, ctypes.c_size_t, u8p]

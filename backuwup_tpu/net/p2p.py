@@ -35,7 +35,7 @@ try:
 except ModuleNotFoundError:  # containers without the wheel: aiohttp shim
     from ..utils import ws_compat as websockets
 
-from .. import defaults, wire
+from .. import defaults, native, wire
 from ..crypto import KeyManager, verify_signature
 from ..obs import journal as obs_journal
 from ..obs import metrics as obs_metrics
@@ -43,6 +43,18 @@ from ..obs import trace as obs_trace
 from ..ops.blake3_cpu import blake3_many
 from ..store import Store
 from ..utils import durable, faults, retry
+
+
+def _file_digest(data) -> bytes:
+    """Whole-file BLAKE3 of a transfer, on the host: the C library where
+    it builds (hundreds of MiB/s, GIL released), else the numpy oracle —
+    which manages about 1 MiB/s and, at one digest per side of every file
+    above ``TRANSFER_CHUNK_BYTES``, was the whole wall clock of a served
+    backup (PERF.md, PR 22)."""
+    if native.available():
+        return native.blake3_native(data)
+    return blake3_many([data])[0]
+
 
 _P2P_BYTES = obs_metrics.counter(
     "bkw_p2p_bytes_sent_total",
@@ -143,7 +155,7 @@ def validate_resume_offer(offer: wire.P2PBody, data: bytes, digest: bytes,
         return 0, "cold"
     if bytes(offer.file_digest) != bytes(digest):
         return 0, "restarted_stale"
-    if bytes(offer.prefix_digest) != blake3_many([data[:off]])[0]:
+    if bytes(offer.prefix_digest) != _file_digest(data[:off]):
         return 0, "restarted_corrupt"
     return off, "resumed"
 
@@ -161,6 +173,10 @@ class ConnectionRequests:
         self._pending[bytes(peer_id)] = (nonce, purpose,
                                          time.time() + self.ttl_s)
         return nonce
+
+    def discard(self, peer_id: bytes) -> None:
+        """Forget a request the peer never confirmed."""
+        self._pending.pop(bytes(peer_id), None)
 
     def finalize(self, peer_id: bytes) -> tuple:
         entry = self._pending.pop(bytes(peer_id), None)
@@ -343,7 +359,7 @@ class Transport:
             return
         loop = asyncio.get_running_loop()
         digest = await loop.run_in_executor(
-            None, lambda: blake3_many([data])[0])
+            None, lambda: _file_digest(data))
         start = 0
         if resume:
             start = await self._negotiate_resume(data, file_info, file_id,
@@ -567,7 +583,7 @@ class PartialStore:
             return 0, b"", b""
         if not held:
             return 0, b"", b""
-        return len(held), digest, blake3_many([held])[0]
+        return len(held), digest, _file_digest(held)
 
     def append(self, file_info: wire.FileInfoKind, file_id: bytes,
                offset: int, total: int, digest: bytes,
@@ -603,7 +619,7 @@ class PartialStore:
         if held < total:
             return None
         raw = bin_p.read_bytes()
-        if held > total or blake3_many([raw])[0] != bytes(digest):
+        if held > total or _file_digest(raw) != bytes(digest):
             self.discard(file_id)
             raise P2PError("assembled file digest mismatch;"
                            " partial discarded")
@@ -823,6 +839,9 @@ class P2PNode:
         self.bind_host = bind_host
         self.requests = ConnectionRequests()
         self._finalize_waiters: Dict[bytes, asyncio.Queue] = {}
+        # the rendezvous carries one outstanding request per peer (the
+        # confirmation names the peer, not the request)
+        self._connect_locks: Dict[bytes, asyncio.Lock] = {}
         self.on_transport_request: Optional[Callable] = None
         self.on_restore_request: Optional[Callable] = None
         self.on_restore_fetch_request: Optional[Callable] = None
@@ -848,14 +867,28 @@ class P2PNode:
             # the residential-NAT reconnect lottery: this dial attempt is
             # simply refused; the caller's resume loop retries
             raise P2PError("injected: flaky reconnect refused dial")
-        nonce = self.requests.add(peer_id, purpose)
-        q = self._finalize_waiters.setdefault(peer_id, asyncio.Queue())
-        await self.server.p2p_connection_begin(peer_id, nonce)
-        try:
-            addr = await asyncio.wait_for(q.get(), timeout)
-        except asyncio.TimeoutError:
-            raise P2PError("peer did not confirm p2p connection")
-        nonce, purpose = self.requests.finalize(peer_id)
+        # One request per peer at a time, and no confirmation outlives its
+        # request: a confirmation that arrived after its request timed out
+        # used to stay queued, so the NEXT connect dialled that dead
+        # listener with its own nonce while its own confirmation queued
+        # up in turn — every later dial to the peer failed, the peer
+        # dropped out of placement, and a loaded deployment ended backups
+        # with under-placed stripes (chip_smoke's restore, PR 22).
+        lock = self._connect_locks.setdefault(peer_id, asyncio.Lock())
+        async with lock:
+            q = self._finalize_waiters.setdefault(peer_id, asyncio.Queue())
+            while not q.empty():
+                q.get_nowait()
+            nonce = self.requests.add(peer_id, purpose)
+            try:
+                await self.server.p2p_connection_begin(peer_id, nonce)
+                addr = await asyncio.wait_for(q.get(), timeout)
+            except BaseException as e:
+                self.requests.discard(peer_id)
+                if isinstance(e, asyncio.TimeoutError):
+                    raise P2PError("peer did not confirm p2p connection")
+                raise
+            nonce, purpose = self.requests.finalize(peer_id)
 
         # dial retries (handle_connections.rs:145-165) through the unified
         # retry policy: 3 dials with jittered exponential backoff

@@ -3,8 +3,7 @@
 The performance half of the obs plane (GWP, Ren et al. — PAPERS.md):
 always-on, low-overhead counters wired into the pipeline entry points
 in :mod:`backuwup_tpu.ops.pipeline` / :mod:`backuwup_tpu.ops.backend`,
-plus the chained-execution device timer that used to live duplicated
-across ``scripts/devtime.py`` and the ``probe_*``/``profile_*`` pile.
+plus the chained-execution device timer (:func:`dev_time`).
 
 Dispatch accounting semantics (the hand-countable contract the tests
 pin; one *dispatch* = one device program launch, or its CPU-fallback
@@ -100,6 +99,13 @@ _HBM_HIGH = _metrics.gauge(
     "Peak bytes in flight per device across the mesh driver's dispatch "
     "window (buffers + packed cuts + digest accumulator + dedup lanes)",
     labelnames=("device",))
+
+_MESH_HOST_RERUN = _metrics.counter(
+    "bkw_mesh_host_rerun_rows_total",
+    "Mesh-driver rows the device could not finish and the host re-ran: "
+    "kind=shard (the shard's leaf pool or tier cascade overflowed, its "
+    "rows re-ran host-tiled) or kind=row (the row's candidate capacity "
+    "overflowed, re-chunked on the CPU oracle)", labelnames=("kind",))
 
 # Tiered dedup index families (dedupstore/, docs/dedup_tiering.md): the
 # hot/cold/host probe split, the promotion/demotion clock, and the HBM
@@ -222,6 +228,17 @@ def hbm_high_water(device: int, in_flight_bytes: int) -> None:
         _HBM_HIGH.set(in_flight_bytes, device=dev)
 
 
+def mesh_host_rerun(kind: str, rows: int) -> None:
+    """Count ``rows`` mesh-driver rows re-run on the host path."""
+    if rows:
+        _MESH_HOST_RERUN.inc(rows, kind=kind)
+
+
+def mesh_host_rerun_rows() -> int:
+    """Rows re-run on the host so far, both kinds."""
+    return int(sum(_MESH_HOST_RERUN.value(kind=k) for k in ("shard", "row")))
+
+
 # --- tiered dedup accounting (dedupstore/) -----------------------------------
 
 def tier_probes(path: str, probes: int, hits: int = 0) -> None:
@@ -261,12 +278,11 @@ def tier_cold_commit(kind: str) -> None:
     _TIER_COLD_COMMITS.inc(1, kind=kind)
 
 
-# --- honest device timing (the scripts/devtime.py technique) ----------------
+# --- honest device timing: chained executions, one tiny download ------------
 
 def _sync(out):
-    """Force one tiny device->host download: block_until_ready lies on
-    the dev rig, but a 1-element ``np.asarray`` cannot return before the
-    producing computation finished."""
+    """Force one tiny device->host download: a 1-element ``np.asarray``
+    cannot return before the producing computation finished."""
     import jax
     import numpy as np
 

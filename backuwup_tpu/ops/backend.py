@@ -319,11 +319,10 @@ class TpuBackend(ChunkerBackend):
 
 
 def _accelerator_attached() -> bool:
-    try:
-        import jax
-        return jax.default_backend() not in ("cpu",)
-    except Exception:
-        return False
+    """A backend that fails to initialise raises here: on a machine with
+    a chip that fault must not turn into a quiet host-only run."""
+    import jax
+    return jax.default_backend() != "cpu"
 
 
 def select_backend(prefer: Optional[str] = None,

@@ -37,7 +37,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from ..utils.jax_compat import shard_map
 from .. import defaults
 
 KEY_WORDS = 4  # 128-bit stored fingerprint of the 256-bit blake3 hash
@@ -344,7 +343,7 @@ def _build_probe_fn(mesh: Mesh, axis: str, capacity: int, max_probes: int,
 
     in_specs = [P(axis), P(axis), P(axis)] + ([P(axis)] if insert else [])
     out_specs = (P(axis), P(axis), P(axis), P(axis)) if insert else P(axis)
-    mapped = shard_map(shard_fn, mesh=mesh, in_specs=tuple(in_specs),
+    mapped = jax.shard_map(shard_fn, mesh=mesh, in_specs=tuple(in_specs),
                            out_specs=out_specs)
     if insert:
         return jax.jit(mapped, donate_argnums=(0, 1))
@@ -410,7 +409,7 @@ def _build_migrate_fn(mesh: Mesh, axis: str, old_capacity: int,
             cond, body, (nk, nv, pending0, exhausted0))
         return nk[None], nv[None], exhausted[None]
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis)))

@@ -1,7 +1,7 @@
 """Device-resident dedup pipeline: scan+select -> gather chunks -> digest.
 
 Composes the TPU kernels into the full chunk+hash step that ``bench.py``
-times and ``__graft_entry__.py`` exposes to the driver:
+times and ``chip_smoke.py`` drives through the engine:
 
 1. fused gear-hash scan + on-device FastCDC cut selection of a resident
    byte batch (:func:`..ops.cdc_tpu.scan_select_batch`) — ONE dispatch,
@@ -742,6 +742,7 @@ class DevicePipeline:
                     # adversarial data costs one shard, not the batch)
                     if hb is None:
                         hb = np.asarray(buf)
+                    obs_profile.mesh_host_rerun("shard", max(0, min(r1, B0) - r0))
                     sub = self.manifest_resident_batch(
                         jnp.asarray(hb[r0:r1]), nv[r0:r1])
                     for r in range(r0, min(r1, B0)):
@@ -753,6 +754,7 @@ class DevicePipeline:
                         if strict_overflow:
                             raise RuntimeError(
                                 "candidate overflow in scan+select")
+                        obs_profile.mesh_host_rerun("row", 1)
                         if hb is None:
                             hb = np.asarray(buf)
                         rowb = bytes(hb[r, _HALO:_HALO + int(nv[r])])

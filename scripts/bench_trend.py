@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
 """Per-config trend deltas across the BENCH_*.json capture history.
 
-Every capture (driver rounds ``BENCH_r*.json``, ``tpu_watch.sh``
-recaptures ``BENCH_<kind>_<stamp>.json``) carries the same shape: a
+Every capture (a ``BENCH_*.json`` file holding one ``bench.py`` output
+line) carries the same shape: a
 top-level headline (``metric``/``value``/``vs_baseline``) plus a
 ``configs`` map of per-config numeric evidence.  This script lines the
 captures up in time order and prints, for every config metric, the

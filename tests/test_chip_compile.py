@@ -1,0 +1,174 @@
+"""Ask the v5e's compiler, without the chip (PR 22, bring-up).
+
+The TPU compiler is installed in the sandbox and compiles for a chip
+that is described, not attached (``on-chip-measurement`` guide §2).
+These tests compile the kernels of the served path at the widths the
+engine really dispatches — 1 row x 128 MiB and 128 rows x 1 MiB, the two
+ends of ``pipeline._SCAN_DISPATCH_BYTES`` — with production
+``CDCParams()``, and the whole shard-mapped manifest and the dedup
+probe/insert on a one- and a four-device mesh.  Nothing runs: a pass
+says the chip's compiler accepts the program and how much device memory
+it plans, never that it is right or fast.
+
+What they have caught: every ``pallas_call`` under ``jax.shard_map``
+needs ``vma`` on its ``out_shape``; the scan v2 driver's ``(..., 4)`` u8
+-> u32 bitcast took 5-9 GB of temporaries at 8-64 MiB rows and did not
+fit the chip at 128 MiB.
+
+Only one process may load libtpu, and it keeps it until it exits, so
+the topology is described inside a module-scoped fixture — never at
+import, in a ``skipif`` or in ``parametrize`` — and everything built
+from it is built in fixtures or tests of this one file.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from backuwup_tpu import defaults
+from backuwup_tpu.ops import scan_fused
+from backuwup_tpu.ops.blake3_tpu import _leaf_scan_pallas
+from backuwup_tpu.ops.dedup_index import KEY_WORDS, _build_probe_fn
+from backuwup_tpu.ops.digest_pool import leaf_capacity, pool_digest
+from backuwup_tpu.ops.gear import CDCParams
+from backuwup_tpu.ops.manifest_device import _mesh_scan_digest_fn, tier_plan
+from backuwup_tpu.ops.pipeline import _SCAN_DISPATCH_BYTES, DevicePipeline
+
+GiB = 1 << 30
+PARAMS = CDCParams()  # production 256 KiB / 1 MiB / 3 MiB
+# (rows, row bytes): the two ends of the engine's dispatch budget
+WIDE = (1, _SCAN_DISPATCH_BYTES)
+MANY = (_SCAN_DISPATCH_BYTES >> 20, 1 << 20)
+# smallest manifest bucket a production tree reaches: files just above
+# min_size (smaller ones are one chunk and skip the scan), 8 rows
+SMALLEST = (8, 2 * PARAMS.min_size)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described (not attached) v5e 2x2; the persistent compile cache
+    is off while it is in use — what is compiled for a described chip is
+    written to the cache but cannot be read back without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def meshes(topo):
+    return {n: Mesh(np.array(topo.devices[:n]), ("data",)) for n in (1, 4)}
+
+
+def _caps(padded: int):
+    pipe = DevicePipeline.__new__(DevicePipeline)
+    pipe.params = PARAMS
+    return pipe._caps(padded)
+
+
+def _temp_bytes(lowered) -> int:
+    return lowered.compile().memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("variant", ["v1", "v2"])
+@pytest.mark.parametrize("rows,width", [WIDE, MANY])
+def test_scan_kernel_compiles_at_dispatch_widths(one_chip, variant, rows,
+                                                 width):
+    fn = {"v1": scan_fused._fused_candidate_words_v1,
+          "v2": scan_fused._fused_candidate_words_u32}[variant]
+    lowered = fn.lower(
+        jax.ShapeDtypeStruct((rows, 31 + width), jnp.uint8, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip),
+        mask_s=PARAMS.mask_s, mask_l=PARAMS.mask_l)
+    # the XLA-side strip prep must stay a small multiple of the batch
+    # (128 MiB): v2's bitcast form planned 9 GB here, then 16.5 GB
+    assert _temp_bytes(lowered) < 1 * GiB
+
+
+def test_leaf_digest_kernel_compiles_at_dispatch_width(one_chip):
+    rows, width = WIDE
+    lanes = leaf_capacity(rows * width, rows * _caps(width)[2])
+    lowered = jax.jit(_leaf_scan_pallas).lower(
+        jax.ShapeDtypeStruct((lanes, 16, 16), jnp.uint32, sharding=one_chip),
+        jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((lanes,), jnp.uint32, sharding=one_chip),
+        jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=one_chip))
+    assert _temp_bytes(lowered) < 1 * GiB
+
+
+def test_pool_digest_compiles_at_dispatch_width(one_chip):
+    rows, width = MANY
+    chunks = rows * _caps(width)[2]
+    lowered = pool_digest.lower(
+        jax.ShapeDtypeStruct((rows * (31 + width) + 1024,), jnp.uint8,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((chunks,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((chunks,), jnp.int32, sharding=one_chip),
+        leaf_cap=leaf_capacity(rows * width, chunks),
+        tiers=tier_plan(PARAMS, rows * width, rows), pallas=True)
+    assert _temp_bytes(lowered) < 2 * GiB
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_mesh_manifest_compiles_with_the_pallas_kernels(meshes, monkeypatch,
+                                                        n_dev):
+    """The program the engine's default route runs: scan v2 + select +
+    leaf-pool digest with the Pallas leaf kernel, under ``shard_map``
+    with its varying-axes check on, handing dedup queries on."""
+    monkeypatch.setattr(scan_fused, "_V2_SELECTED", True)  # the TPU default
+    mesh = meshes[n_dev]
+    rows, width = SMALLEST
+    per_shard = rows // n_dev
+    s_cap, l_cap, cut_cap = _caps(width)
+    fn = _mesh_scan_digest_fn(
+        mesh, "data", PARAMS.min_size, PARAMS.desired_size, PARAMS.max_size,
+        PARAMS.mask_s, PARAMS.mask_l, s_cap, l_cap, cut_cap, True,
+        leaf_capacity(per_shard * width, per_shard * cut_cap),
+        tier_plan(PARAMS, per_shard * width, per_shard), True, True)
+    sharded = NamedSharding(mesh, P("data"))
+    compiled = fn.lower(
+        jax.ShapeDtypeStruct((rows, 31 + width), jnp.uint8, sharding=sharded),
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=sharded)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2  # scan + leaf
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 * GiB
+
+
+@pytest.mark.parametrize("insert", [False, True], ids=["probe", "insert"])
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_dedup_program_compiles_at_default_capacity(meshes, n_dev, insert):
+    mesh = meshes[n_dev]
+    rows, width = MANY
+    lanes = (rows // n_dev) * _caps(width)[2]  # one manifest batch's queries
+    cap = defaults.DEDUP_SHARD_CAPACITY
+    fn = _build_probe_fn(mesh, "data", cap, defaults.DEDUP_MAX_PROBES, insert)
+    sharded = NamedSharding(mesh, P("data"))
+    args = [
+        jax.ShapeDtypeStruct((n_dev, cap, KEY_WORDS), jnp.uint32,
+                             sharding=sharded),
+        jax.ShapeDtypeStruct((n_dev, cap), jnp.uint32, sharding=sharded),
+        jax.ShapeDtypeStruct((n_dev, lanes, KEY_WORDS), jnp.uint32,
+                             sharding=sharded)]
+    if insert:
+        args.append(jax.ShapeDtypeStruct((n_dev, lanes), jnp.uint32,
+                                         sharding=sharded))
+    compiled = fn.lower(*args).compile()
+    if n_dev > 1:  # queries ride the interconnect, table rows never move
+        assert "all-gather" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

@@ -14,7 +14,6 @@ trace id, and per-peer estimator rows that survive a client restart.
 """
 
 import asyncio
-import importlib.util
 import json
 from pathlib import Path
 
@@ -142,18 +141,6 @@ def test_report_is_a_delta_and_journals(tmp_path):
     all_padded = reg.get("bkw_pipeline_stage_padded_bytes_total")
     assert eff.value(stage="digest") == pytest.approx(
         all_actual.value(stage="digest") / all_padded.value(stage="digest"))
-
-
-def test_devtime_shim_reexports_the_library_api():
-    """scripts/devtime.py must stay a thin wrapper over obs/profile.py
-    (the runbook's ``from scripts.devtime import dev_time`` contract)."""
-    path = Path(__file__).resolve().parent.parent / "scripts" / "devtime.py"
-    spec = importlib.util.spec_from_file_location("devtime_shim", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert mod.dev_time is profile.dev_time
-    assert mod.dev_time_stage is profile.dev_time_stage
-    assert mod._sync is profile._sync
 
 
 @pytest.mark.profile
