@@ -1,0 +1,10 @@
+"""Tests of the benchmark's own files.  Run by hand from the checkout's
+root, on the CPU: ``JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q``.
+They are not part of the repository's tier-1 tests (``tests/``)."""
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
