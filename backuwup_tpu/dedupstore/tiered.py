@@ -41,6 +41,7 @@ from jax.sharding import Mesh
 
 from .. import defaults
 from ..obs import profile as obs_profile
+from ..obs import trace as obs_trace
 from ..ops.dedup_index import (
     DedupIndexFull,
     ShardedDedupIndex,
@@ -294,6 +295,7 @@ class TieredDedupIndex(MeshDedupIndex):
 
     # --- classify interface --------------------------------------------------
 
+    @obs_trace.traced("index.classify")
     def resolve_hints(self, hashes: List[bytes],
                       raw: List[Optional[bool]]) -> List[bool]:
         """Parent semantics plus the cold fall-through: concrete-False
@@ -367,6 +369,7 @@ class TieredDedupIndex(MeshDedupIndex):
                 flags.append(host_facts[h] if f is None else f)
         return flags
 
+    @obs_trace.traced("index.classify")
     def classify_insert(self, hashes: List[bytes]) -> List[bool]:
         """Parent semantics plus the cold fall-through for device-new
         verdicts (and budget-capped growth via the overridden _grow)."""

@@ -31,7 +31,7 @@ import shutil
 import threading
 import time
 from pathlib import Path
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 from . import defaults, wire
 from .audit import (
@@ -96,6 +96,8 @@ _BUSY_REJECTS = obs_metrics.counter(
     "bkw_engine_busy_rejections_total",
     "Backup/restore/repair attempts rejected while the engine was busy",
     ("op",))
+_PACK_STAGE_SECONDS = obs_metrics.histogram(
+    "bkw_pack_stage_seconds", "", ("stage",))  # declared in packfile.py
 _RECOVERY_RUNS = obs_metrics.counter(
     "bkw_recovery_runs_total", "Startup recovery sweeps run")
 _RECOVERY_ITEMS = obs_metrics.counter(
@@ -150,7 +152,7 @@ def _registry_stage_sums() -> Dict[str, float]:
     out: Dict[str, float] = {}
     pack = reg.get("bkw_pack_stage_seconds")
     if pack is not None:
-        for stage in ("seal", "write", "stall", "chunk_hash"):
+        for stage in ("seal", "write", "stall", "chunk_hash", "paused"):
             out[stage] = pack.sum_value(stage=stage)
     for metric, label in (("bkw_transfer_send_seconds", "send"),
                           ("bkw_transfer_wait_seconds", "send_wait")):
@@ -227,8 +229,13 @@ class Orchestrator:
 
     def block_if_paused(self) -> None:
         """Called from the packer thread between blobs
-        (block_if_paused! macro, backup/mod.rs:241-250)."""
+        (block_if_paused! macro, backup/mod.rs:241-250).  The seconds
+        it waits, and only those, are the pack stage ``paused``."""
+        if self._resume.is_set():
+            return
+        t0 = time.monotonic()
         self._resume.wait()
+        _PACK_STAGE_SECONDS.observe(time.monotonic() - t0, stage="paused")
 
 
 class Engine:
@@ -1135,6 +1142,7 @@ class Engine:
         try:
             await pack_fut
             orch.packing_completed = True
+            packed_t = time.monotonic()
             # wake a send loop parked on the seal event: no more seal
             # commits are coming, the drain check must run now
             orch.notify_packfile()
@@ -1153,7 +1161,8 @@ class Engine:
             await send_task
         except asyncio.CancelledError:
             raise EngineError("send pipeline cancelled")
-        wall_s = time.monotonic() - wall_t0
+        done_t = time.monotonic()
+        wall_s = done_t - wall_t0
         snapshot = snapshot_holder["hash"]
         self.last_pack_stats = snapshot_holder["stats"]
         # per-stage roll-up, derived from the metrics registry (delta vs.
@@ -1168,7 +1177,8 @@ class Engine:
         self.last_overlap = obs_profile.overlap_report(
             {k: stages.get(k, 0.0)
              for k in ("chunk_hash", "seal", "write", "send")},
-            wall_s, mode="phased" if phased else "stream")
+            wall_s, mode="phased" if phased else "stream",
+            drain_s=done_t - packed_t)
         # lineage + manifest commit (one store transaction): parent is
         # the previous retained head, so prune/GC can reason about the
         # chain (docs/lifecycle.md)
@@ -1194,8 +1204,6 @@ class Engine:
             self.messenger.transfer("engine", "summary",
                                     size=orch.bytes_sent, stages=stages,
                                     overlap=self.last_overlap)
-        if tracing.enabled():
-            self._log("trace spans:\n" + tracing.format_report())
         return snapshot
 
     def _pack_progress(self, **kw) -> None:
@@ -1574,7 +1582,6 @@ class Engine:
             return unsent, 0
         k, m = geom
         n = k + m
-        loop = asyncio.get_running_loop()
         leftover = []
         placed_bytes = 0
         for pid, path, size in unsent:
@@ -1596,8 +1603,9 @@ class Engine:
                 continue
             shard_size = rs_stripe.HEADER_LEN + gf_cpu.shard_len(size, k)
             exclude = set(holders.values()) | self._avoid_peers
-            conns = await self._get_stripe_connections(
-                orch, len(missing), exclude, shard_size)
+            with obs_trace.span("send.dial"):
+                conns = await self._get_stripe_connections(
+                    orch, len(missing), exclude, shard_size)
             if len(conns) < len(missing):
                 leftover.append((pid, path, size))
                 continue
@@ -1611,12 +1619,11 @@ class Engine:
                           f" {e}; queued for retry")
                 leftover.append((pid, path, size))
                 continue
-            # GF(2^8) matmul (device or numpy oracle): off the event loop
-            containers = await loop.run_in_executor(
-                None, rs_stripe.split_packfile, data, k, m, self.backend)
-            for i in missing:
-                await self._blocking(
-                    self._save_shard_challenge_table, pid, i, containers[i])
+            # GF(2^8) matmul (device or numpy oracle) and the shards'
+            # audit tables: off the event loop, in one executor call
+            containers = await self._blocking(
+                self._encode_stripe, obs_trace.current_trace_id(), pid,
+                data, k, m, missing)
             pairs = list(zip(missing, conns))
             tasks = [
                 sched.submit(peer_id, len(containers[i]),
@@ -1674,6 +1681,21 @@ class Engine:
         orch.adjust_buffer(-size)
         self._log(f"packfile {bytes(pid).hex()[:8]} placed as "
                   f"{defaults.RS_K}+{defaults.RS_M} stripe")
+
+    def _encode_stripe(self, tid: Optional[str], pid: bytes, data: bytes,
+                       k: int, m: int, missing: List[int]) -> List[bytes]:
+        """Executor-thread half of one packfile's stripe: RS-encode it
+        into k+m shard containers and save the challenge table of every
+        shard still to be placed.  ``tid``: the backup's trace id
+        (contextvars do not cross run_in_executor)."""
+        with obs_trace.bind(tid):
+            with obs_trace.span("send.rs_encode"):
+                containers = rs_stripe.split_packfile(data, k, m,
+                                                      self.backend)
+            with obs_trace.span("send.challenge_tables"):
+                for i in missing:
+                    self._save_shard_challenge_table(pid, i, containers[i])
+        return containers
 
     def _save_shard_challenge_table(self, pid: bytes, index: int,
                                     container: bytes) -> None:

@@ -1,9 +1,11 @@
-"""Device-pipeline profiler: dispatch accounting + honest stage timing.
+"""Device-pipeline profiler: dispatch accounting + the per-backup report.
 
 The performance half of the obs plane (GWP, Ren et al. — PAPERS.md):
 always-on, low-overhead counters wired into the pipeline entry points
 in :mod:`backuwup_tpu.ops.pipeline` / :mod:`backuwup_tpu.ops.backend`,
-plus the chained-execution device timer (:func:`dev_time`).
+the per-function compile seconds a ``jax.monitoring`` listener feeds
+(:func:`jit_compiled`), and the report that folds them and the served
+path's span sums into one backup's delta (:func:`report`).
 
 Dispatch accounting semantics (the hand-countable contract the tests
 pin; one *dispatch* = one device program launch, or its CPU-fallback
@@ -37,16 +39,16 @@ real work — the number PERF.md round-5 item 1 (merging the per-class
 digest dispatches) moves.
 
 Like the rest of ``obs/`` this module is import-light: stdlib +
-defaults only; jax/numpy are imported lazily inside the timing helpers.
+defaults only, neither jax nor numpy.
 """
 
 from __future__ import annotations
 
-import time
 from typing import Dict, Optional
 
 from . import journal as _journal
 from . import metrics as _metrics
+from . import trace as _trace
 
 STAGES = ("scan", "select", "gather", "digest", "index")
 
@@ -66,10 +68,11 @@ _PAD_EFFICIENCY = _metrics.gauge(
     "bkw_pipeline_pad_efficiency",
     "Cumulative actual/padded byte ratio per stage (1.0 = no padding "
     "waste)", labelnames=("stage",))
-_PROFILE_SECONDS = _metrics.histogram(
-    "bkw_profile_stage_seconds",
-    "Honest chained-execution device seconds per profiled stage "
-    "(dev_time_stage)", labelnames=("stage",))
+_JIT_COMPILE = _metrics.histogram(
+    "bkw_jit_compile_seconds",
+    "Backend-compile seconds per jitted function (a persistent-cache "
+    "load reports its retrieval time here too), from the jax.monitoring "
+    "listener the TPU backend registers", labelnames=("fun",))
 
 # Per-device twins of the dispatch/bytes/pad families for the mesh
 # pipeline (shard_map over the row axis).  Additive alongside the
@@ -148,9 +151,28 @@ _TIER_COLD_COMMITS = _metrics.counter(
     "bkw_tier_cold_run_commits_total",
     "Durable cold-tier run commits by kind", labelnames=("kind",))
 
+# What the host is doing inside one streamed file (``stream.file``), by
+# span: preparing bytes for the device, waiting for the device (upload,
+# program and download), or doing the packer's own work (the per-chunk
+# callback, then the file's tree node: what ``chunk_hash`` leaves out).
+# :func:`report` sums the spans of a group into its ``stream`` section.
+STREAM_GROUPS = {
+    "stream.read": "host_prep",
+    "cdc.stage": "host_prep",
+    "cdc.decode": "host_prep",
+    "stream.select_cuts": "host_prep",
+    "stream.slice": "host_prep",
+    "blake3.stage": "host_prep",
+    "cdc.scan": "device_wait",
+    "blake3.digest": "device_wait",
+    "stream.emit": "emit",
+    "stream.tree": "emit",
+}
+
 # Span names whose bkw_span_seconds sums a pipeline report attributes as
-# per-stage wall time (the device pipeline's dispatch/collect pairs plus
-# the packer entry point that drives them).
+# per-stage wall time: the batched route's dispatch/collect pairs and
+# the packer entry point that drives them, the streamed file and its
+# parts, the index classify, and the send stage's steps.
 REPORT_SPANS = (
     "pipeline.scan_select_dispatch",
     "pipeline.cut_collect",
@@ -162,6 +184,12 @@ REPORT_SPANS = (
     "pipeline.mesh_collect",
     "pipeline.h2d_stage",
     "packer.manifest_many",
+    "stream.file",
+    *STREAM_GROUPS,
+    "index.classify",
+    "send.dial",
+    "send.rs_encode",
+    "send.challenge_tables",
 )
 
 # Streaming-dataflow overlap families (the engine's stage graph,
@@ -278,46 +306,25 @@ def tier_cold_commit(kind: str) -> None:
     _TIER_COLD_COMMITS.inc(1, kind=kind)
 
 
-# --- honest device timing: chained executions, one tiny download ------------
+# --- which step recompiled ----------------------------------------------------
 
-def _sync(out):
-    """Force one tiny device->host download: a 1-element ``np.asarray``
-    cannot return before the producing computation finished."""
-    import jax
-    import numpy as np
-
-    leaf = jax.tree_util.tree_leaves(out)[0]
-    return np.asarray(leaf.ravel()[0])
-
-
-def dev_time(fn, *args, n: int = 20) -> float:
-    """Honest per-call device seconds for ``fn(*args)``.
-
-    Times ``n`` chained executions plus ONE tiny download, subtracts the
-    download-only baseline, and averages — dispatch overhead amortises
-    while the sync cost cancels.  Callers must pass already-jitted
-    callables with device-resident args."""
-    out = fn(*args)
-    _sync(out)
-    t0 = time.perf_counter()
-    _sync(out)
-    base = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    for _ in range(n):
-        out = fn(*args)
-    _sync(out)
-    total = time.perf_counter() - t0
-    return max(total - base, 1e-9) / n
+def jit_compiled(fun: str, seconds: float) -> None:
+    """One backend compile of jitted function ``fun``: observed into
+    ``bkw_jit_compile_seconds{fun}`` and journaled with the span that
+    was open on the compiling thread, so an operator reads which step of
+    which backup recompiled."""
+    _JIT_COMPILE.observe(seconds, fun=fun)
+    ctx = _trace.current()
+    _journal.emit(
+        "compile", fun=fun, dur_s=round(seconds, 6),
+        trace_id=ctx.trace_id if ctx is not None else None,
+        span=ctx.name if ctx is not None else None)
 
 
-def dev_time_stage(stage: str, fn, *args, n: int = 20) -> float:
-    """:func:`dev_time` with the registry as sink: observes the result
-    into ``bkw_profile_stage_seconds{stage}`` and journals a ``profile``
-    event so one-off probe runs leave a durable record."""
-    dt = dev_time(fn, *args, n=n)
-    _PROFILE_SECONDS.observe(dt, stage=stage)
-    _journal.emit("profile", stage=stage, dev_s=round(dt, 9), n=n)
-    return dt
+def _compile_values() -> Dict[str, float]:
+    """{fun: summed seconds} of ``bkw_jit_compile_seconds``."""
+    return {s["labels"]["fun"]: s["sum"]
+            for s in _JIT_COMPILE._snapshot_series()}
 
 
 # --- per-backup pipeline report ---------------------------------------------
@@ -332,6 +339,7 @@ def baseline() -> Dict[str, Dict[str, float]]:
     """Snapshot the profiler families so :func:`report` can attribute a
     delta to one backup (the engine's ``_registry_stage_sums`` idiom)."""
     out = {"dispatch": {}, "bytes": {}, "padded": {}, "span_s": {},
+           "compile_s": _compile_values(),
            "dispatch_dev": _device_values(_DISPATCH_DEV),
            "bytes_dev": _device_values(_STAGE_BYTES_DEV),
            "padded_dev": _device_values(_STAGE_PADDED_DEV)}
@@ -369,8 +377,14 @@ def report(base: Optional[dict] = None) -> dict:
         stage: (round(actual[stage] / padded[stage], 6)
                 if padded[stage] > 0 else None)
         for stage in STAGES}
+    span_s = _delta("span_s")
     stage_seconds = {name: round(dt, 6)
-                     for name, dt in _delta("span_s").items() if dt > 0}
+                     for name, dt in span_s.items() if dt > 0}
+    stream = dict.fromkeys(STREAM_GROUPS.values(), 0.0)
+    for name, group in STREAM_GROUPS.items():
+        stream[group] += span_s.get(name, 0.0)
+    compile_s = {fun: round(dt, 6)
+                 for fun, dt in _delta("compile_s").items() if dt > 0}
     # per-device split of the mesh-pipeline launches: {device: {stage: n}}
     # plus per-device pad efficiency, so the report shows whether work
     # divided evenly across the shards (the bench even-split gate)
@@ -395,6 +409,9 @@ def report(base: Optional[dict] = None) -> dict:
         "padded_bytes": padded,
         "pad_efficiency": efficiency,
         "stage_seconds": stage_seconds,
+        "stream": {k: round(v, 6) for k, v in stream.items()},
+        "compile_s": compile_s,
+        "compile_total_s": round(sum(compile_s.values()), 6),
     }
     # tiered-dedup rows: probe/hit split per answering path plus the
     # promotion/demotion clock movement, only when the tier moved at all
@@ -426,7 +443,7 @@ def emit_report(rep: dict, **fields) -> None:
 
 
 def overlap_report(stage_busy: Dict[str, float], wall_s: float,
-                   mode: str = "stream") -> dict:
+                   mode: str = "stream", drain_s: float = 0.0) -> dict:
     """Fold one backup's per-stage busy seconds into the overlap
     families and return the summary row the engine stores + journals.
 
@@ -436,7 +453,9 @@ def overlap_report(stage_busy: Dict[str, float], wall_s: float,
     max(stage)/wall: 1.0 means the end-to-end wall clock collapsed onto
     the slowest stage (perfect overlap); a phased run trends toward
     max/sum.  Concurrent fan-out can legitimately push a stage's summed
-    busy seconds past the wall, so values above 1.0 are kept as-is."""
+    busy seconds past the wall, so values above 1.0 are kept as-is.
+    ``drain_s`` is what the send stage added after the packer was done:
+    from the last blob packed to the last packfile acked."""
     busy = {k: max(float(v), 0.0) for k, v in stage_busy.items()}
     for stage, dt in busy.items():
         if dt > 0:
@@ -447,6 +466,7 @@ def overlap_report(stage_busy: Dict[str, float], wall_s: float,
     rep = {
         "mode": mode,
         "wall_s": round(wall_s, 6),
+        "drain_s": round(drain_s, 6),
         "stage_busy_s": {k: round(v, 6) for k, v in busy.items()},
         "max_stage_s": round(max_stage, 6),
         "overlap_efficiency": round(eff, 6),

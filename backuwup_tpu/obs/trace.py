@@ -1,17 +1,22 @@
 """Hierarchical spans with Dapper-style trace/span ids.
 
-Subsumes :mod:`backuwup_tpu.utils.tracing` (which remains as thin
-wrappers over this module): the flat ``{name: (calls, total_s)}``
-aggregate table and its ``BKW_TRACE`` gate keep their exact semantics,
-while every span now additionally
+:mod:`backuwup_tpu.utils.tracing` re-exports this module.  Every span
 
 * carries a **trace id** (64-bit hex) inherited from the enclosing span
   via a contextvar — ``asyncio.create_task`` copies the context, so the
   send tasks a backup spawns share the backup's trace id for free;
 * observes its duration into the ``bkw_span_seconds{name}`` histogram
-  (always on — the registry is how /metrics sees per-stage times);
+  (always on — the registry is how /metrics sees per-stage times, and
+  its per-name count and sum are the only aggregate there is);
 * journals a ``span`` line (trace id, span id, parent id, duration)
-  when a journal is installed (obs/journal.py).
+  when a journal is installed (obs/journal.py);
+* enters the installed **annotator** (:func:`set_annotator`; the TPU
+  backend installs ``jax.profiler.TraceAnnotation``) around the timed
+  block, so the span also lies on its thread's line of the profiler's
+  host plane, on the same clock as the device's operations.  Only spans
+  opened on a thread that runs no event loop are bridged: there the
+  ``with`` block nests properly, while a span held across an ``await``
+  interleaves with its siblings on the loop's one thread.
 
 Cross-process propagation (the Dapper model, PAPERS.md): the current
 trace id rides as an *optional, unauthenticated* ``trace_id`` field on
@@ -32,8 +37,9 @@ import random
 import re
 import threading
 import time
+from asyncio import _get_running_loop
 from dataclasses import dataclass
-from typing import Dict, Iterator, Optional, Tuple
+from typing import Callable, ContextManager, Iterator, Optional
 
 from . import journal as _journal
 from . import metrics as _metrics
@@ -48,10 +54,12 @@ _TRACE_ID_RE = re.compile(r"^[0-9a-f]{1,32}$")
 @dataclass(frozen=True)
 class SpanContext:
     """What the current task carries: the trace it belongs to and the
-    innermost open span (None right after a cross-process bind)."""
+    innermost open span, id and name (None right after a cross-process
+    bind)."""
 
     trace_id: str
     span_id: Optional[str] = None
+    name: Optional[str] = None
 
 
 _ctx: "contextvars.ContextVar[Optional[SpanContext]]" = \
@@ -125,47 +133,45 @@ def _reset(token) -> None:
         pass
 
 
-# --- the flat aggregate table (exact utils/tracing.py semantics) ------------
+# --- the bridge to the device profiler's clock -------------------------------
 
-_lock = threading.Lock()
-_spans: Dict[str, Tuple[int, float]] = {}
-_enabled = os.environ.get("BKW_TRACE", "0") == "1"
-
-
-def enable(on: bool = True) -> None:
-    global _enabled
-    _enabled = on
+_annotator: Optional[Callable[[str], ContextManager]] = None
+_NO_ANNOTATION = contextlib.nullcontext()
 
 
-def enabled() -> bool:
-    return _enabled
+def set_annotator(factory: Optional[Callable[[str], ContextManager]]) -> None:
+    """Install ``factory(name) -> context manager`` to be entered around
+    every bridged span (None uninstalls).  ``obs/`` imports no jax: the
+    TPU backend hands ``jax.profiler.TraceAnnotation`` in, which outside
+    a capture costs one flag test."""
+    global _annotator
+    _annotator = factory
 
 
 @contextlib.contextmanager
 def span(name: str) -> Iterator[SpanContext]:
     """One named span: times the block, propagates the trace id to
     everything started inside it, feeds the ``bkw_span_seconds``
-    histogram, journals the close, and (only when ``BKW_TRACE``/
-    :func:`enable` is on) accumulates into the flat report table."""
+    histogram, journals the close, and lies inside the annotator's
+    block where one is installed and no event loop runs on this
+    thread."""
     parent = _ctx.get()
     trace_id = parent.trace_id if parent is not None else new_trace_id()
-    ctx = SpanContext(trace_id=trace_id, span_id=new_span_id())
+    ctx = SpanContext(trace_id=trace_id, span_id=new_span_id(), name=name)
     token = _ctx.set(ctx)
-    t0 = time.perf_counter()
-    try:
-        yield ctx
-    finally:
-        dt = time.perf_counter() - t0
-        _reset(token)
-        if _enabled:
-            with _lock:
-                calls, total = _spans.get(name, (0, 0.0))
-                _spans[name] = (calls + 1, total + dt)
-        _SPAN_SECONDS.observe(dt, name=name)
-        _journal.emit(
-            "span", name=name, trace_id=trace_id, span_id=ctx.span_id,
-            parent_id=(parent.span_id if parent is not None else None),
-            dur_s=round(dt, 6))
+    bridged = _annotator is not None and _get_running_loop() is None
+    with _annotator(name) if bridged else _NO_ANNOTATION:
+        t0 = time.perf_counter()
+        try:
+            yield ctx
+        finally:
+            dt = time.perf_counter() - t0
+            _reset(token)
+            _SPAN_SECONDS.observe(dt, name=name)
+            _journal.emit(
+                "span", name=name, trace_id=trace_id, span_id=ctx.span_id,
+                parent_id=(parent.span_id if parent is not None else None),
+                dur_s=round(dt, 6))
 
 
 def traced(name: str = None):
@@ -182,27 +188,6 @@ def traced(name: str = None):
         return wrapper
 
     return deco
-
-
-def report() -> Dict[str, Tuple[int, float]]:
-    with _lock:
-        return dict(_spans)
-
-
-def reset() -> None:
-    with _lock:
-        _spans.clear()
-
-
-def format_report() -> str:
-    rows = sorted(report().items(), key=lambda kv: -kv[1][1])
-    if not rows:
-        return "no spans recorded (BKW_TRACE=1 to enable)"
-    width = max(len(k) for k, _ in rows)
-    out = []
-    for name, (calls, total) in rows:
-        out.append(f"{name:<{width}}  {calls:>6}x  {total * 1e3:>10.1f} ms")
-    return "\n".join(out)
 
 
 @contextlib.contextmanager

@@ -24,6 +24,7 @@ import numpy as np
 
 from ..erasure import gf_cpu
 from ..obs import profile as obs_profile
+from ..obs import trace as obs_trace
 from .blake3_cpu import blake3_many
 from .blake3_tpu import blake3_many_tpu
 from .cdc_cpu import chunk_stream as chunk_stream_cpu
@@ -132,17 +133,28 @@ class ChunkerBackend:
         CDC cut depends only on bytes up to the cut: chunking a prefix gives
         final chunks except the last (whose end might be EOF-forced), which
         is carried into the next segment.  Bit-identical to chunking the
-        whole stream at once.  ``emit(ref, chunk_bytes)`` fires per final
-        chunk as soon as it is fingerprinted (lets the caller pack blobs
-        incrementally); the returned list is the full manifest.
+        whole stream at once.  ``emit(ref, chunk)`` fires per final chunk
+        as soon as it is fingerprinted (lets the caller pack blobs
+        incrementally); the returned list is the full manifest.  ``chunk``
+        is a read-only view into the segment's buffer, not a copy: slicing
+        a segment into ~3,600 ``bytes`` cost 0.05 or 0.8 s a backup with
+        the allocator's mood (PERF.md section 6, PR 25), and most chunks
+        are duplicates whose bytes nobody reads.  A caller that keeps a
+        chunk past the call copies it (``bytes(chunk)``).
+
+        One span per step per segment, never one per chunk (``stream.*``
+        here; the TPU backend's ``chunk`` and ``_stream_digest`` add
+        ``cdc.*`` and ``blake3.*``): obs/profile.py ``STREAM_GROUPS``
+        folds them into host preparation, device wait and emit.
         """
         out: List[ChunkRef] = []
         carry = b""
         base = 0  # absolute offset of carry[0]
         while True:
-            segment = read(segment_bytes)
-            eof = not segment
-            buf = carry + segment
+            with obs_trace.span("stream.read"):
+                segment = read(segment_bytes)
+                eof = not segment
+                buf = carry + segment
             chunks = self.chunk(buf)
             obs_profile.dispatch("scan", actual_bytes=len(buf),
                                  padded_bytes=len(buf))
@@ -157,23 +169,31 @@ class ChunkerBackend:
             else:
                 # single chunk that may still grow: carry everything
                 final, carry, next_base = [], buf, base
-            pieces = [buf[off:off + ln] for off, ln in final]
+            with obs_trace.span("stream.slice"):
+                view = memoryview(buf)
+                pieces = [view[off:off + ln] for off, ln in final]
             if pieces:
                 total = sum(len(p) for p in pieces)
                 obs_profile.dispatch("gather", actual_bytes=total,
                                      padded_bytes=total)
                 obs_profile.dispatch("digest", actual_bytes=total,
                                      padded_bytes=total)
-            for h, (off, ln), data in zip(self.digest_many(pieces), final,
-                                          pieces):
-                ref = ChunkRef(offset=base + off, length=ln, hash=h)
-                out.append(ref)
-                if emit is not None:
-                    emit(ref, data)
+            digests = self._stream_digest(pieces)
+            with obs_trace.span("stream.emit"):
+                for h, (off, ln), data in zip(digests, final, pieces):
+                    ref = ChunkRef(offset=base + off, length=ln, hash=h)
+                    out.append(ref)
+                    if emit is not None:
+                        emit(ref, data)
             base = next_base
             if eof:
                 break
         return out
+
+    def _stream_digest(self, pieces: Sequence[bytes]) -> List[bytes]:
+        """``manifest_stream``'s digest step (``digest_many`` unless a
+        backend times it apart from its other callers)."""
+        return self.digest_many(pieces)
 
 
 class CpuBackend(ChunkerBackend):
@@ -238,6 +258,7 @@ class TpuBackend(ChunkerBackend):
     name = "tpu"
 
     def __init__(self, params: Optional[CDCParams] = None):
+        _install_jax_hooks()
         self.params = params or CDCParams()
         self._scanner = TpuCdcScanner(self.params)
         self._pipeline = None
@@ -270,6 +291,12 @@ class TpuBackend(ChunkerBackend):
 
     def digest_many(self, datas):
         return blake3_many_tpu(datas)
+
+    def _stream_digest(self, pieces):
+        # digest_many also serves the send stage's threads (shard
+        # payloads, challenge tables): only the stream route's batches
+        # enter blake3.stage / blake3.digest, so their sums stay its own
+        return blake3_many_tpu(pieces, timed=True)
 
     def encode_shards(self, stripes, m):
         from ..erasure import rs_tpu
@@ -316,6 +343,33 @@ class TpuBackend(ChunkerBackend):
                 hashes.append(ref.hash)
                 raw.append(None if fl is None else bool(fl[k]))
         return out, dedup.resolve_hints(hashes, raw)
+
+
+_jax_hooks_installed = False
+
+
+def _install_jax_hooks() -> None:
+    """Once per process, when the first TPU backend is made: spans enter
+    ``jax.profiler.TraceAnnotation`` (obs/trace.py; the host half of a
+    profiler capture then carries them on the device trace's clock), and
+    every backend compile is counted under its function's name
+    (``bkw_jit_compile_seconds``, obs/profile.py)."""
+    global _jax_hooks_installed
+    if _jax_hooks_installed:
+        return
+    _jax_hooks_installed = True
+    import jax
+    import jax.monitoring
+
+    def on_duration(event: str, secs: float, **kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            fun = str(kw.get("fun_name", "?"))
+            if fun.startswith("jit(") and fun.endswith(")"):
+                fun = fun[4:-1]
+            obs_profile.jit_compiled(fun, secs)
+
+    obs_trace.set_annotator(jax.profiler.TraceAnnotation)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
 
 
 def _accelerator_attached() -> bool:

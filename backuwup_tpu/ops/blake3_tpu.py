@@ -26,6 +26,7 @@ waste and XLA recompiles.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -33,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import defaults
+from ..obs import trace as obs_trace
 from .blake3_cpu import (
     BLOCK_LEN,
     CHUNK_END,
@@ -178,8 +180,9 @@ def digest_padded(buf: jnp.ndarray, lens: jnp.ndarray, *, L: int,
     zeros = jnp.zeros(lanes, dtype=jnp.uint32)
 
     if pallas:
-        cv_mat, cvp_mat = _leaf_scan_pallas(words_flat, nb, lbl, counter_lo,
-                                            interpret=pallas_interpret)
+        with jax.named_scope("blake3_leaf_scan"):
+            cv_mat, cvp_mat = _leaf_scan_pallas(
+                words_flat, nb, lbl, counter_lo, interpret=pallas_interpret)
         leaf_cv = [cv_mat[:, i].reshape(B, L) for i in range(8)]
         # single-chunk ROOT recompute from the penultimate CV + the last
         # block of chunk 0, rebuilt here (B lanes — negligible)
@@ -225,8 +228,10 @@ def digest_padded(buf: jnp.ndarray, lens: jnp.ndarray, *, L: int,
         init = _vary_like(
             (iv_cols, list(iv_cols), [zeros] * 16, zeros, zeros),
             nb, lbl, counter_lo)
-        cv, cv_last_in, m_last, blen_last, flags_last = jax.lax.fori_loop(
-            0, MAX_LEAVES_PER_CHUNK, leaf_body, init)
+        # the scope names the loop's operations in a device trace
+        with jax.named_scope("blake3_leaf_scan"):
+            cv, cv_last_in, m_last, blen_last, flags_last = \
+                jax.lax.fori_loop(0, MAX_LEAVES_PER_CHUNK, leaf_body, init)
         leaf_cv = [c.reshape(B, L) for c in cv]
 
         # single-chunk roots: recompress chunk 0's final block, ROOT set
@@ -244,6 +249,7 @@ def digest_padded(buf: jnp.ndarray, lens: jnp.ndarray, *, L: int,
     return tree_reduce_cvs(leaf_cv, n_chunks, root_cv)
 
 
+@jax.named_scope("blake3_tree_reduce")
 def tree_reduce_cvs(leaf_cv, counts, root_cv):
     """BLAKE3 tree reduction over per-input leaf chaining values.
 
@@ -440,6 +446,7 @@ def _leaf_scan_pallas(words: jnp.ndarray, n_blocks: jnp.ndarray,
             (g, 8 * _LROWS, 128), jnp.uint32,
             vma=_vma_of(nb, lbl, cidx, wt))] * 2,
         interpret=interpret,
+        name="blake3_leaf_scan",
     )(nb, lbl, cidx, wt)
     # (g, 8 words, R, 128) -> (lanes, 8)
     def unpack(x):
@@ -471,30 +478,42 @@ def _batch_bucket(n: int) -> int:
     return b
 
 
-def bucketed_batches(datas):
-    """Group inputs by leaf bucket; yields (indices, buf, lens, L)."""
+def _span(name: str, timed: bool):
+    return obs_trace.span(name) if timed else contextlib.nullcontext()
+
+
+def bucketed_batches(datas, timed: bool = False):
+    """Group inputs by leaf bucket; yields (indices, buf, lens, L).
+    ``timed``: each batch's padded buffer is built inside a
+    ``blake3.stage`` span."""
     groups = {}
     for i, d in enumerate(datas):
         groups.setdefault(_leaf_bucket(len(d)), []).append(i)
     for L, idxs in sorted(groups.items()):
-        B = _batch_bucket(len(idxs))
-        buf = np.zeros((B, L * CHUNK_LEN), dtype=np.uint8)
-        lens = np.zeros(B, dtype=np.int32)
-        for row, i in enumerate(idxs):
-            d = datas[i]
-            buf[row, :len(d)] = np.frombuffer(bytes(d), dtype=np.uint8)
-            lens[row] = len(d)
+        with _span("blake3.stage", timed):
+            B = _batch_bucket(len(idxs))
+            buf = np.zeros((B, L * CHUNK_LEN), dtype=np.uint8)
+            lens = np.zeros(B, dtype=np.int32)
+            for row, i in enumerate(idxs):
+                d = datas[i]
+                buf[row, :len(d)] = np.frombuffer(d, dtype=np.uint8)
+                lens[row] = len(d)
         yield idxs, buf, lens, L
 
 
-def blake3_many_tpu(datas) -> list:
+def blake3_many_tpu(datas, timed: bool = False) -> list:
     """Batched digests on the device; bit-exact vs
-    :func:`backuwup_tpu.ops.blake3_cpu.blake3_hash`."""
+    :func:`backuwup_tpu.ops.blake3_cpu.blake3_hash`.  ``timed``: one
+    ``blake3.stage`` (host padding) and one ``blake3.digest`` (upload,
+    program, download) span per batch, for the caller whose time they
+    are to explain (the stream route; the others lie inside spans of
+    their own)."""
     datas = list(datas)
     out = [None] * len(datas)
-    for idxs, buf, lens, L in bucketed_batches(datas):
-        root = np.asarray(digest_padded(jnp.asarray(buf), jnp.asarray(lens),
-                                        L=L))
+    for idxs, buf, lens, L in bucketed_batches(datas, timed):
+        with _span("blake3.digest", timed):
+            root = np.asarray(digest_padded(
+                jnp.asarray(buf), jnp.asarray(lens), L=L))
         digests = _root_cv_to_digests(root)
         for row, i in enumerate(idxs):
             out[i] = digests[row]

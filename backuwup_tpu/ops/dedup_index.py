@@ -343,6 +343,9 @@ def _build_probe_fn(mesh: Mesh, axis: str, capacity: int, max_probes: int,
 
     in_specs = [P(axis), P(axis), P(axis)] + ([P(axis)] if insert else [])
     out_specs = (P(axis), P(axis), P(axis), P(axis)) if insert else P(axis)
+    # the function's name is the program's name in a device trace and in
+    # bkw_jit_compile_seconds{fun}
+    shard_fn.__name__ = "dedup_insert" if insert else "dedup_probe"
     mapped = jax.shard_map(shard_fn, mesh=mesh, in_specs=tuple(in_specs),
                            out_specs=out_specs)
     if insert:
@@ -409,6 +412,7 @@ def _build_migrate_fn(mesh: Mesh, axis: str, old_capacity: int,
             cond, body, (nk, nv, pending0, exhausted0))
         return nk[None], nv[None], exhausted[None]
 
+    shard_fn.__name__ = "dedup_migrate"
     mapped = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis)),

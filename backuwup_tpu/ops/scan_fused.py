@@ -193,6 +193,7 @@ def _fused_candidate_words_u32(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
         out_shape=[_words_shape(B, S, body, nv)] * 2,
         grid_spec=grid_spec,
         interpret=interpret,
+        name="cdc_scan_fused_v2",
     )(nv, halo0, body, body)
     wl = wl.transpose(0, 2, 1).reshape(B, P // 32)
     ws = ws.transpose(0, 2, 1).reshape(B, P // 32)
@@ -308,6 +309,7 @@ def _fused_candidate_words_v1(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
         kernel,
         out_shape=[_words_shape(B, S, body, nv)] * 2,
         grid_spec=grid_spec,
+        name="cdc_scan_fused_v1",
     )(nv, halo0, body, body)
     # strip-major -> position-major: word (w, l) covers positions
     # l*S + w*32 ..+31, so transposing to (l, w) and flattening yields

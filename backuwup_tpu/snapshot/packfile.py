@@ -64,7 +64,8 @@ _STAGE_SECONDS = obs_metrics.histogram(
     "bkw_pack_stage_seconds",
     "Packfile pipeline stage times (seal=zstd+AES-GCM per blob,"
     " write=assemble+fsync per packfile, stall=packer blocked on the"
-    " double buffer, chunk_hash=CDC+fingerprint per stream)",
+    " double buffer, chunk_hash=CDC+fingerprint per stream without the"
+    " per-chunk emit, paused=packer parked behind the send buffer)",
     ("stage",))
 
 

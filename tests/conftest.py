@@ -82,10 +82,6 @@ def pytest_configure(config):
         " 3-node permakill swarm are tier-1, the soak and the kill-9"
         " promote e2e are also marked slow")
     config.addinivalue_line(
-        "markers", "profile: timing-sensitive profiling tests"
-        " (obs/profile.py dev timer); excluded from tier-1 like accel —"
-        " set BKW_PROFILE_TESTS=1 to run them")
-    config.addinivalue_line(
         "markers", "dataflow: streaming backup dataflow tests (bounded"
         " inter-stage queues, backpressure, event-driven seal->send"
         " wakeup, phased-vs-stream parity, docs/dataflow.md); all"
@@ -105,13 +101,6 @@ def pytest_collection_modifyitems(config, items):
     """Device-only tests (``@pytest.mark.accel``) skip on the CPU host
     platform instead of failing — mirroring the runtime-probe skip the
     blake3 device tests use, but declaratively."""
-    if os.environ.get("BKW_PROFILE_TESTS", "") != "1":
-        skip_profile = pytest.mark.skip(
-            reason="profile-marked timing test (BKW_PROFILE_TESTS=1 to"
-            " run)")
-        for item in items:
-            if item.get_closest_marker("profile"):
-                item.add_marker(skip_profile)
     import jax
     if jax.default_backend() != "cpu":
         return

@@ -232,3 +232,21 @@ def test_phased_and_stream_snapshots_identical(tmp_path, loop, monkeypatch):
 
     snap_p, snap_s = loop.run_until_complete(asyncio.wait_for(run(), 300))
     assert snap_p == snap_s
+
+
+def test_overlap_reports_the_drain_inside_the_wall(tmp_path, loop):
+    """``drain_s``: from the packer's last blob to the last ack; the
+    pack stage ``paused`` rides in the summary's stage sums."""
+    src = tmp_path / "src"
+    src.mkdir()
+    _corpus(src, files=8)
+
+    async def run():
+        async with _universe(tmp_path, src, "drain") as a:
+            await asyncio.wait_for(a.backup(), 120)
+            return a.engine.last_overlap
+
+    overlap = loop.run_until_complete(asyncio.wait_for(run(), 180))
+    assert 0 <= overlap["drain_s"] <= overlap["wall_s"]
+    assert set(overlap["stage_busy_s"]) == {
+        "chunk_hash", "seal", "write", "send"}

@@ -217,13 +217,17 @@ def test_span_nesting_journals_one_trace(tmp_path):
 
 
 def test_span_seconds_histogram_always_observes():
-    obs_trace.enable(False)
-    with obs_trace.span("obs_test.work"):
-        pass
     h = obs_metrics.registry().get("bkw_span_seconds")
-    assert h.count_value(name="obs_test.work") == 1
-    # the flat BKW_TRACE table stays gated off (utils/tracing compat)
-    assert "obs_test.work" not in obs_trace.report()
+    n0, s0 = (h.count_value(name="obs_test.work"),
+              h.sum_value(name="obs_test.work"))
+    for _ in range(2):
+        with obs_trace.span("obs_test.work") as ctx:
+            assert obs_trace.current().name == "obs_test.work"
+            assert ctx.span_id == obs_trace.current_span_id()
+    # no gate and no second table: the histogram's count and sum are
+    # the per-name aggregate
+    assert h.count_value(name="obs_test.work") == n0 + 2
+    assert h.sum_value(name="obs_test.work") > s0
 
 
 def test_clean_trace_id():

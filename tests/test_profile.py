@@ -143,29 +143,6 @@ def test_report_is_a_delta_and_journals(tmp_path):
         all_actual.value(stage="digest") / all_padded.value(stage="digest"))
 
 
-@pytest.mark.profile
-def test_dev_time_stage_records_histogram_and_journal(tmp_path):
-    """Timing-sensitive: excluded from tier-1 via the profile marker
-    (BKW_PROFILE_TESTS=1 to run)."""
-    import jax
-    import jax.numpy as jnp
-
-    fn = jax.jit(lambda x: x * 2 + 1)
-    x = jnp.ones(128, jnp.float32)
-    jr = obs_journal.install(obs_journal.Journal(tmp_path / "j.jsonl"))
-    try:
-        dt = profile.dev_time_stage("scan", fn, x, n=5)
-    finally:
-        obs_journal.uninstall()
-    assert dt > 0
-    hist = obs_metrics.registry().get("bkw_profile_stage_seconds")
-    assert hist.sum_value(stage="scan") >= dt * 0.99
-    lines = [json.loads(l) for l in
-             (tmp_path / "j.jsonl").read_text().splitlines()]
-    assert any(l["kind"] == "profile" and l["stage"] == "scan"
-               for l in lines)
-
-
 # --- the e2e acceptance bundle ----------------------------------------------
 
 @pytest.fixture

@@ -88,28 +88,26 @@ def test_persistence_across_reopen(tmp_path):
 
 def test_tracing_spans_and_report():
     """Host tracing subsystem (SURVEY §5.1: the build adds what the
-    reference lacks)."""
+    reference lacks): the facade's spans land in ``bkw_span_seconds``,
+    whose per-name count and sum are the aggregate report."""
+    from backuwup_tpu.obs import metrics as obs_metrics
     from backuwup_tpu.utils import tracing
 
-    tracing.reset()
-    tracing.enable(True)
-    try:
-        with tracing.span("unit.test"):
-            pass
-
-        @tracing.traced("unit.decorated")
-        def f():
-            return 41
-
-        assert f() == 41
-        rep = tracing.report()
-        assert rep["unit.test"][0] == 1
-        assert rep["unit.decorated"][0] == 1
-        assert "unit.test" in tracing.format_report()
-    finally:
-        tracing.enable(False)
-        tracing.reset()
-    # disabled: no recording
-    with tracing.span("unit.off"):
+    spans = obs_metrics.registry().get("bkw_span_seconds")
+    before = {n: spans.count_value(name=n)
+              for n in ("unit.test", "unit.decorated")}
+    with tracing.span("unit.test"):
         pass
-    assert "unit.off" not in tracing.report()
+
+    @tracing.traced("unit.decorated")
+    def f():
+        return 41
+
+    assert f() == 41
+    assert f() == 41
+    assert spans.count_value(name="unit.test") == before["unit.test"] + 1
+    assert spans.count_value(name="unit.decorated") == \
+        before["unit.decorated"] + 2
+    assert spans.sum_value(name="unit.decorated") > 0
+    assert not hasattr(tracing, "format_report")  # one aggregate, not two
+

@@ -1,0 +1,328 @@
+"""Spans on the served backup's streaming route, and their bridges.
+
+What the route enters (``stream.*`` in ``ChunkerBackend.manifest_stream``,
+``cdc.*`` in ``TpuCdcScanner``, ``blake3.*`` in ``blake3_many_tpu``), what
+the per-backup report makes of them, what ``chunk_hash`` and ``paused``
+count, the annotator that puts a span on the profiler's host plane, and
+the compile listener.  Counts are deltas of ``bkw_span_seconds``: the
+registry is the process's, and other tests feed it too.
+"""
+
+import asyncio
+import glob
+import json
+import os
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from backuwup_tpu.crypto import KeyManager
+from backuwup_tpu.engine import Orchestrator
+from backuwup_tpu.obs import journal as obs_journal
+from backuwup_tpu.obs import metrics as obs_metrics
+from backuwup_tpu.obs import profile as obs_profile
+from backuwup_tpu.obs import trace as obs_trace
+from backuwup_tpu.ops import backend as ops_backend
+from backuwup_tpu.ops.backend import CpuBackend, TpuBackend
+from backuwup_tpu.ops.gear import CDCParams
+from backuwup_tpu.snapshot.blob_index import BlobIndex
+from backuwup_tpu.snapshot.packer import DirPacker
+from backuwup_tpu.snapshot.packfile import PackfileWriter
+
+KEYS = KeyManager.from_secret(bytes(range(32)))
+SMALL = CDCParams.from_desired(4096)
+SEGMENT = 256 << 10
+STREAM_ONLY = ("stream.read", "stream.slice", "stream.emit")
+DEVICE_ROUTE = ("cdc.stage", "cdc.scan", "cdc.decode", "stream.select_cuts")
+
+
+def _span_counts() -> dict:
+    spans = obs_metrics.registry().get("bkw_span_seconds")
+    return {n: spans.count_value(name=n) for n in obs_profile.REPORT_SPANS}
+
+
+def _reader(data: bytes):
+    pos = 0
+
+    def read(n: int) -> bytes:
+        nonlocal pos
+        out = data[pos:pos + n]
+        pos += len(out)
+        return out
+
+    return read
+
+
+@pytest.fixture
+def annotations():
+    """Records what the installed annotator is entered with; puts back
+    whatever was installed (a TPU backend made earlier in this process
+    installs jax's)."""
+    seen = []
+
+    class Note:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    prior = obs_trace._annotator
+    obs_trace.set_annotator(Note)
+    yield seen
+    obs_trace.set_annotator(prior)
+
+
+@pytest.mark.parametrize("make", [CpuBackend, TpuBackend],
+                         ids=["cpu", "tpu"])
+def test_stream_route_enters_each_span_once_per_segment(make, rng):
+    data = rng.randbytes(3 * SEGMENT + 1000)
+    rounds = 5  # three full reads, the short one, the empty one at EOF
+    before, base = _span_counts(), obs_profile.baseline()
+    emitted = []
+    refs = make(SMALL).manifest_stream(
+        _reader(data), segment_bytes=SEGMENT,
+        emit=lambda ref, chunk: emitted.append(ref))
+    delta = {n: c - before[n] for n, c in _span_counts().items()}
+    assert emitted == refs
+    assert sum(r.length for r in refs) == len(data)
+    for name in STREAM_ONLY:
+        assert delta[name] == rounds, (name, delta)
+    rep = obs_profile.report(base)
+    assert set(rep["stream"]) == {"host_prep", "device_wait", "emit"}
+    assert rep["stream"]["host_prep"] > 0 and rep["stream"]["emit"] > 0
+    if make is TpuBackend:
+        # every round scans what it holds (the carried last chunk at
+        # EOF too) and digests in at least one bucketed batch
+        for name in DEVICE_ROUTE:
+            assert delta[name] == rounds, (name, delta)
+        assert delta["blake3.stage"] == delta["blake3.digest"] >= rounds - 1
+        assert rep["stream"]["device_wait"] > 0
+        for name in ("cdc.scan", "blake3.digest", "stream.emit"):
+            assert rep["stage_seconds"][name] > 0
+    else:
+        assert all(delta[n] == 0 for n in DEVICE_ROUTE + ("blake3.stage",
+                                                          "blake3.digest"))
+        assert rep["stream"]["device_wait"] == 0
+
+
+@pytest.mark.parametrize("make", [CpuBackend, TpuBackend],
+                         ids=["cpu", "tpu"])
+def test_stream_route_emits_views_of_the_segment_not_copies(make, rng):
+    """Slicing a 256 MiB segment into ~3,600 ``bytes`` cost 0.05 or 0.8 s
+    a backup by the allocator's mood (PERF.md, PR 25): ``emit`` gets
+    read-only views, equal to the chunk's bytes."""
+    data = rng.randbytes(2 * SEGMENT + 500)
+    seen = []
+    refs = make(SMALL).manifest_stream(
+        _reader(data), segment_bytes=SEGMENT,
+        emit=lambda ref, chunk: seen.append((ref, chunk)))
+    assert [r for r, _ in seen] == refs and len(refs) > 8
+    for ref, chunk in seen:
+        assert isinstance(chunk, memoryview) and chunk.readonly
+        assert chunk == data[ref.offset:ref.offset + ref.length]
+
+
+def test_packer_copies_a_new_streamed_chunk_out_of_its_segment(tmp_path,
+                                                                rng):
+    src = tmp_path / "src"
+    src.mkdir()
+    (src / "image").write_bytes(rng.randbytes(64 << 10))
+    packer = _packer(tmp_path, batch_bytes=16 << 10)
+    kept = []
+    add_blob = packer.writer.add_blob
+    packer.writer.add_blob = lambda blob: (kept.append(blob),
+                                           add_blob(blob))[1]
+    packer.pack(src)
+    packer.writer.shutdown()
+    assert len(kept) > packer.stats.chunks > 4  # chunks and tree nodes
+    assert all(type(b.data) is bytes for b in kept)
+
+
+def test_digest_many_of_other_callers_enters_no_blake3_span(rng):
+    """The send stage's threads digest through the same seam; their
+    batches must not land in the stream route's sums."""
+    before = _span_counts()
+    TpuBackend(SMALL).digest_many([rng.randbytes(3000), rng.randbytes(70)])
+    after = _span_counts()
+    assert after["blake3.stage"] == before["blake3.stage"]
+    assert after["blake3.digest"] == before["blake3.digest"]
+
+
+def _packer(tmp_path, **kw) -> DirPacker:
+    out = tmp_path / "pack"
+    out.mkdir(exist_ok=True)
+    writer = PackfileWriter(KEYS, out)
+    index = BlobIndex(KEYS, tmp_path / "idx")
+    return DirPacker(CpuBackend(SMALL), writer, index, **kw)
+
+
+def test_chunk_hash_leaves_out_an_emit_that_sleeps(tmp_path, rng):
+    data = rng.randbytes(96 << 10)
+    src = tmp_path / "src"
+    src.mkdir()
+    (src / "image").write_bytes(data)
+    chunks = {r.hash for r in CpuBackend(SMALL).manifest(data)}
+    slept = 0.0
+
+    def on_blob(blob_hash, size):
+        nonlocal slept
+        if blob_hash in chunks:  # a tree node is packed outside emit
+            time.sleep(0.01)
+            slept += 0.01
+
+    stage = obs_metrics.registry().get("bkw_pack_stage_seconds")
+    busy0 = stage.sum_value(stage="chunk_hash")
+    before = _span_counts()
+    packer = _packer(tmp_path, batch_bytes=16 << 10, on_blob=on_blob)
+    t0 = time.monotonic()
+    packer.pack(src)
+    wall = time.monotonic() - t0
+    packer.writer.shutdown()
+    assert packer.stats.chunks == len(chunks) and slept >= 0.1
+    after = _span_counts()
+    for name in ("stream.file", "stream.tree"):
+        assert after[name] == before[name] + 1, name
+    assert 0 < packer.stats.chunk_hash_s <= wall - 0.9 * slept
+    assert stage.sum_value(stage="chunk_hash") - busy0 == pytest.approx(
+        packer.stats.chunk_hash_s)
+
+
+def test_chunks_deduped_counts_chunks_not_tree_nodes(tmp_path, rng):
+    """A second pack of an unchanged tree finds every chunk and every
+    tree node there already: ``chunks_deduped`` is the chunks alone (it
+    read 4,964 of 2,868 chunks on the chip, the tree nodes among them)."""
+    src = tmp_path / "src"
+    (src / "d").mkdir(parents=True)
+    (src / "big").write_bytes(rng.randbytes(64 << 10))  # streamed
+    for i in range(4):
+        (src / "d" / f"f{i}").write_bytes(rng.randbytes(9 << 10))
+    first = _packer(tmp_path, batch_bytes=32 << 10)
+    first.pack(src)
+    first.writer.shutdown()
+    assert first.stats.chunks_deduped == 0
+    again = DirPacker(CpuBackend(SMALL), PackfileWriter(KEYS, tmp_path / "p2"),
+                      first.index, batch_bytes=32 << 10)
+    again.pack(src)
+    again.writer.shutdown()
+    assert again.stats.chunks == first.stats.chunks > 8
+    assert again.stats.chunks_deduped == again.stats.chunks
+    assert again.stats.bytes_deduped == again.stats.bytes_read
+
+
+def test_block_if_paused_counts_only_the_seconds_it_waited():
+    stage = obs_metrics.registry().get("bkw_pack_stage_seconds")
+    orch = Orchestrator()
+    n0, s0 = (stage.count_value(stage="paused"),
+              stage.sum_value(stage="paused"))
+    for _ in range(3):
+        orch.block_if_paused()  # not paused: no clock read, no sample
+    assert stage.count_value(stage="paused") == n0
+    orch.pause()
+    threading.Timer(0.05, orch.resume).start()
+    orch.block_if_paused()
+    assert stage.count_value(stage="paused") == n0 + 1
+    assert 0.04 <= stage.sum_value(stage="paused") - s0 < 5.0
+
+
+def test_annotator_is_entered_with_each_span_nested(annotations):
+    with obs_trace.span("outer.work"):
+        with obs_trace.span("inner.work"):
+            pass
+        with obs_trace.span("inner.more"):
+            pass
+    assert annotations == [
+        ("enter", "outer.work"), ("enter", "inner.work"),
+        ("exit", "inner.work"), ("enter", "inner.more"),
+        ("exit", "inner.more"), ("exit", "outer.work")]
+
+
+def test_annotator_skips_spans_on_an_event_loops_thread(annotations):
+    """A span held across an await interleaves with its siblings on the
+    loop's thread; one opened in an executor thread nests and is
+    bridged."""
+    async def main():
+        with obs_trace.span("held.across.await"):
+            await asyncio.sleep(0)
+            await asyncio.get_running_loop().run_in_executor(
+                None, _in_thread)
+
+    def _in_thread():
+        with obs_trace.span("executor.work"):
+            pass
+
+    asyncio.run(main())
+    assert annotations == [("enter", "executor.work"),
+                           ("exit", "executor.work")]
+
+
+def test_no_annotator_no_call(annotations):
+    obs_trace.set_annotator(None)
+    with obs_trace.span("unbridged.work"):
+        pass
+    assert annotations == []
+    spans = obs_metrics.registry().get("bkw_span_seconds")
+    assert spans.count_value(name="unbridged.work") >= 1
+
+
+def test_fresh_jit_counts_one_compile_under_its_name(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    ops_backend._install_jax_hooks()
+    hist = obs_metrics.registry().get("bkw_jit_compile_seconds")
+
+    def bkw_test_fresh_program(x):
+        return x * 3 + 1
+
+    n0 = hist.count_value(fun="bkw_test_fresh_program")
+    base = obs_profile.baseline()
+    obs_journal.install(obs_journal.Journal(tmp_path / "j.jsonl"))
+    try:
+        with obs_trace.span("step.that.compiles"):
+            jax.jit(bkw_test_fresh_program)(jnp.ones(7)).block_until_ready()
+    finally:
+        obs_journal.uninstall()
+    assert hist.count_value(fun="bkw_test_fresh_program") == n0 + 1
+    rep = obs_profile.report(base)
+    assert rep["compile_s"]["bkw_test_fresh_program"] > 0
+    assert rep["compile_total_s"] >= rep["compile_s"][
+        "bkw_test_fresh_program"]
+    lines = [json.loads(ln) for ln in
+             (tmp_path / "j.jsonl").read_text().splitlines()]
+    mine = [ln for ln in lines if ln["kind"] == "compile"
+            and ln["fun"] == "bkw_test_fresh_program"]
+    assert len(mine) == 1 and mine[0]["span"] == "step.that.compiles"
+
+
+def test_profiler_capture_holds_a_bridged_span(tmp_path):
+    """The benchmark's capture options (host tracer level 1, no Python
+    tracer): the span's name is an event of a host-plane line."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    ops_backend._install_jax_hooks()
+    obs_trace.set_annotator(jax.profiler.TraceAnnotation)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with obs_trace.span("bridged.on.the.host.plane"):
+            np.asarray(jnp.arange(64) * 2)
+    finally:
+        jax.profiler.stop_trace()
+    found = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    assert found
+    data = ProfileData.from_file(found[-1])
+    names = {ev.name for plane in data.planes
+             if not plane.name.startswith("/device:")
+             for line in plane.lines for ev in line.events}
+    assert "bridged.on.the.host.plane" in names
