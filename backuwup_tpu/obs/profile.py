@@ -158,16 +158,29 @@ _TIER_COLD_COMMITS = _metrics.counter(
 # :func:`report` sums the spans of a group into its ``stream`` section.
 STREAM_GROUPS = {
     "stream.read": "host_prep",
-    "cdc.stage": "host_prep",
     "cdc.decode": "host_prep",
     "stream.select_cuts": "host_prep",
     "stream.slice": "host_prep",
     "blake3.stage": "host_prep",
+    "stream.upload": "device_wait",
     "cdc.scan": "device_wait",
     "blake3.digest": "device_wait",
     "stream.emit": "emit",
     "stream.tree": "emit",
 }
+
+# Bytes the resident streaming route (ops/resident.py) moved for a
+# streamed file: ``uploaded`` is everything it put on the device
+# (window blocks, chunk rows), about the file's size when every byte
+# goes up once; ``host_assembled`` is what it had to copy together on
+# the host (the chunk that straddles carry and window, a slice rescanned
+# by the oracle), a few hundred KiB a segment.
+STREAM_BYTE_KINDS = ("uploaded", "host_assembled")
+_STREAM_BYTES = _metrics.counter(
+    "bkw_stream_bytes_total",
+    "Bytes the resident streaming route uploaded to the device, and "
+    "bytes it assembled on the host, per streamed file",
+    labelnames=("kind",))
 
 # Span names whose bkw_span_seconds sums a pipeline report attributes as
 # per-stage wall time: the batched route's dispatch/collect pairs and
@@ -306,6 +319,14 @@ def tier_cold_commit(kind: str) -> None:
     _TIER_COLD_COMMITS.inc(1, kind=kind)
 
 
+def stream_bytes(kind: str, n: int) -> None:
+    """Count ``n`` bytes of a streamed file uploaded or host-assembled."""
+    if kind not in STREAM_BYTE_KINDS:
+        raise ValueError(f"unknown stream byte kind {kind!r}")
+    if n:
+        _STREAM_BYTES.inc(n, kind=kind)
+
+
 # --- which step recompiled ----------------------------------------------------
 
 def jit_compiled(fun: str, seconds: float) -> None:
@@ -353,6 +374,8 @@ def baseline() -> Dict[str, Dict[str, float]]:
         tier[f"probes_{path}"] = _TIER_PROBES.value(path=path)
         tier[f"hits_{path}"] = _TIER_HITS.value(path=path)
     out["tier"] = tier
+    out["stream_bytes"] = {k: _STREAM_BYTES.value(kind=k)
+                           for k in STREAM_BYTE_KINDS}
     spans = _metrics.registry().get("bkw_span_seconds")
     if spans is not None:
         for name in REPORT_SPANS:
@@ -383,6 +406,9 @@ def report(base: Optional[dict] = None) -> dict:
     stream = dict.fromkeys(STREAM_GROUPS.values(), 0.0)
     for name, group in STREAM_GROUPS.items():
         stream[group] += span_s.get(name, 0.0)
+    stream = {k: round(v, 6) for k, v in stream.items()}
+    for kind, n in _delta("stream_bytes").items():
+        stream[f"{kind}_bytes"] = int(n)
     compile_s = {fun: round(dt, 6)
                  for fun, dt in _delta("compile_s").items() if dt > 0}
     # per-device split of the mesh-pipeline launches: {device: {stage: n}}
@@ -409,7 +435,7 @@ def report(base: Optional[dict] = None) -> dict:
         "padded_bytes": padded,
         "pad_efficiency": efficiency,
         "stage_seconds": stage_seconds,
-        "stream": {k: round(v, 6) for k, v in stream.items()},
+        "stream": stream,
         "compile_s": compile_s,
         "compile_total_s": round(sum(compile_s.values()), 6),
     }

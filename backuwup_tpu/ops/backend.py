@@ -28,8 +28,9 @@ from ..obs import trace as obs_trace
 from .blake3_cpu import blake3_many
 from .blake3_tpu import blake3_many_tpu
 from .cdc_cpu import chunk_stream as chunk_stream_cpu
+from .cdc_cpu import cuts_to_chunks, select_cuts
 from .cdc_tpu import TpuCdcScanner
-from .gear import CDCParams
+from .gear import GEAR_WINDOW, CDCParams
 
 
 @dataclass(frozen=True)
@@ -142,10 +143,10 @@ class ChunkerBackend:
         are duplicates whose bytes nobody reads.  A caller that keeps a
         chunk past the call copies it (``bytes(chunk)``).
 
-        One span per step per segment, never one per chunk (``stream.*``
-        here; the TPU backend's ``chunk`` and ``_stream_digest`` add
-        ``cdc.*`` and ``blake3.*``): obs/profile.py ``STREAM_GROUPS``
-        folds them into host preparation, device wait and emit.
+        One span per step per segment, never one per chunk (``stream.*``):
+        obs/profile.py ``STREAM_GROUPS`` folds them into host preparation,
+        device wait and emit.  Backends with nothing to make resident
+        (CPU, native) run this; :class:`TpuBackend` has its own.
         """
         out: List[ChunkRef] = []
         carry = b""
@@ -252,8 +253,10 @@ class TpuBackend(ChunkerBackend):
     """Device-resident execution: ``manifest_many`` stages each batch into
     HBM once and runs scan -> cut -> HBM-to-HBM chunk gather -> batched
     digest (:meth:`DevicePipeline.manifest_batch`) — no per-chunk host
-    slicing.  ``chunk``/``digest_many`` remain for the streaming path and
-    as the op-level seams the parity tests pin."""
+    slicing; ``manifest_stream`` does the same for one resident segment
+    of a long file at a time.  ``chunk``/``digest_many`` remain as the
+    op-level seams the parity tests pin (``digest_many`` also serves the
+    send stage)."""
 
     name = "tpu"
 
@@ -292,11 +295,89 @@ class TpuBackend(ChunkerBackend):
     def digest_many(self, datas):
         return blake3_many_tpu(datas)
 
-    def _stream_digest(self, pieces):
-        # digest_many also serves the send stage's threads (shard
-        # payloads, challenge tables): only the stream route's batches
-        # enter blake3.stage / blake3.digest, so their sums stay its own
-        return blake3_many_tpu(pieces, timed=True)
+    def manifest_stream(self, read, segment_bytes: int = 256 * 1024 * 1024,
+                        emit: Optional[Callable] = None) -> List[ChunkRef]:
+        """The base method's contract (same chunks, same digests, the
+        same read-only views handed to ``emit``), with the segment
+        resident on the device (:mod:`.resident`): each window of the
+        stream is uploaded once, straight from a view of what ``read``
+        returned, scanned where it lies, and its chunks are gathered and
+        digested out of HBM.  Only candidate words and 32 bytes a chunk
+        come down; only ``(offset, length)`` rows go up a second time.
+
+        The host keeps positions and views.  The carry, the last and
+        still open chunk of a segment, moves to the front of the next
+        resident buffer by a device slice, and its cut candidates are
+        kept, so no byte is scanned or uploaded twice.  At EOF the carry
+        is the last chunk as it stands.  The one chunk a segment that
+        starts in the carry and ends in the new window is assembled on
+        the host (at most ``max_size`` bytes).
+
+        Spans, one per step per segment: ``stream.read``,
+        ``stream.upload``, ``cdc.scan``, ``cdc.decode``,
+        ``stream.select_cuts``, ``stream.slice`` (views), ``blake3.stage``
+        (chunk rows), ``blake3.digest``, ``stream.emit``.
+        """
+        from .resident import ResidentStream
+
+        dev = ResidentStream(self.params, self._scanner, segment_bytes)
+        out: List[ChunkRef] = []
+        base = 0  # absolute offset of the carry's first byte
+        carry = memoryview(b"")  # host view of the carry's bytes
+        # the carry's cut candidates, relative to its first byte
+        pos_l = np.empty(0, dtype=np.int64)
+        is_s = np.empty(0, dtype=bool)
+        while True:
+            with obs_trace.span("stream.read"):
+                window = memoryview(read(segment_bytes)).toreadonly()
+            c, w = len(carry), len(window)
+            eof = w == 0
+            if eof:
+                # nothing cut the carry while more could follow: it is
+                # the stream's last chunk, and already resident
+                final = [(0, c)] if c else []
+                origin = dev.carry + dev.window - c
+            else:
+                with obs_trace.span("stream.upload"):
+                    dev.load(window, c)
+                with obs_trace.span("cdc.scan"):
+                    scanned = dev.scan()
+                with obs_trace.span("cdc.decode"):
+                    pos_w, is_s_w = dev.candidates(
+                        scanned, window, bytes(carry[1 - GEAR_WINDOW:]))
+                    pos_l = np.concatenate([pos_l, pos_w + c])
+                    is_s = np.concatenate([is_s, is_s_w])
+                with obs_trace.span("stream.select_cuts"):
+                    chunks = cuts_to_chunks(select_cuts(
+                        pos_l[is_s], pos_l, c + w, self.params))
+                # the last chunk's end is the buffer's, not a cut: carry
+                # it (all of the buffer, if it is the only one)
+                final, last_off = chunks[:-1], chunks[-1][0]
+                origin = 0
+                keep = pos_l >= last_off
+                pos_l, is_s = pos_l[keep] - last_off, is_s[keep]
+            with obs_trace.span("stream.slice"):
+                pieces = [_host_view(carry, window, off, ln)
+                          for off, ln in final]
+                if not eof:
+                    carry = _host_view(carry, window, last_off,
+                                       c + w - last_off)
+            digests = []
+            if final:
+                with obs_trace.span("blake3.stage"):
+                    offs, lens = np.array(final, dtype=np.int64).T
+                    meta, tiles, row_of = dev.tile_rows(offs + origin, lens)
+                with obs_trace.span("blake3.digest"):
+                    digests = dev.digest(meta, tiles, row_of)
+            with obs_trace.span("stream.emit"):
+                for h, (off, ln), data in zip(digests, final, pieces):
+                    ref = ChunkRef(offset=base + off, length=ln, hash=h)
+                    out.append(ref)
+                    if emit is not None:
+                        emit(ref, data)
+            if eof:
+                return out
+            base += last_off
 
     def encode_shards(self, stripes, m):
         from ..erasure import rs_tpu
@@ -343,6 +424,20 @@ class TpuBackend(ChunkerBackend):
                 hashes.append(ref.hash)
                 raw.append(None if fl is None else bool(fl[k]))
         return out, dedup.resolve_hints(hashes, raw)
+
+
+def _host_view(carry: memoryview, window: memoryview, off: int,
+               ln: int) -> memoryview:
+    """Read-only view of ``ln`` bytes at ``off`` of carry + window: a
+    slice of either where the span lies within one, else the two parts
+    joined (the one copy a segment costs the host)."""
+    c = len(carry)
+    if off >= c:
+        return window[off - c:off - c + ln]
+    if off + ln <= c:
+        return carry[off:off + ln]
+    obs_profile.stream_bytes("host_assembled", ln)
+    return memoryview(b"".join((carry[off:], window[:off + ln - c])))
 
 
 _jax_hooks_installed = False

@@ -26,7 +26,6 @@ waste and XLA recompiles.
 
 from __future__ import annotations
 
-import contextlib
 import functools
 
 import jax
@@ -34,7 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import defaults
-from ..obs import trace as obs_trace
 from .blake3_cpu import (
     BLOCK_LEN,
     CHUNK_END,
@@ -478,42 +476,30 @@ def _batch_bucket(n: int) -> int:
     return b
 
 
-def _span(name: str, timed: bool):
-    return obs_trace.span(name) if timed else contextlib.nullcontext()
-
-
-def bucketed_batches(datas, timed: bool = False):
-    """Group inputs by leaf bucket; yields (indices, buf, lens, L).
-    ``timed``: each batch's padded buffer is built inside a
-    ``blake3.stage`` span."""
+def bucketed_batches(datas):
+    """Group inputs by leaf bucket; yields (indices, buf, lens, L)."""
     groups = {}
     for i, d in enumerate(datas):
         groups.setdefault(_leaf_bucket(len(d)), []).append(i)
     for L, idxs in sorted(groups.items()):
-        with _span("blake3.stage", timed):
-            B = _batch_bucket(len(idxs))
-            buf = np.zeros((B, L * CHUNK_LEN), dtype=np.uint8)
-            lens = np.zeros(B, dtype=np.int32)
-            for row, i in enumerate(idxs):
-                d = datas[i]
-                buf[row, :len(d)] = np.frombuffer(d, dtype=np.uint8)
-                lens[row] = len(d)
+        B = _batch_bucket(len(idxs))
+        buf = np.zeros((B, L * CHUNK_LEN), dtype=np.uint8)
+        lens = np.zeros(B, dtype=np.int32)
+        for row, i in enumerate(idxs):
+            d = datas[i]
+            buf[row, :len(d)] = np.frombuffer(d, dtype=np.uint8)
+            lens[row] = len(d)
         yield idxs, buf, lens, L
 
 
-def blake3_many_tpu(datas, timed: bool = False) -> list:
+def blake3_many_tpu(datas) -> list:
     """Batched digests on the device; bit-exact vs
-    :func:`backuwup_tpu.ops.blake3_cpu.blake3_hash`.  ``timed``: one
-    ``blake3.stage`` (host padding) and one ``blake3.digest`` (upload,
-    program, download) span per batch, for the caller whose time they
-    are to explain (the stream route; the others lie inside spans of
-    their own)."""
+    :func:`backuwup_tpu.ops.blake3_cpu.blake3_hash`."""
     datas = list(datas)
     out = [None] * len(datas)
-    for idxs, buf, lens, L in bucketed_batches(datas, timed):
-        with _span("blake3.digest", timed):
-            root = np.asarray(digest_padded(
-                jnp.asarray(buf), jnp.asarray(lens), L=L))
+    for idxs, buf, lens, L in bucketed_batches(datas):
+        root = np.asarray(digest_padded(jnp.asarray(buf), jnp.asarray(lens),
+                                        L=L))
         digests = _root_cv_to_digests(root)
         for row, i in enumerate(idxs):
             out[i] = digests[row]

@@ -40,7 +40,6 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from .. import defaults
-from ..obs import trace as obs_trace
 from .cdc_cpu import cuts_to_chunks, select_cuts
 from .cdc_cpu import gear_hashes as gear_hashes_np
 from .gear import GEAR_WINDOW, CDCParams
@@ -226,31 +225,26 @@ class TpuCdcScanner:
         offset = 0
         tail = bytes(prev_tail)[-_HALO:] if prev_tail else b""
         while offset < n:
-            with obs_trace.span("cdc.stage"):
-                seg = data[offset:offset + self.segment_size]
-                padded = _segment_bucket(len(seg))
-                ext = np.zeros(_HALO + padded, dtype=np.uint8)
-                if tail:
-                    ext[_HALO - len(tail):_HALO] = np.frombuffer(
-                        tail, np.uint8)
-                ext[_HALO:_HALO + len(seg)] = np.frombuffer(seg, np.uint8)
-                k_cap = self._k_cap(padded)
-            with obs_trace.span("cdc.scan"):
-                # upload, program and the first download: int() waits
-                widx, wl, ws, nz_words = _scan_segment(
-                    jnp.asarray(ext), jnp.int32(len(seg)),
-                    jnp.uint32(params.mask_s), jnp.uint32(params.mask_l),
-                    k_cap=k_cap)
-                overflow = int(nz_words) > k_cap
-            with obs_trace.span("cdc.decode"):
-                if overflow:  # capacity overflow: oracle rescan
-                    h = gear_hashes_np(seg, tail)
-                    cand_l = (h & np.uint32(params.mask_l)) == 0
-                    p = np.nonzero(cand_l)[0].astype(np.int64)
-                    s = (h[p] & np.uint32(params.mask_s)) == 0
-                    p = p + offset
-                else:
-                    p, s = _decode_words(widx, wl, ws, k_cap, offset)
+            seg = data[offset:offset + self.segment_size]
+            padded = _segment_bucket(len(seg))
+            ext = np.zeros(_HALO + padded, dtype=np.uint8)
+            if tail:
+                ext[_HALO - len(tail):_HALO] = np.frombuffer(tail, np.uint8)
+            ext[_HALO:_HALO + len(seg)] = np.frombuffer(seg, np.uint8)
+            k_cap = self._k_cap(padded)
+            widx, wl, ws, nz_words = _scan_segment(
+                jnp.asarray(ext), jnp.int32(len(seg)),
+                jnp.uint32(params.mask_s), jnp.uint32(params.mask_l),
+                k_cap=k_cap)
+            if int(nz_words) > k_cap:  # capacity overflow: oracle rescan
+                h = gear_hashes_np(seg, tail)
+                cand_l = (h & np.uint32(params.mask_l)) == 0
+                p = np.nonzero(cand_l)[0].astype(np.int64)
+                s = (h[p] & np.uint32(params.mask_s)) == 0
+                all_pos.append(p + offset)
+                all_s.append(s)
+            else:
+                p, s = _decode_words(widx, wl, ws, k_cap, offset)
                 all_pos.append(p)
                 all_s.append(s)
             tail = seg[-_HALO:] if len(seg) >= _HALO else (tail + seg)[-_HALO:]
@@ -268,8 +262,7 @@ class TpuCdcScanner:
         :func:`backuwup_tpu.ops.cdc_cpu.chunk_stream`."""
         n = len(data)
         pos_s, pos_l = self.candidate_positions(data)
-        with obs_trace.span("stream.select_cuts"):
-            return cuts_to_chunks(select_cuts(pos_s, pos_l, n, self.params))
+        return cuts_to_chunks(select_cuts(pos_s, pos_l, n, self.params))
 
 
 # ---------------------------------------------------------------------------

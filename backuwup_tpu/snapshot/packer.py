@@ -275,8 +275,9 @@ class DirPacker:
 
         The file is mmapped and fed as memoryview windows
         (dir_packer.rs:252's memmap2 analog), so the packer never holds a
-        second buffered copy of the file; the backend still assembles one
-        per-segment buffer when it splices the carry onto each window.
+        second buffered copy of the file; the CPU and native backends
+        still assemble one per-segment buffer when they splice the carry
+        onto each window, ``TpuBackend`` uploads the window as it is.
         The same documented race as the reference applies: a file
         mutating mid-chunk produces a wrong (detectably inconsistent)
         backup of that file, never a crash — mmap failures (e.g. the

@@ -6,14 +6,18 @@ digests to `CpuBackend` — dedup ratios depend on it (SURVEY.md section 7
 hard part 1).
 """
 
+import mmap
 import random
 
 import pytest
 
+from backuwup_tpu.obs import profile as obs_profile
 from backuwup_tpu.ops.backend import CpuBackend, TpuBackend, select_backend
+from backuwup_tpu.ops.cdc_tpu import TpuCdcScanner
 from backuwup_tpu.ops.gear import CDCParams
 
 PARAMS = CDCParams.from_desired(4096)
+SEGMENT = 64 * 1024
 
 
 def _assert_manifests_equal(a, b):
@@ -67,6 +71,115 @@ def test_manifest_stream_matches_manifest(backends, rng=random.Random(7)):
     refs = tpu.manifest_stream(read, segment_bytes=64 * 1024)
     assert [(r.offset, r.length, r.hash) for r in refs] == \
         [(r.offset, r.length, r.hash) for r in cpu.manifest(data)]
+
+
+def _reader(data, sizes=()):
+    """``read(n)`` over ``data``; the first reads return at most
+    ``sizes[i]`` bytes (a read may return fewer than it was asked for)."""
+    pos, caps = [0], list(sizes)
+
+    def read(n):
+        if caps:
+            n = min(n, caps.pop(0))
+        out = data[pos[0]:pos[0] + n]
+        pos[0] += len(out)
+        return out
+
+    return read
+
+
+def _first_cut_after(data, floor):
+    return next(r.offset + r.length for r in CpuBackend(PARAMS).manifest(data)
+                if r.offset + r.length >= floor)
+
+
+# each case: (stream, sizes of the first reads, what the route must have
+# done besides matching the oracle)
+def _stream_cases():
+    rng = random.Random(26)
+    plain = rng.randbytes(3 * SEGMENT + 4321)
+    return {
+        "shorter_than_a_segment": (rng.randbytes(SEGMENT // 3), (), None),
+        "exact_multiple_of_segment": (rng.randbytes(3 * SEGMENT), (), None),
+        # the first read ends on a cut: the carry is one whole chunk and
+        # the next window starts a chunk
+        "cut_on_the_segment_boundary": (
+            plain, (_first_cut_after(plain, SEGMENT // 2),), "no_assembly"),
+        "chunk_spans_carry_and_window": (plain, (), "assembled"),
+        # reads shorter than min_size: every window leaves one open chunk
+        "single_open_chunk_carried_whole": (
+            plain[:20_000], (PARAMS.min_size // 2,) * 40, "assembled"),
+        "zeros_force_max_size_cuts": (b"\x00" * (2 * SEGMENT + 99), (), None),
+        "trailing_chunk_below_min_size": (
+            plain[:SEGMENT] + b"tail", (), None),
+    }
+
+
+@pytest.mark.parametrize("case", list(_stream_cases()))
+def test_resident_stream_route_matches_the_oracle(backends, case):
+    """``TpuBackend.manifest_stream`` (one resident segment at a time,
+    ops/resident.py) against ``CpuBackend.manifest`` of the whole
+    stream: offsets, lengths, digests, and the bytes handed to ``emit``."""
+    cpu, tpu = backends
+    data, sizes, expect = _stream_cases()[case]
+    base = obs_profile.baseline()
+    seen = []
+    refs = tpu.manifest_stream(
+        _reader(data, sizes), segment_bytes=SEGMENT,
+        emit=lambda ref, chunk: seen.append((ref, bytes(chunk))))
+    assert [(r.offset, r.length, r.hash) for r in refs] == \
+        [(r.offset, r.length, r.hash) for r in cpu.manifest(data)]
+    assert [r for r, _ in seen] == refs
+    assert all(data[r.offset:r.offset + r.length] == c for r, c in seen)
+    assembled = obs_profile.report(base)["stream"]["host_assembled_bytes"]
+    if expect == "assembled":
+        assert 0 < assembled
+    elif expect == "no_assembly":
+        # only the later, ordinary boundaries cost a copy: fewer bytes
+        # than one max-size chunk a segment
+        assert assembled <= 3 * PARAMS.max_size
+
+
+@pytest.mark.parametrize("source", ["mmap_views", "bytes"])
+def test_resident_stream_route_reads_views_and_bytes(backends, tmp_path,
+                                                     source):
+    """The packer hands ``read`` windows of an mmap; its fallback hands
+    ``bytes``.  Both upload as they are, and the map closes afterwards."""
+    cpu, tpu = backends
+    data = random.Random(27).randbytes(2 * SEGMENT + 777)
+    want = [(r.offset, r.length, r.hash) for r in cpu.manifest(data)]
+    if source == "bytes":
+        refs = tpu.manifest_stream(_reader(data), segment_bytes=SEGMENT)
+    else:
+        path = tmp_path / "image"
+        path.write_bytes(data)
+        with open(path, "rb") as f:
+            mm = mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ)
+            view = memoryview(mm)
+            refs = tpu.manifest_stream(_reader(view),
+                                       segment_bytes=SEGMENT)
+            view.release()
+            mm.close()  # BufferError if a window is still held
+    assert [(r.offset, r.length, r.hash) for r in refs] == want
+
+
+def test_resident_stream_route_rescans_an_overflowed_slice_on_the_host():
+    """A scan slice with more candidate words than its sparse capacity
+    (``cap_factor`` 0 leaves 512; a 4-bit loose mask makes ~1,700 in
+    64 KiB) is rescanned by the numpy oracle from the host's view."""
+    params = CDCParams(min_size=64, desired_size=256, max_size=768,
+                       mask_s_bits=10, mask_l_bits=4)
+    tpu = TpuBackend(params)
+    tpu._scanner = TpuCdcScanner(params, cap_factor=0)
+    data = random.Random(28).randbytes(2 * SEGMENT + 300)
+    base = obs_profile.baseline()
+    refs = tpu.manifest_stream(_reader(data), segment_bytes=SEGMENT)
+    assert [(r.offset, r.length, r.hash) for r in refs] == \
+        [(r.offset, r.length, r.hash)
+         for r in CpuBackend(params).manifest(data)]
+    # every slice overflowed, so every byte was rescanned on the host
+    assert obs_profile.report(base)["stream"]["host_assembled_bytes"] \
+        >= len(data)
 
 
 def test_select_backend_policy():

@@ -29,13 +29,18 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from backuwup_tpu import defaults
-from backuwup_tpu.ops import scan_fused
+from backuwup_tpu.ops import resident, scan_fused
+from backuwup_tpu.ops.cdc_tpu import _HALO, TpuCdcScanner, _scan_segment
 from backuwup_tpu.ops.blake3_tpu import _leaf_scan_pallas
 from backuwup_tpu.ops.dedup_index import KEY_WORDS, _build_probe_fn
 from backuwup_tpu.ops.digest_pool import leaf_capacity, pool_digest
 from backuwup_tpu.ops.gear import CDCParams
 from backuwup_tpu.ops.manifest_device import _mesh_scan_digest_fn, tier_plan
-from backuwup_tpu.ops.pipeline import _SCAN_DISPATCH_BYTES, DevicePipeline
+from backuwup_tpu.ops.pipeline import (
+    _SCAN_DISPATCH_BYTES,
+    DevicePipeline,
+    _gather_digest,
+)
 
 GiB = 1 << 30
 PARAMS = CDCParams()  # production 256 KiB / 1 MiB / 3 MiB
@@ -172,3 +177,38 @@ def test_dedup_program_compiles_at_default_capacity(meshes, n_dev, insert):
     if n_dev > 1:  # queries ride the interconnect, table rows never move
         assert "all-gather" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_resident_stream_route_compiles_at_the_packers_segment(one_chip):
+    """The streamed file's route (ops/resident.py) at the packer's 256 MiB
+    segment and the benchmark's 64 KiB chunks: the buffer's programs
+    alias it in place, the scan slice is the 128 MiB program the route
+    always ran, and the gather+digest tile of the largest class keeps its
+    temporaries under a gibibyte beside the resident segment."""
+    params = CDCParams(16384, 65536, 196608, 18, 14)
+    geo = resident.Geometry.of(params, TpuCdcScanner(params), 256 << 20)
+    assert geo.classes == ((64, 128), (256, 128)) and geo.n_slices == 2
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    buf, i32 = shape((geo.size,), jnp.uint8), shape((), jnp.int32)
+    in_place = [
+        resident._next_resident.lower(buf, i32, i32, front=geo.front),
+        resident._put_block.lower(
+            buf, shape((geo.segment_bytes,), jnp.uint8), i32)]
+    for lowered in in_place:
+        mem = lowered.compile().memory_analysis()
+        assert mem.alias_size_in_bytes == geo.size
+        assert mem.temp_size_in_bytes < 1 << 20
+    resident._resident_slice.lower(
+        buf, i32, size=_HALO + geo.scan_slice).compile()
+    scan = _scan_segment.lower(
+        shape((_HALO + geo.scan_slice,), jnp.uint8), i32,
+        shape((), jnp.uint32), shape((), jnp.uint32), k_cap=geo.k_cap)
+    assert _temp_bytes(scan) < 1.25 * GiB
+    L, B = geo.classes[-1]
+    tile = _gather_digest.lower(
+        buf, shape((2, geo.rows), jnp.int32), i32,
+        shape((geo.rows, 8), jnp.uint32), B=B, L=L)
+    assert _temp_bytes(tile) < 1 * GiB

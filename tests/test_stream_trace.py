@@ -1,11 +1,13 @@
 """Spans on the served backup's streaming route, and their bridges.
 
-What the route enters (``stream.*`` in ``ChunkerBackend.manifest_stream``,
-``cdc.*`` in ``TpuCdcScanner``, ``blake3.*`` in ``blake3_many_tpu``), what
-the per-backup report makes of them, what ``chunk_hash`` and ``paused``
-count, the annotator that puts a span on the profiler's host plane, and
-the compile listener.  Counts are deltas of ``bkw_span_seconds``: the
-registry is the process's, and other tests feed it too.
+What the route enters (``stream.*`` in ``ChunkerBackend.manifest_stream``;
+``stream.upload``, ``cdc.*`` and ``blake3.*`` as well in
+``TpuBackend.manifest_stream``, whose segment is resident on the device),
+what the per-backup report makes of them and of the route's two byte
+counters, what ``chunk_hash`` and ``paused`` count, the annotator that
+puts a span on the profiler's host plane, and the compile listener.
+Counts are deltas of ``bkw_span_seconds``: the registry is the process's,
+and other tests feed it too.
 """
 
 import asyncio
@@ -34,8 +36,10 @@ from backuwup_tpu.snapshot.packfile import PackfileWriter
 KEYS = KeyManager.from_secret(bytes(range(32)))
 SMALL = CDCParams.from_desired(4096)
 SEGMENT = 256 << 10
+BIG_SEGMENT = 1 << 20
 STREAM_ONLY = ("stream.read", "stream.slice", "stream.emit")
-DEVICE_ROUTE = ("cdc.stage", "cdc.scan", "cdc.decode", "stream.select_cuts")
+DEVICE_ROUTE = ("stream.upload", "cdc.scan", "cdc.decode",
+                "stream.select_cuts")
 
 
 def _span_counts() -> dict:
@@ -94,21 +98,32 @@ def test_stream_route_enters_each_span_once_per_segment(make, rng):
     for name in STREAM_ONLY:
         assert delta[name] == rounds, (name, delta)
     rep = obs_profile.report(base)
-    assert set(rep["stream"]) == {"host_prep", "device_wait", "emit"}
+    assert set(rep["stream"]) == {"host_prep", "device_wait", "emit",
+                                  "uploaded_bytes", "host_assembled_bytes"}
     assert rep["stream"]["host_prep"] > 0 and rep["stream"]["emit"] > 0
     if make is TpuBackend:
-        # every round scans what it holds (the carried last chunk at
-        # EOF too) and digests in at least one bucketed batch
+        # every window is uploaded and scanned once (the empty read at
+        # EOF has none: the carry is the last chunk as it stands), and
+        # every round with final chunks digests them in one pass (the
+        # short read may only lengthen the open chunk)
         for name in DEVICE_ROUTE:
-            assert delta[name] == rounds, (name, delta)
-        assert delta["blake3.stage"] == delta["blake3.digest"] >= rounds - 1
+            assert delta[name] == rounds - 1, (name, delta)
+        digests = delta["blake3.digest"]
+        assert delta["blake3.stage"] == digests and \
+            rounds - 1 <= digests <= rounds
         assert rep["stream"]["device_wait"] > 0
-        for name in ("cdc.scan", "blake3.digest", "stream.emit"):
+        for name in ("stream.upload", "cdc.scan", "blake3.digest",
+                     "stream.emit"):
             assert rep["stage_seconds"][name] > 0
+        # one span per step per segment, none per chunk
+        assert len(refs) > 20 * rounds
+        assert sum(delta.values()) == 3 * rounds + 4 * (rounds - 1) \
+            + 2 * digests
     else:
         assert all(delta[n] == 0 for n in DEVICE_ROUTE + ("blake3.stage",
                                                           "blake3.digest"))
         assert rep["stream"]["device_wait"] == 0
+        assert rep["stream"]["uploaded_bytes"] == 0
 
 
 @pytest.mark.parametrize("make", [CpuBackend, TpuBackend],
@@ -152,6 +167,92 @@ def test_digest_many_of_other_callers_enters_no_blake3_span(rng):
     after = _span_counts()
     assert after["blake3.stage"] == before["blake3.stage"]
     assert after["blake3.digest"] == before["blake3.digest"]
+
+
+def test_resident_route_uploads_each_byte_once(rng):
+    """``uploaded_bytes`` is everything the route put on the device for
+    the stream (window blocks, chunk rows): the stream's length within
+    2 %, where the old route uploaded it ~4.3 times.  What the host had
+    to copy together is the chunks that straddle two windows."""
+    data = rng.randbytes(4 * BIG_SEGMENT)
+    base = obs_profile.baseline()
+    refs = TpuBackend(SMALL).manifest_stream(_reader(data),
+                                             segment_bytes=BIG_SEGMENT)
+    stream = obs_profile.report(base)["stream"]
+    assert len(data) <= stream["uploaded_bytes"] <= 1.02 * len(data)
+    edges = [k * BIG_SEGMENT for k in range(1, 4)]
+    straddling = sum(r.length for r in refs for e in edges
+                     if r.offset < e < r.offset + r.length)
+    assert stream["host_assembled_bytes"] == straddling > 0
+
+
+def _tpu_packer(tmp_path, name: str, **kw) -> DirPacker:
+    out = tmp_path / name
+    out.mkdir()
+    return DirPacker(TpuBackend(SMALL), PackfileWriter(KEYS, out),
+                     BlobIndex(KEYS, tmp_path / f"{name}.idx"), **kw)
+
+
+def _pack_one_file(tmp_path, name: str, data: bytes) -> dict:
+    """Pack a tree holding one streamed file; the pipeline report."""
+    src = tmp_path / f"{name}.src"
+    src.mkdir()
+    (src / "image").write_bytes(data)
+    packer = _tpu_packer(tmp_path, name, batch_bytes=BIG_SEGMENT)
+    base = obs_profile.baseline()
+    packer.pack(src)
+    packer.writer.shutdown()
+    assert packer.stats.bytes_read == len(data)
+    return obs_profile.report(base)
+
+
+def test_resident_route_names_its_time_and_compiles_once(tmp_path, rng):
+    """The three groups name at least 97 % of ``stream.file``, and the
+    route's programs are a function of ``segment_bytes`` alone: the first
+    streamed file compiles them all, a second of another length (other
+    upload blocks, other chunk counts, other tile heights) none."""
+    first = _pack_one_file(tmp_path, "a", rng.randbytes(3 * BIG_SEGMENT))
+    second = _pack_one_file(
+        tmp_path, "b", rng.randbytes(4 * BIG_SEGMENT + 333_333))
+    assert second["compile_s"] == {}, second["compile_s"]
+    for rep in (first, second):
+        assert rep["stage_seconds"]["stream.upload"] > 0
+    named = sum(second["stream"][g]
+                for g in ("host_prep", "device_wait", "emit"))
+    assert named >= 0.97 * second["stage_seconds"]["stream.file"]
+
+
+def test_streamed_map_closes_with_no_device_array_over_it(tmp_path, rng,
+                                                          monkeypatch):
+    """On the CPU backend ``device_put`` of aligned host memory aliases
+    it.  When ``_pack_file_streaming`` lets go of the map, closing it
+    raises no ``BufferError`` (which the packer would swallow) and no
+    live device array points into it."""
+    import mmap
+
+    import jax
+
+    closes = []
+
+    class WatchedMap(mmap.mmap):
+        def close(self):
+            probe = np.frombuffer(self, dtype=np.uint8)
+            lo, hi = probe.ctypes.data, probe.ctypes.data + probe.size
+            del probe
+            over = [a.shape for a in jax.live_arrays()
+                    if not a.is_deleted()
+                    and lo <= a.unsafe_buffer_pointer() < hi]
+            try:
+                super().close()
+                closes.append(("closed", over))
+            except BufferError:
+                closes.append(("BufferError", over))
+                raise
+
+    monkeypatch.setattr(mmap, "mmap", WatchedMap)
+    rep = _pack_one_file(tmp_path, "m", rng.randbytes(2 * BIG_SEGMENT + 5))
+    assert rep["stream"]["uploaded_bytes"] >= 2 * BIG_SEGMENT
+    assert closes == [("closed", [])]
 
 
 def _packer(tmp_path, **kw) -> DirPacker:
