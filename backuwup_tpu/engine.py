@@ -1688,14 +1688,33 @@ class Engine:
         into k+m shard containers and save the challenge table of every
         shard still to be placed.  ``tid``: the backup's trace id
         (contextvars do not cross run_in_executor)."""
-        with obs_trace.bind(tid):
+        with obs_trace.bind(tid), obs_profile.send_stage(len(data)):
             with obs_trace.span("send.rs_encode"):
-                containers = rs_stripe.split_packfile(data, k, m,
-                                                      self.backend)
+                stripe = self.backend.encode_stripe(
+                    data, k, m,
+                    [i for i in missing if not self.challenge_tables.has(
+                        rs_stripe.shard_id(pid, i))])
             with obs_trace.span("send.challenge_tables"):
-                for i in missing:
-                    self._save_shard_challenge_table(pid, i, containers[i])
-        return containers
+                self._save_shard_challenge_tables(pid, stripe)
+        return stripe.containers
+
+    def _save_shard_challenge_tables(self, pid: bytes, stripe) -> None:
+        """The audit tables a coded stripe was asked for, each keyed by
+        its 13-byte shard id, saved while the stripe is local.  Failure
+        degrades auditing, not backup."""
+        try:
+            tables = stripe.challenge_tables()
+        except Exception as e:
+            self._log(f"challenge tables for packfile"
+                      f" {bytes(pid).hex()[:8]} failed: {e}")
+            return
+        for i, table in tables.items():
+            sid = rs_stripe.shard_id(pid, i)
+            try:
+                self.challenge_tables.save(sid, table)
+            except Exception as e:
+                self._log(f"challenge table for shard {sid.hex()[:8]}"
+                          f" failed: {e}")
 
     def _save_shard_challenge_table(self, pid: bytes, index: int,
                                     container: bytes) -> None:

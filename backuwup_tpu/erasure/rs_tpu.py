@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import profile as obs_profile
 from . import gf_cpu
 
 
@@ -64,7 +65,9 @@ def gf_matmul_stripes(mat: np.ndarray, stripes: np.ndarray) -> np.ndarray:
     pad = _length_bucket(ln) - ln
     if pad:
         stripes = np.pad(stripes, ((0, 0), (0, 0), (0, pad)))
+    obs_profile.device_upload(mat.nbytes + stripes.nbytes)
     out = _matmul_batched()(jnp.asarray(mat), jnp.asarray(stripes))
+    obs_profile.device_wait()
     return np.asarray(jax.device_get(out), dtype=np.uint8)[:, :, :ln]
 
 

@@ -22,6 +22,7 @@ repair.
 
 from __future__ import annotations
 
+import os
 import struct
 from dataclasses import dataclass
 from pathlib import Path
@@ -66,12 +67,17 @@ class Shard:
     payload: bytes
 
 
+def shard_prefix(index: int, k: int, m: int, orig_len: int) -> bytes:
+    """A container's first 16 bytes: all of its header but the digest."""
+    return (MAGIC + bytes([VERSION, index, k, m])
+            + struct.pack("<Q", orig_len))
+
+
 def pack_shard(index: int, k: int, m: int, orig_len: int, digest: bytes,
                payload: bytes) -> bytes:
     if len(digest) != DIGEST_LEN:
         raise StripeError("bad shard digest length")
-    return (MAGIC + bytes([VERSION, index, k, m])
-            + struct.pack("<Q", orig_len) + digest + payload)
+    return shard_prefix(index, k, m, orig_len) + digest + payload
 
 
 def parse_shard(blob: bytes) -> Shard:
@@ -101,6 +107,29 @@ def split_packfile(data: bytes, k: int, m: int, backend) -> List[bytes]:
     digests = backend.digest_many(payloads)
     return [pack_shard(i, k, m, len(data), digests[i], payloads[i])
             for i in range(k + m)]
+
+
+class Stripe:
+    """One packfile coded for placement: its k + m shard ``containers``,
+    and the audit tables of the ``missing`` shards, the ones about to be
+    placed, built while the containers are local.  This is the host
+    composition every backend can run (:func:`split_packfile`, then one
+    ``build_challenge_table`` a shard); ``ChunkerBackend.encode_stripe``
+    hands it out, and a backend that keeps the packfile on its device
+    hands out its own with the same two members."""
+
+    def __init__(self, data: bytes, k: int, m: int, backend,
+                 missing: Sequence[int] = (), rand=os.urandom):
+        self._backend, self._rand = backend, rand
+        self._missing = [int(i) for i in missing]
+        self.containers = split_packfile(data, k, m, backend)
+
+    def challenge_tables(self) -> Dict[int, list]:
+        """{shard index: its ``ChallengeEntry`` table} for ``missing``."""
+        from ..audit.challenge import build_challenge_table
+        return {i: build_challenge_table(
+                    self._backend, self.containers[i], rand=self._rand)
+                for i in self._missing}
 
 
 def collect_shards(containers: Iterable[bytes], backend,

@@ -44,7 +44,9 @@ defaults only, neither jax nor numpy.
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+import contextlib
+import threading
+from typing import Dict, Iterator, Optional
 
 from . import journal as _journal
 from . import metrics as _metrics
@@ -181,6 +183,25 @@ _STREAM_BYTES = _metrics.counter(
     "Bytes the resident streaming route uploaded to the device, and "
     "bytes it assembled on the host, per streamed file",
     labelnames=("kind",))
+
+# What the send stage moves for the packfiles it codes: ``packfile`` is
+# the bytes of every packfile whose stripe it coded and audited,
+# ``uploaded`` every byte it put on the device for them (the shard
+# matrix, digest batches, window rows), whichever route the backend
+# takes.  ``bkw_send_dispatches_total`` counts the times a send-stage
+# thread waited for the device to hand a result down.  All three count
+# only inside :func:`send_stage`, so the same seams' other callers (the
+# packer's seal-time table, repair) stay out.
+SEND_BYTE_KINDS = ("uploaded", "packfile")
+_SEND_BYTES = _metrics.counter(
+    "bkw_send_bytes_total",
+    "Packfile bytes the send stage coded into stripes, and bytes it "
+    "uploaded to the device to do so", labelnames=("kind",))
+_SEND_DISPATCHES = _metrics.counter(
+    "bkw_send_dispatches_total",
+    "Blocking device round trips of the send stage (upload, programs, "
+    "download awaited)")
+_send_thread = threading.local()
 
 # Span names whose bkw_span_seconds sums a pipeline report attributes as
 # per-stage wall time: the batched route's dispatch/collect pairs and
@@ -327,6 +348,34 @@ def stream_bytes(kind: str, n: int) -> None:
         _STREAM_BYTES.inc(n, kind=kind)
 
 
+@contextlib.contextmanager
+def send_stage(packfile_bytes: int) -> Iterator[None]:
+    """The calling thread codes one packfile of ``packfile_bytes`` for
+    the send stage: until the block ends, what it stages on the device
+    (:func:`device_upload`) and each wait for a result
+    (:func:`device_wait`) is the send stage's."""
+    _SEND_BYTES.inc(packfile_bytes, kind="packfile")
+    _send_thread.depth = getattr(_send_thread, "depth", 0) + 1
+    try:
+        yield
+    finally:
+        _send_thread.depth -= 1
+
+
+def device_upload(n: int) -> None:
+    """``n`` bytes staged on the device by the digest and erasure seams
+    the send stage codes through; counted where :func:`send_stage` is
+    open on this thread."""
+    if n and getattr(_send_thread, "depth", 0):
+        _SEND_BYTES.inc(n, kind="uploaded")
+
+
+def device_wait() -> None:
+    """The calling thread is about to wait for a device result."""
+    if getattr(_send_thread, "depth", 0):
+        _SEND_DISPATCHES.inc()
+
+
 # --- which step recompiled ----------------------------------------------------
 
 def jit_compiled(fun: str, seconds: float) -> None:
@@ -376,6 +425,9 @@ def baseline() -> Dict[str, Dict[str, float]]:
     out["tier"] = tier
     out["stream_bytes"] = {k: _STREAM_BYTES.value(kind=k)
                            for k in STREAM_BYTE_KINDS}
+    out["send"] = {f"{k}_bytes": _SEND_BYTES.value(kind=k)
+                   for k in SEND_BYTE_KINDS}
+    out["send"]["dispatches"] = _SEND_DISPATCHES.value()
     spans = _metrics.registry().get("bkw_span_seconds")
     if spans is not None:
         for name in REPORT_SPANS:
@@ -436,6 +488,7 @@ def report(base: Optional[dict] = None) -> dict:
         "pad_efficiency": efficiency,
         "stage_seconds": stage_seconds,
         "stream": stream,
+        "send": {k: int(v) for k, v in _delta("send").items()},
         "compile_s": compile_s,
         "compile_total_s": round(sum(compile_s.values()), 6),
     }

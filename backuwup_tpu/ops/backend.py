@@ -17,12 +17,15 @@ only the sequential CPU form (``dir_packer.rs:246-311``); here:
 
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass
 from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
+from .. import defaults
 from ..erasure import gf_cpu
+from ..erasure.stripe import Stripe
 from ..obs import profile as obs_profile
 from ..obs import trace as obs_trace
 from .blake3_cpu import blake3_many
@@ -75,6 +78,18 @@ class ChunkerBackend:
         cols = sorted(set(int(i) for i in present))
         rec = gf_cpu.decode_matrix(k, m, cols)[:, cols]
         return np.stack([gf_cpu.gf_matmul(rec, s) for s in stripes])
+
+    def encode_stripe(self, data: bytes, k: int, m: int,
+                      missing: Sequence[int] = (),
+                      rand=os.urandom) -> Stripe:
+        """The send stage's executor-thread half of one sealed packfile:
+        its k + m shard ``containers``, coded when this returns, and
+        ``challenge_tables()`` for the audit tables of the ``missing``
+        shards, the ones about to be placed (nonces and windows from
+        ``rand``).  Here the host composition over ``encode_shards`` and
+        ``digest_many``; :class:`TpuBackend` keeps the packfile on the
+        device instead.  Byte-identical containers either way."""
+        return Stripe(data, k, m, self, missing, rand)
 
     def manifest_many(self, streams: Sequence[bytes]) -> List[List[ChunkRef]]:
         """Chunk + fingerprint a batch of streams in one pipeline pass.
@@ -254,9 +269,10 @@ class TpuBackend(ChunkerBackend):
     HBM once and runs scan -> cut -> HBM-to-HBM chunk gather -> batched
     digest (:meth:`DevicePipeline.manifest_batch`) — no per-chunk host
     slicing; ``manifest_stream`` does the same for one resident segment
-    of a long file at a time.  ``chunk``/``digest_many`` remain as the
-    op-level seams the parity tests pin (``digest_many`` also serves the
-    send stage)."""
+    of a long file at a time; ``encode_stripe`` keeps a sealed packfile
+    resident while the send stage codes and audits it.  ``chunk``/
+    ``digest_many`` remain as the op-level seams the parity tests pin
+    (``digest_many`` also serves the seal-time audit table and repair)."""
 
     name = "tpu"
 
@@ -386,6 +402,25 @@ class TpuBackend(ChunkerBackend):
     def decode_shards(self, stripes, k, m, present):
         from ..erasure import rs_tpu
         return rs_tpu.decode_stripes(stripes, k, m, present)
+
+    def encode_stripe(self, data, k, m, missing=(), rand=os.urandom):
+        """The base method's contract with the packfile resident on the
+        device (:mod:`..erasure.resident`): uploaded once, RS-coded and
+        its shard rows digested where they lie, its audit windows
+        gathered out of HBM; two waits for the device a packfile.  The
+        route's programs are warmed at the first stripe, for every
+        length a sealed packfile has (the target size plus the blob
+        that crossed it)."""
+        from ..erasure import resident as stripe_resident
+
+        bucket = stripe_resident.shard_bucket(
+            gf_cpu.shard_len(len(data), k))
+        if bucket is None:  # past k x the largest digest class
+            return super().encode_stripe(data, k, m, missing, rand)
+        stripe_resident.warm(
+            k, m, defaults.PACKFILE_TARGET_SIZE + self.params.max_size)
+        return stripe_resident.ResidentStripe(data, k, m, bucket,
+                                              missing, rand)
 
     def manifest_many(self, streams):
         results = self.pipeline.manifest_batch(streams)

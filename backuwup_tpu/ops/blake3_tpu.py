@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import defaults
+from ..obs import profile as obs_profile
 from .blake3_cpu import (
     BLOCK_LEN,
     CHUNK_END,
@@ -498,8 +499,10 @@ def blake3_many_tpu(datas) -> list:
     datas = list(datas)
     out = [None] * len(datas)
     for idxs, buf, lens, L in bucketed_batches(datas):
-        root = np.asarray(digest_padded(jnp.asarray(buf), jnp.asarray(lens),
-                                        L=L))
+        obs_profile.device_upload(buf.nbytes + lens.nbytes)
+        root = digest_padded(jnp.asarray(buf), jnp.asarray(lens), L=L)
+        obs_profile.device_wait()
+        root = np.asarray(root)
         digests = _root_cv_to_digests(root)
         for row, i in enumerate(idxs):
             out[i] = digests[row]

@@ -212,3 +212,36 @@ def test_resident_stream_route_compiles_at_the_packers_segment(one_chip):
         buf, shape((2, geo.rows), jnp.int32), i32,
         shape((geo.rows, 8), jnp.uint32), B=B, L=L)
     assert _temp_bytes(tile) < 1 * GiB
+
+
+def test_resident_stripe_route_compiles_at_a_sealed_packfiles_bucket(one_chip):
+    """The send stage's resident route (erasure/resident.py) at RS 4+2
+    and the 1 MiB bucket a 3 MiB packfile's shards fall in: the three
+    programs that are the route's own (the heavy ones are
+    ``rs_gf_matmul`` and ``digest_padded`` at shapes ``encode_shards``
+    and ``digest_many`` compile too), each with temporaries of a few
+    containers, not of a digest batch."""
+    from backuwup_tpu.erasure import resident as stripe_resident
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    k, m, rows, count = 4, 2, 8, stripe_resident.COUNT
+    bucket = stripe_resident.shard_bucket(768 << 10)
+    assert bucket == 1 << 20
+    span = stripe_resident._window_span(bucket)
+    i32 = shape((), jnp.int32)
+    stack = stripe_resident._shard_rows.lower(
+        shape((1, k, bucket), jnp.uint8), shape((1, m, bucket), jnp.uint8),
+        rows=rows)
+    assert _temp_bytes(stack) < 16 << 20
+    pieces = stripe_resident._window_pieces.lower(
+        shape((rows, bucket), jnp.uint8),
+        shape((k + m, 16), jnp.uint8), shape((rows, 8), jnp.uint32),
+        shape((k + m, count, 2), jnp.int32),
+        shape((k + m, count, 16), jnp.uint8), i32, span=span, L=256)
+    assert _temp_bytes(pieces) < 16 << 20
+    put = stripe_resident._put_digests.lower(
+        shape(((k + m) * count, 8), jnp.uint32),
+        shape((count, 8), jnp.uint32), i32)
+    assert put.compile().memory_analysis().temp_size_in_bytes < 1 << 20

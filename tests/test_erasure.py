@@ -287,6 +287,62 @@ def test_device_kernel_matches_oracle_on_accelerator(nprng):
     assert np.array_equal(dec, stripes)
 
 
+def _seeded_rand(seed: int):
+    """``os.urandom``'s shape from a seed; the first offset drawn is 17,
+    so one window of the first shard starts inside its 48-byte header."""
+    rng = random.Random(seed)
+    forced = [(17).to_bytes(8, "little")]
+
+    def rand(n: int) -> bytes:
+        return forced.pop() if forced and n == 8 else rng.randbytes(n)
+
+    return rand
+
+
+@pytest.mark.parametrize("missing", [[0, 1, 2, 3, 4, 5], [1, 4]],
+                         ids=["all-six", "one-and-four"])
+@pytest.mark.parametrize(
+    "size", [1, 4096 - 1, 300 << 10, 3 << 20, (3 << 20) + 5])
+def test_resident_stripe_matches_the_host_composition(size, missing, rng):
+    """The device route of ``TpuBackend.encode_stripe`` (erasure/
+    resident.py) against the oracle: containers byte for byte those of
+    ``split_packfile`` on the numpy backend; every audit entry's digest
+    is BLAKE3 of ``nonce || container[offset:offset+length]`` by the CPU
+    oracle, its window inside the container, sixteen a shard, and only
+    for the shards asked; drawn from the same ``rand`` the tables are
+    the host composition's, entry for entry."""
+    from backuwup_tpu.ops.backend import TpuBackend
+    from backuwup_tpu.ops.blake3_cpu import blake3_many
+
+    k, m = defaults.RS_K, defaults.RS_M
+    data = rng.randbytes(size)
+    stripe = TpuBackend(CDCParams.from_desired(4096)).encode_stripe(
+        data, k, m, missing, rand=_seeded_rand(size))
+    assert type(stripe) is not rs_stripe.Stripe
+    expect = rs_stripe.split_packfile(data, k, m, BACKEND)
+    assert stripe.containers == expect
+    tables = stripe.challenge_tables()
+    assert sorted(tables) == missing
+    count = defaults.AUDIT_CHALLENGES_PER_PACKFILE
+    entries = [(i, e) for i in missing for e in tables[i]]
+    assert len(entries) == count * len(missing)
+    for i, e in entries:
+        assert 0 <= e.offset and e.offset + e.length <= len(expect[i])
+        assert e.length == min(defaults.AUDIT_WINDOW_BYTES, len(expect[i]))
+    assert [e.digest for _i, e in entries] == blake3_many(
+        [e.nonce + expect[i][e.offset:e.offset + e.length]
+         for i, e in entries])
+    in_header = tables[missing[0]][0]
+    assert in_header.offset < rs_stripe.HEADER_LEN
+    if len(expect[0]) > defaults.AUDIT_WINDOW_BYTES + 17:
+        assert in_header.offset == 17
+    host = BACKEND.encode_stripe(data, k, m, missing,
+                                 rand=_seeded_rand(size))
+    assert host.containers == expect
+    if size <= 300 << 10:  # the numpy oracle hashes ~1 MiB/s
+        assert host.challenge_tables() == tables
+
+
 # --------------------------------------------------------------------------
 # store: shard_index schema + deterministic peer ordering
 # --------------------------------------------------------------------------

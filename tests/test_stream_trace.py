@@ -6,6 +6,9 @@ What the route enters (``stream.*`` in ``ChunkerBackend.manifest_stream``;
 what the per-backup report makes of them and of the route's two byte
 counters, what ``chunk_hash`` and ``paused`` count, the annotator that
 puts a span on the profiler's host plane, and the compile listener.
+Beside the streamed file's counters, the send stage's: what a packfile's
+stripe uploads and how often it waits for the device, on the host
+composition and on the resident route (``erasure/resident.py``).
 Counts are deltas of ``bkw_span_seconds``: the registry is the process's,
 and other tests feed it too.
 """
@@ -20,18 +23,22 @@ import time
 import numpy as np
 import pytest
 
+from backuwup_tpu import defaults
 from backuwup_tpu.crypto import KeyManager
-from backuwup_tpu.engine import Orchestrator
+from backuwup_tpu.engine import Engine, Orchestrator
+from backuwup_tpu.erasure import stripe as rs_stripe
 from backuwup_tpu.obs import journal as obs_journal
 from backuwup_tpu.obs import metrics as obs_metrics
 from backuwup_tpu.obs import profile as obs_profile
 from backuwup_tpu.obs import trace as obs_trace
 from backuwup_tpu.ops import backend as ops_backend
 from backuwup_tpu.ops.backend import CpuBackend, TpuBackend
+from backuwup_tpu.ops.blake3_cpu import blake3_hash
 from backuwup_tpu.ops.gear import CDCParams
 from backuwup_tpu.snapshot.blob_index import BlobIndex
 from backuwup_tpu.snapshot.packer import DirPacker
 from backuwup_tpu.snapshot.packfile import PackfileWriter
+from backuwup_tpu.store import Store
 
 KEYS = KeyManager.from_secret(bytes(range(32)))
 SMALL = CDCParams.from_desired(4096)
@@ -184,6 +191,73 @@ def test_resident_route_uploads_each_byte_once(rng):
     straddling = sum(r.length for r in refs for e in edges
                      if r.offset < e < r.offset + r.length)
     assert stream["host_assembled_bytes"] == straddling > 0
+
+
+def test_resident_stripe_uploads_a_packfile_once_in_two_dispatches(
+        tmp_path, rng, monkeypatch):
+    """One 3 MiB packfile through the engine's executor-thread half
+    (``_encode_stripe``).  The host composition over the device seams
+    stages 36 MiB in eight waits (4 MiB for RS, 8 MiB of shard payloads,
+    six tables of 4 MiB); the resident route uploads the padded shard
+    matrix and the window rows, under 1.5 bytes a packfile byte, and
+    waits twice: once in each span, which still bound the work."""
+    monkeypatch.setenv("BKW_DEVICE_DEDUP", "0")  # no index in this test
+    store = Store(directory=tmp_path / "cfg", data_base=tmp_path / "data")
+    engine = Engine(KEYS, store, server=None, node=None,
+                    backend=TpuBackend(SMALL))
+    data, k, m = rng.randbytes(3 << 20), 4, 2
+
+    base = obs_profile.baseline()
+    with obs_profile.send_stage(len(data)):
+        host = rs_stripe.Stripe(data, k, m, engine.backend, range(k + m))
+        host.challenge_tables()
+    send = obs_profile.report(base)["send"]
+    assert send["packfile_bytes"] == len(data)
+    assert send["uploaded_bytes"] / len(data) == pytest.approx(12.0,
+                                                               rel=1e-3)
+    assert send["dispatches"] == 8
+
+    engine.backend.encode_stripe(b"warm", k, m)
+    pid = bytes(range(12))
+    base, spans = obs_profile.baseline(), _span_counts()
+    containers = engine._encode_stripe(None, pid, data, k, m,
+                                       list(range(k + m)))
+    rep, after = obs_profile.report(base), _span_counts()
+    assert containers == host.containers
+    assert rep["send"]["packfile_bytes"] == len(data)
+    assert 1.0 < rep["send"]["uploaded_bytes"] / len(data) < 1.5
+    assert rep["send"]["dispatches"] == 2
+    for name in ("send.rs_encode", "send.challenge_tables"):
+        assert after[name] == spans[name] + 1
+        assert rep["stage_seconds"][name] > 0
+    for i in range(k + m):
+        table = engine.challenge_tables.load(rs_stripe.shard_id(pid, i))
+        assert len(table) == defaults.AUDIT_CHALLENGES_PER_PACKFILE
+        e = table[-1]
+        assert e.digest == blake3_hash(
+            e.nonce + containers[i][e.offset:e.offset + e.length])
+    # what other threads stage is not the send stage's
+    base = obs_profile.baseline()
+    engine.backend.digest_many([data[:70000]])
+    assert obs_profile.report(base)["send"] == {
+        "uploaded_bytes": 0, "packfile_bytes": 0, "dispatches": 0}
+    store.close()
+
+
+def test_resident_stripe_programs_are_a_closed_set(rng):
+    """The route's programs are a function of (k, m) and the shard-length
+    bucket alone: after its warm (the first stripe), stripes of ten
+    lengths over every bucket a sealed packfile falls in, with tables
+    for all shards or for some, compile nothing."""
+    backend = TpuBackend(SMALL)
+    backend.encode_stripe(b"warm", 4, 2)
+    base = obs_profile.baseline()
+    for n in (1, 5000, 65536, 65537, 300 << 10, 1 << 20, (1 << 20) + 1,
+              (2 << 20) + 333, 3 << 20, (3 << 20) + (150 << 10)):
+        backend.encode_stripe(rng.randbytes(n), 4, 2,
+                              [1, 4] if n % 2 else range(6)
+                              ).challenge_tables()
+    assert obs_profile.report(base)["compile_s"] == {}
 
 
 def _tpu_packer(tmp_path, name: str, **kw) -> DirPacker:
