@@ -119,7 +119,7 @@ TRANSFER_CHUNK_BYTES = 1 * MiB
 # send, before the failure surfaces to the scheduler as a failed transfer.
 TRANSFER_RESUME_ATTEMPTS = 2
 # False = reconnect attempts restart from byte zero (no RESUME_QUERY);
-# the bench's restart-from-zero baseline leg, never what production wants.
+# a baseline shape, never what production wants (ROADMAP D3b).
 TRANSFER_RESUME_ENABLED = True
 # Adaptive per-transfer deadline (replaces the fixed send/ack timeout pair
 # for sized payloads): budget = ACK_TIMEOUT_S + size / floor, where floor
@@ -141,10 +141,6 @@ TRANSFER_DEADLINE_CAP_S = 600.0
 # redundant pull of a spare shard is launched and the first completion
 # wins — the stall is raced, not waited out.
 RESTORE_HEDGE_DEADLINE_FRACTION = 0.5
-# Re-queue budget for a stalled/failed shard download before the stripe
-# falls back to whole-copy RESTORE_ALL sources (each retry prefers a
-# holder that has not failed this shard yet).
-RESTORE_FETCH_RETRIES = 2
 # Serve-side throttle for RESTORE_FETCH sessions.  Deliberately decoupled
 # from RESTORE_REQUEST_THROTTLE_S and off by default: one multi-source
 # restore legitimately opens several fetch connections to the same holder
@@ -310,10 +306,9 @@ PARTIAL_STORE_TTL_S = 24 * 3600.0
 
 # --- snapshot lifecycle / GC (engine.run_gc, docs/lifecycle.md; no
 # reference equivalent — the reference is append-only) ------------------------
-# Default retention policy recorded into fresh stores.  keep-all keeps
-# every snapshot (the pre-lifecycle behavior); operators narrow it to
-# comma-separated keep-last:N / keep-daily:N rules.
-RETENTION_DEFAULT = "keep-all"
+# Retention: a store with no policy keeps every snapshot
+# (store.apply_retention); operators narrow it to comma-separated
+# keep-last:N / keep-daily:N rules.
 # A packfile whose live-byte fraction (bytes still referenced by some
 # retained snapshot / total payload bytes) drops below this is sparse:
 # GC pulls it back, extracts the live blobs, and re-packs them into
@@ -418,9 +413,6 @@ PEERS_DEBOUNCE_S = 0.25
 PROGRESS_TICKER_S = 0.4
 
 # --- TPU execution tunables (no reference equivalent) -----------------------
-# Device block length for the gear-hash scan: streams are cut into blocks of
-# this many bytes, sharded across devices with a GEAR_WINDOW-1 byte halo.
-TPU_STREAM_BLOCK = 4 * MiB
 # Leaf bucket sizes (in 1 KiB blake3 chunks) used when batching variable-size
 # CDC chunks for fingerprinting; chunks are padded up to the nearest bucket.
 BLAKE3_LEAF_BUCKETS = (16, 64, 256, 1024, 2048, 3072)

@@ -74,7 +74,7 @@ from .snapshot.packer import DirPacker
 from .snapshot.packfile import PackfileReader, PackfileWriter, packfile_path
 from .store import (EVENT_BACKUP, EVENT_GC, EVENT_REPAIR,
                     EVENT_RESTORE_REQUEST, Store)
-from .utils import faults, retry, tracing
+from .utils import faults, retry
 
 
 class EngineError(Exception):
@@ -256,9 +256,8 @@ class Engine:
         # oracle.  On an accelerator backend the mesh is attached by
         # DEFAULT (single axis over every local device) so real runs
         # exercise the HBM table without caller plumbing (SURVEY §7 3e);
-        # BKW_DEVICE_DEDUP=0 opts out.
-        if dedup_mesh is None and getattr(self.backend, "name", "") == "tpu" \
-                and os.environ.get("BKW_DEVICE_DEDUP", "1") != "0":
+        # a caller that wants another mesh passes ``dedup_mesh=``.
+        if dedup_mesh is None and getattr(self.backend, "name", "") == "tpu":
             dedup_mesh = self._default_mesh()
         self.device_dedup = None
         if dedup_mesh is not None:
@@ -304,19 +303,12 @@ class Engine:
     # --- paths -------------------------------------------------------------
 
     def _make_device_dedup(self, mesh):
-        """Device dedup front for ``mesh``: tiered by default.
-
-        The tiered front keeps the HBM table under
-        ``DEDUP_HBM_BUDGET_BYTES`` with the LSM cold tier under the
-        store's data dir absorbing demoted fingerprints
-        (docs/dedup_tiering.md); ``BKW_DEDUP_TIERED=0`` falls back to
-        the grow-only :class:`MeshDedupIndex`.
-        """
-        if os.environ.get("BKW_DEDUP_TIERED", "1") != "0":
-            return TieredDedupIndex(
-                mesh, self.index, cold_dir=self.store.dedup_cold_dir())
-        from .snapshot.device_dedup import MeshDedupIndex
-        return MeshDedupIndex(mesh, self.index)
+        """Device dedup front for ``mesh``: the tiered index, which
+        keeps the HBM table under ``DEDUP_HBM_BUDGET_BYTES`` with the LSM
+        cold tier under the store's data dir absorbing demoted
+        fingerprints (docs/dedup_tiering.md)."""
+        return TieredDedupIndex(
+            mesh, self.index, cold_dir=self.store.dedup_cold_dir())
 
     def _pack_dir(self) -> Path:
         return self.store.packfile_dir()
@@ -1121,24 +1113,18 @@ class Engine:
                                on_blob=lambda h, s: manifest.setdefault(h, s))
             try:
                 with obs_trace.bind(backup_tid), \
-                        tracing.span("engine.pack"), \
-                        tracing.jax_profiler("backup_pack"):
+                        obs_trace.span("engine.pack"), \
+                        obs_trace.jax_profiler("backup_pack"):
                     snapshot_holder["hash"] = packer.pack(root)
                 snapshot_holder["stats"] = packer.stats
             finally:
                 writer.shutdown()
 
-        # BKW_BACKUP_PHASED=1 is the sum(stage) baseline leg the bench
-        # speedup ratio is measured against: the send stage starts only
-        # after the full pack finished, so nothing overlaps the wire.
-        # Default is the streaming dataflow — pack, seal, and send all
-        # concurrently busy, linked by bounded queues (docs/dataflow.md).
-        phased = os.environ.get("BKW_BACKUP_PHASED", "0") == "1"
+        # the streaming dataflow: pack, seal and send all concurrently
+        # busy, linked by bounded queues (docs/dataflow.md)
         wall_t0 = time.monotonic()
         pack_fut = loop.run_in_executor(None, pack_thread)
-        send_task = None
-        if not phased:
-            send_task = asyncio.create_task(self._send_loop(orch, estimate))
+        send_task = asyncio.create_task(self._send_loop(orch, estimate))
         try:
             await pack_fut
             orch.packing_completed = True
@@ -1152,11 +1138,8 @@ class Engine:
             # cancel of this coroutine) must still tear down the send
             # loop instead of leaving it spinning against a dead backup
             orch.failed = True
-            if send_task is not None:
-                send_task.cancel()
+            send_task.cancel()
             raise
-        if send_task is None:
-            send_task = asyncio.create_task(self._send_loop(orch, estimate))
         try:
             await send_task
         except asyncio.CancelledError:
@@ -1177,8 +1160,7 @@ class Engine:
         self.last_overlap = obs_profile.overlap_report(
             {k: stages.get(k, 0.0)
              for k in ("chunk_hash", "seal", "write", "send")},
-            wall_s, mode="phased" if phased else "stream",
-            drain_s=done_t - packed_t)
+            wall_s, drain_s=done_t - packed_t)
         # lineage + manifest commit (one store transaction): parent is
         # the previous retained head, so prune/GC can reason about the
         # chain (docs/lifecycle.md)
@@ -2382,7 +2364,7 @@ class Engine:
                                dedup_index=self.device_dedup)
             try:
                 with obs_trace.bind(repair_tid), \
-                        tracing.span("engine.repair_pack"):
+                        obs_trace.span("engine.repair_pack"):
                     packer.pack(root)
             finally:
                 writer.shutdown()

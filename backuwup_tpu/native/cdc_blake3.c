@@ -5,8 +5,7 @@
  * the role the SIMD `fastcdc` + `blake3` crates play in the reference
  * client (dir_packer.rs:246-311).  Semantics are normative per
  * backuwup_tpu/ops/CDC_SPEC.md and bit-identical to ops/cdc_cpu.py /
- * ops/blake3_cpu.py; parity is asserted by tests and by bench.py before
- * any timing is reported.
+ * ops/blake3_cpu.py; parity is asserted by tests/test_native.py.
  *
  * BLAKE3 is implemented from the public specification (IV, message
  * permutation, flag values, tree structure); no third-party code.

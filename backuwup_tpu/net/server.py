@@ -28,8 +28,8 @@ two swappable planes (docs/server.md):
 
 ``CoordinationServer(legacy=True)`` assembles the pre-PR-10 shape (the
 direct-commit :class:`~.serverstore.ServerDB` plus the single-lock
-:class:`StorageQueue`) as the measured baseline for bench config
-``12_swarm``.
+:class:`StorageQueue`) for the swarm tests' ``legacy=`` legs
+(ROADMAP D3b).
 """
 
 from __future__ import annotations
@@ -202,7 +202,7 @@ class StorageQueue:
     clients with each other.
 
     Retained as the measured baseline for the sharded matchmaker
-    (``CoordinationServer(legacy=True)``, bench config ``12_swarm``) and
+    (``CoordinationServer(legacy=True)``, ``tests/test_swarm.py``) and
     because its semantics tests pin the matchmaking contract both
     implementations honor.  Structural costs, by design: ``_lock`` is
     held across the WHOLE fulfill — db writes and WS pushes included —
@@ -335,8 +335,8 @@ async def _obs_middleware(request, handler):
     (bounded label cardinality — the route table, not raw paths) and
     adopt the client's trace id from the POST JSON so the server-side
     span journals under the same id as the caller's.  The latency lands
-    in ``bkw_server_request_seconds{route}``; the swarm scorecard and
-    bench config 12 read their p99 from its buckets."""
+    in ``bkw_server_request_seconds{route}``; the swarm scorecard reads
+    its p99 from its buckets."""
     resource = request.match_info.route.resource
     path = resource.canonical if resource is not None else request.path
     _REQUESTS.inc(path=path)
@@ -388,7 +388,7 @@ class CoordinationServer:
     ever running a sqlite commit on the event loop.
 
     ``legacy=True`` assembles the pre-PR-10 single-lock shape over a
-    direct-commit store — the bench baseline.  ``store=`` injects any
+    direct-commit store (the swarm tests' baseline leg).  ``store=`` injects any
     other :class:`~.serverstore.ServerStore` implementation.
     """
 
@@ -1092,7 +1092,7 @@ class CoordinationServer:
         registry — all zeros / ``ok`` for a standalone server (the
         server never sees client placement state), and the live
         cross-client durability picture when clients are colocated (the
-        scenario harness, tests, bench).  A violated invariant turns
+        scenario harness, tests).  A violated invariant turns
         the whole document 503 (obs/expo.py)."""
         durability = obs_invariants.summary_from_registry()
         slo = obs_slo.summary_from_registry()

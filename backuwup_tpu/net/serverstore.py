@@ -25,8 +25,8 @@ argument, without the handlers changing.
   stall detector and by :attr:`commit_threads`).
 * **direct** (``write_behind=False``, the :class:`ServerDB` shim) — the
   pre-PR-10 shape: every call executes inline on the calling thread and
-  commits immediately.  Kept as the measured baseline for bench config
-  ``12_swarm`` and for tests that predate the writer thread.  Unlike
+  commits immediately.  Kept for the ``legacy=`` legs of the swarm
+  tests and for tests that predate the writer thread (ROADMAP D3b).  Unlike
   the original, calls are serialized under an RLock: the original
   shared one ``check_same_thread=False`` connection across threads with
   no serialization at all (the latent bug this PR's regression test
@@ -36,8 +36,8 @@ Fsync discipline follows ``utils/durable.py`` semantics: when
 ``durable.FSYNC_ENABLED`` (the ``BKW_FSYNC`` switch) a file-backed
 database runs ``PRAGMA synchronous=FULL`` so a group commit is a real
 durability barrier; with fsync disabled it drops to ``NORMAL`` (the
-pure-tmpfs test posture).  Both store modes apply the same pragma so the
-bench's baseline-vs-sharded comparison is durability-for-durability.
+pure-tmpfs test posture).  Both store modes apply the same pragma so a
+legacy-vs-sharded comparison is durability-for-durability.
 """
 
 from __future__ import annotations
@@ -259,7 +259,7 @@ class _AioFacade:
     the coroutine resumes only after the writer thread's group commit.
     Direct mode: runs the sync method inline on the event loop —
     deliberately preserving the pre-PR-10 blocking-commit behavior for
-    the bench baseline.
+    the ``legacy=`` legs.
     """
 
     def __init__(self, store: "SqliteServerStore"):
@@ -296,7 +296,7 @@ class SqliteServerStore(ServerStore):
         if path != ":memory:":
             self._db.execute("PRAGMA journal_mode=WAL")
             # Federation opens the same partition files from several
-            # store instances (node revive, multi-process bench legs):
+            # store instances (node revive, multi-process load legs):
             # wait out a sibling's group commit instead of raising
             # "database is locked" into a request handler.
             self._db.execute("PRAGMA busy_timeout=5000")
@@ -672,7 +672,7 @@ class ServerDB(SqliteServerStore):
     Everything executes inline on the calling thread with an immediate
     commit (now under a lock — the original shared its connection across
     threads unserialized).  ``CoordinationServer(legacy=True)`` and the
-    bench's single-lock baseline leg use this; new code wants
+    swarm tests' single-lock leg use this; new code wants
     :class:`SqliteServerStore`.
     """
 

@@ -10,8 +10,7 @@ reference prints ad-hoc lines and keeps no machine-readable telemetry):
   trace/span ids, propagated across the wire (p2p ``EncapsulatedMsg``
   and client<->server JSON messages) so one backup's
   pack -> seal -> transfer -> ack -> audit chain is joinable across
-  processes; subsumes :mod:`backuwup_tpu.utils.tracing` (kept as thin
-  wrappers);
+  processes;
 * :mod:`~backuwup_tpu.obs.journal` — a size-rotated append-only JSONL
   journal of status events, span closes, retry firings, and fault-plane
   injections, with a panic handler that dumps the metrics snapshot plus

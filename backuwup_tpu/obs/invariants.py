@@ -341,7 +341,7 @@ class InvariantMonitor:
 def summary_from_registry() -> dict:
     """Cross-client durability summary from the process registry — what
     the coordination server's ``/healthz`` reports when clients are
-    colocated (the scenario harness, tests, bench), and all zeros /
+    colocated (the scenario harness, tests), and all zeros /
     ``ok`` in a standalone server process.  Counts sum across client
     labels; status and audit age take the worst."""
     out = {key: 0 for key in _FACT_GAUGES}

@@ -16,7 +16,7 @@ framework needs only a fraction of it):
 * **Two read paths.**  :meth:`Registry.render_prometheus` emits the
   text exposition format (``# HELP``/``# TYPE`` + samples, histograms
   as cumulative ``_bucket``/``_sum``/``_count``) for ``GET /metrics``;
-  :meth:`Registry.snapshot` returns a plain-JSON dict for bench records,
+  :meth:`Registry.snapshot` returns a plain-JSON dict for scorecards,
   panic dumps, and ``scripts/obs_dump.py``.
 
 Histograms are log-bucketed (:func:`log_buckets`): stage times in this

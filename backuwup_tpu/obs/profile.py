@@ -228,9 +228,9 @@ REPORT_SPANS = (
 
 # Streaming-dataflow overlap families (the engine's stage graph,
 # docs/dataflow.md): per-stage busy seconds attributed to one backup at
-# end of run, plus the overlap-efficiency verdict the bench
-# `20_dataflow` gate watches.  Declared here — the single construction
-# site for every bkw_* family — and folded by :func:`overlap_report`.
+# end of run, plus the overlap-efficiency verdict.  Declared here — the
+# single construction site for every bkw_* family — and folded by
+# :func:`overlap_report`.
 _BACKUP_STAGE_BUSY = _metrics.counter(
     "bkw_backup_stage_busy_seconds_total",
     "Busy seconds per backup dataflow stage (chunk_hash / seal / write /"
@@ -465,7 +465,7 @@ def report(base: Optional[dict] = None) -> dict:
                  for fun, dt in _delta("compile_s").items() if dt > 0}
     # per-device split of the mesh-pipeline launches: {device: {stage: n}}
     # plus per-device pad efficiency, so the report shows whether work
-    # divided evenly across the shards (the bench even-split gate)
+    # divided evenly across the shards (tests/test_mesh_pipeline.py)
     by_device: Dict[str, Dict[str, int]] = {}
     eff_device: Dict[str, Dict[str, Optional[float]]] = {}
     prior_d = base.get("dispatch_dev", {})
@@ -522,7 +522,7 @@ def emit_report(rep: dict, **fields) -> None:
 
 
 def overlap_report(stage_busy: Dict[str, float], wall_s: float,
-                   mode: str = "stream", drain_s: float = 0.0) -> dict:
+                   drain_s: float = 0.0) -> dict:
     """Fold one backup's per-stage busy seconds into the overlap
     families and return the summary row the engine stores + journals.
 
@@ -530,9 +530,9 @@ def overlap_report(stage_busy: Dict[str, float], wall_s: float,
     idle/wait accumulators (pack stall, transfer admission wait), which
     would otherwise reward a stalled pipeline.  Efficiency is
     max(stage)/wall: 1.0 means the end-to-end wall clock collapsed onto
-    the slowest stage (perfect overlap); a phased run trends toward
-    max/sum.  Concurrent fan-out can legitimately push a stage's summed
-    busy seconds past the wall, so values above 1.0 are kept as-is.
+    the slowest stage (perfect overlap).  Concurrent fan-out can
+    legitimately push a stage's summed busy seconds past the wall, so
+    values above 1.0 are kept as-is.
     ``drain_s`` is what the send stage added after the packer was done:
     from the last blob packed to the last packfile acked."""
     busy = {k: max(float(v), 0.0) for k, v in stage_busy.items()}
@@ -543,7 +543,6 @@ def overlap_report(stage_busy: Dict[str, float], wall_s: float,
     eff = (max_stage / wall_s) if wall_s > 0 else 0.0
     _BACKUP_OVERLAP.set(eff)
     rep = {
-        "mode": mode,
         "wall_s": round(wall_s, 6),
         "drain_s": round(drain_s, 6),
         "stage_busy_s": {k: round(v, 6) for k, v in busy.items()},

@@ -1,6 +1,4 @@
-"""Hierarchical spans with Dapper-style trace/span ids.
-
-:mod:`backuwup_tpu.utils.tracing` re-exports this module.  Every span
+"""Hierarchical spans with Dapper-style trace/span ids.  Every span
 
 * carries a **trace id** (64-bit hex) inherited from the enclosing span
   via a contextvar — ``asyncio.create_task`` copies the context, so the

@@ -5,8 +5,7 @@ manifests (cut points + BLAKE3 fingerprints); everything above it — snapshot
 builder, packfiles, peer exchange — is backend-agnostic.  The reference has
 only the sequential CPU form (``dir_packer.rs:246-311``); here:
 
-* :class:`CpuBackend` — the numpy oracle pipeline (also the honest baseline
-  for the 10x target; see ``bench.py``).
+* :class:`CpuBackend` — the numpy oracle pipeline.
 * :class:`TpuBackend` — device gear-scan (:mod:`.cdc_tpu`) + batched
   device BLAKE3 (:mod:`.blake3_tpu`).  Files are processed as batches so
   fingerprinting amortizes into a few bucketed compiles.

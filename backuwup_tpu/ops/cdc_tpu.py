@@ -24,8 +24,7 @@ decomposition designed for XLA/TPU:
   (:func:`backuwup_tpu.ops.cdc_cpu.select_cuts`), so TPU and CPU chunking
   are bit-identical by construction.
 * Long streams are processed in bounded segments with a 31-byte carried halo
-  (sequence-parallel blockwise decomposition); across a device mesh the halo
-  travels over ICI via ``ppermute`` (:func:`make_sharded_scanner`).
+  (sequence-parallel blockwise decomposition).
 """
 
 from __future__ import annotations
@@ -36,8 +35,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
-from jax.sharding import PartitionSpec as P
 
 from .. import defaults
 from .cdc_cpu import cuts_to_chunks, select_cuts
@@ -265,41 +262,6 @@ class TpuCdcScanner:
         return cuts_to_chunks(select_cuts(pos_s, pos_l, n, self.params))
 
 
-# ---------------------------------------------------------------------------
-# Batched scan with single-transfer sparse output: the CDC candidate front
-# end for whole file batches, one dispatch + ONE device->host download.
-# ---------------------------------------------------------------------------
-
-
-@functools.partial(jax.jit, static_argnames=("mask_s", "mask_l", "k_cap"))
-def scan_words_batch(ext_b: jnp.ndarray, nv_b: jnp.ndarray,
-                     *, mask_s: int, mask_l: int,
-                     k_cap: int) -> jnp.ndarray:
-    """``(B, _HALO+P) u8 -> (B, 1+3*k_cap) i32`` packed sparse candidates.
-
-    Per row: ``[nz_words, widx..., words_l..., words_s...]`` — the same
-    two-level sparse structure as :func:`_scan_segment`, but all outputs
-    packed into ONE array so a whole batch costs a single device->host
-    transfer (every transfer pays a fixed latency).  Host-side cut
-    selection then runs the oracle's ``select_cuts`` verbatim.
-    """
-    ms = jnp.uint32(mask_s)  # static -> folded constants, no upload
-    ml = jnp.uint32(mask_l)
-
-    def one(ext, n):
-        h = _hash_ext_fast(ext)
-        words_l, words_s = _candidate_words(h, n, ms, ml)
-        nz = words_l != 0
-        (widx,) = jnp.nonzero(nz, size=k_cap, fill_value=-1)
-        nz_words = jnp.sum(nz.astype(jnp.int32))
-        safe = jnp.clip(widx, 0, words_l.shape[0] - 1)
-        return jnp.concatenate([
-            nz_words[None], widx.astype(jnp.int32),
-            words_l[safe].astype(jnp.int32), words_s[safe].astype(jnp.int32)])
-
-    return jax.vmap(one)(ext_b, nv_b)
-
-
 def _block_cum(pos, padded: int, bb: int):
     """Exclusive prefix counts of candidates per ``2^bb``-byte block.
 
@@ -385,10 +347,8 @@ def _parallel_select(pos_l, pos_s, n, *, min_size: int, desired_size: int,
       candidate/terminal cut.
 
     Replaces a ``cut_cap``-iteration ``lax.while_loop`` whose per-step
-    latency dominated small-chunk configs (measured 389 ms of 481 ms for
-    64 KiB chunks on a 256 MiB segment).  Bit-identical to
-    :func:`backuwup_tpu.ops.cdc_cpu.select_cuts` (property-tested; bench
-    parity gate end-to-end).
+    latency dominated small-chunk configs.  Bit-identical to
+    :func:`backuwup_tpu.ops.cdc_cpu.select_cuts` (property-tested).
     """
     m = jnp.int32(min_size)
     d = jnp.int32(desired_size)
@@ -669,107 +629,3 @@ def scan_select_batch(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
         wl_b, ws_b = jax.vmap(words_one)(ext_b, nv_i)
 
     return jax.vmap(one)(nv_i, wl_b, ws_b)
-
-
-def unpack_scan_words(row, k_cap: int):
-    """One packed row -> (nz_words, widx, wl(u32), ws(u32)) numpy views."""
-    nz = int(row[0])
-    widx = row[1:1 + k_cap]
-    wl = row[1 + k_cap:1 + 2 * k_cap].astype(np.int64).astype(np.uint32)
-    ws = row[1 + 2 * k_cap:1 + 3 * k_cap].astype(np.int64).astype(np.uint32)
-    return nz, widx, wl, ws
-
-
-# ---------------------------------------------------------------------------
-# Sharded long-stream scan: blockwise over a device mesh, halo over ICI.
-# ---------------------------------------------------------------------------
-
-
-@functools.lru_cache(maxsize=32)
-def make_sharded_scanner(mesh: Mesh, axis: str = "data", *,
-                         k_cap_per_shard: int = 4096):
-    """Build a jitted scanner that shards one long stream across ``mesh``.
-
-    The stream (length divisible by the mesh axis size) is split into
-    per-device shards; each device hashes its shard using the 31-byte tail of
-    its left neighbour, exchanged over ICI with ``lax.ppermute`` — the CDC
-    analog of ring-attention's block decomposition (SURVEY.md section 5.7).
-
-    Returns ``scan(stream_u8, n_valid, mask_s, mask_l) ->
-    (widx, wl, ws, nz_words)`` with a leading per-device axis; ``widx`` are
-    *absolute* word indices into the stream (-1 pad).
-    """
-    n_dev = mesh.shape[axis]
-
-    def shard_fn(local, n_valid, mask_s, mask_l):
-        idx = jax.lax.axis_index(axis)
-        shard_len = local.shape[0]
-        # left neighbour's tail rides the ring: shard i sends its last 31
-        # bytes to shard i+1.
-        tail = jax.lax.ppermute(
-            local[-_HALO:], axis,
-            perm=[(i, (i + 1) % n_dev) for i in range(n_dev)])
-        # shard 0 receives the last shard's tail — garbage, but it only
-        # perturbs h[0..30], positions that can never be cuts (min_size > 31)
-        ext = jnp.concatenate([tail, local])
-        start = idx.astype(jnp.int32) * shard_len
-        h = _hash_ext_fast(ext)
-        words_l, words_s = _candidate_words(h, n_valid - start, mask_s, mask_l)
-        nz = words_l != 0
-        (widx,) = jnp.nonzero(nz, size=k_cap_per_shard, fill_value=-1)
-        nz_words = jnp.sum(nz.astype(jnp.int32))
-        safe = jnp.clip(widx, 0, words_l.shape[0] - 1)
-        abs_widx = jnp.where(widx >= 0, widx + start // 32, widx)
-        return (abs_widx[None], words_l[safe][None], words_s[safe][None],
-                nz_words[None])
-
-    mapped = jax.shard_map(
-        shard_fn, mesh=mesh,
-        in_specs=(P(axis), P(), P(), P()),
-        out_specs=(P(axis), P(axis), P(axis), P(axis)),
-    )
-    return jax.jit(mapped)
-
-
-def chunk_stream_sharded(data, mesh: Mesh, params: Optional[CDCParams] = None,
-                         axis: str = "data", k_cap: Optional[int] = None):
-    """Host convenience: chunk one long stream across all devices of ``mesh``.
-
-    Bit-identical to the CPU oracle; used by tests and the multi-chip dryrun.
-    ``k_cap`` overrides the per-shard sparse capacity (tests force overflow).
-    """
-    params = params or CDCParams()
-    if params.min_size < GEAR_WINDOW:
-        raise ValueError(f"TPU chunker requires min_size >= {GEAR_WINDOW}")
-    n = len(data)
-    if n >= 2**31:
-        # positions are tracked in (x64-disabled) int32 on device; larger
-        # streams go through the segmented scanner, which is still exact.
-        return TpuCdcScanner(params).chunk_stream(data)
-    n_dev = mesh.shape[axis]
-    padded = _round_up(max(n, 1), n_dev * 1024)
-    buf = np.zeros(padded, dtype=np.uint8)
-    buf[:n] = np.frombuffer(bytes(data), dtype=np.uint8)
-    # nearly every sparse candidate lands in its own 32-bit word, so size
-    # capacity by candidate count, not candidate/32
-    if k_cap is None:
-        k_cap = max(512, _round_up(
-            16 * max(1, (padded // n_dev) >> params.mask_l_bits), 512))
-    scan = make_sharded_scanner(mesh, axis, k_cap_per_shard=k_cap)
-    stream = jax.device_put(jnp.asarray(buf), NamedSharding(mesh, P(axis)))
-    widx, wl, ws, nz_words = scan(stream, jnp.int32(n),
-                                  jnp.uint32(params.mask_s),
-                                  jnp.uint32(params.mask_l))
-    if (np.asarray(nz_words) > k_cap).any():  # overflow: oracle, still exact
-        from .cdc_cpu import chunk_stream as cpu_chunk
-        return cpu_chunk(data, params)
-    pos_parts, s_parts = [], []
-    for d in range(n_dev):
-        p, s = _decode_words(widx[d], wl[d], ws[d], k_cap, 0)
-        pos_parts.append(p)
-        s_parts.append(s)
-    pos_l = np.concatenate(pos_parts)
-    is_s = np.concatenate(s_parts)
-    order = np.argsort(pos_l, kind="stable")
-    pos_l, is_s = pos_l[order], is_s[order]
-    return cuts_to_chunks(select_cuts(pos_l[is_s], pos_l, n, params))

@@ -156,14 +156,6 @@ class ShardedDedupIndex:
             self.keys, self.values, q_dev, v_dev)
         return found, lost
 
-    def probe_device(self, q_dev):
-        """Device-resident probe: dispatch WITHOUT host synchronization;
-        returns the sharded found-vector as a device array (``value+1``
-        if present else 0).  The steady-state read path: sustained
-        global-dedup queries chain on device back to back, the caller
-        downloads results when (and only when) it needs them."""
-        return self._fn(False)(self.keys, self.values, q_dev)
-
     def grown(self, new_capacity: int) -> "ShardedDedupIndex":
         """Capacity-doubled (or more) copy with the resident keys
         re-hashed ON DEVICE — shard routing depends only on the hash

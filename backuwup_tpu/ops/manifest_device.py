@@ -43,13 +43,6 @@ from .gear import CDCParams
 CHUNK_LEN = 1024
 
 
-def _pow2_at_least(n: int) -> int:
-    b = 1
-    while b < n:
-        b *= 2
-    return b
-
-
 @functools.lru_cache(maxsize=16)
 def _length_histogram(params: CDCParams) -> Tuple[float, Tuple[float, ...]]:
     """(mean_chunk_len, fraction per pow2 leaf class), computed

@@ -1,7 +1,7 @@
 """Device-resident dedup pipeline: scan+select -> gather chunks -> digest.
 
-Composes the TPU kernels into the full chunk+hash step that ``bench.py``
-times and ``chip_smoke.py`` drives through the engine:
+Composes the TPU kernels into the full chunk+hash step that the engine's
+batched route runs and ``chip_smoke.py`` drives:
 
 1. fused gear-hash scan + on-device FastCDC cut selection of a resident
    byte batch (:func:`..ops.cdc_tpu.scan_select_batch`) — ONE dispatch,
@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import profile as obs_profile
-from ..utils import tracing
+from ..obs import trace as obs_trace
 from .blake3_tpu import blake3_many_tpu, digest_padded
 from .cdc_cpu import chunk_stream as chunk_stream_cpu
 from .cdc_tpu import (
@@ -245,7 +245,7 @@ class DevicePipeline:
         p = self.params
         padded = int(buf_d.shape[1]) - _HALO
         s_cap, l_cap, cut_cap = self._caps(padded)
-        with tracing.span("pipeline.scan_select_dispatch"):
+        with obs_trace.span("pipeline.scan_select_dispatch"):
             packed_d = scan_select_batch(
                 buf_d, self._nv_device(nv),
                 min_size=p.min_size, desired_size=p.desired_size,
@@ -269,7 +269,7 @@ class DevicePipeline:
         re-chunked with the CPU oracle to stay bit-identical, unless
         ``strict_overflow`` (benchmarks must never silently time the
         oracle)."""
-        with tracing.span("pipeline.cut_collect"):
+        with obs_trace.span("pipeline.cut_collect"):
             packed = np.asarray(packed_d)
         nv = np.asarray(nv, dtype=np.int32)
         per_row: List[List[tuple]] = []
@@ -333,7 +333,7 @@ class DevicePipeline:
             _pad_to(np.concatenate(lens_parts), total),
             _pad_to(starts, total)]))
         acc = jnp.zeros((total, 8), dtype=jnp.uint32)
-        with tracing.span("pipeline.digest_dispatch"):
+        with obs_trace.span("pipeline.digest_dispatch"):
             for i, (_st, Bb, Lb, _tags) in enumerate(tiles):
                 acc = _gather_digest(flat, meta, meta[2, i], acc,
                                      B=Bb, L=Lb)
@@ -354,7 +354,7 @@ class DevicePipeline:
             return [(chunks, np.zeros((0, 32), dtype=np.uint8))
                     for chunks in per_row]
         acc, tiles = pending
-        with tracing.span("pipeline.digest_collect"):
+        with obs_trace.span("pipeline.digest_collect"):
             allcv = np.asarray(acc)
         dig8 = np.ascontiguousarray(allcv.astype("<u4")).view(
             np.uint8).reshape(-1, 32)
@@ -367,23 +367,6 @@ class DevicePipeline:
                 for r in range(len(per_row))]
 
     # --- composed drivers --------------------------------------------------
-
-    def manifest_resident_batch(self, buf_d: jnp.ndarray, nv: np.ndarray,
-                                strict_overflow: bool = False,
-                                ) -> List[Tuple[List[tuple], np.ndarray]]:
-        """One resident ``(B, _HALO + P)`` batch -> per-row
-        (chunks, digests).
-
-        ``buf_d`` rows are ``_HALO`` zero bytes then the stream (zero-padded
-        to P); ``nv`` holds true lengths.  This is the exact code path the
-        engine's backup runs per batch — ``bench.py`` times it (pipelined
-        across segments via :meth:`manifest_segments`).
-        """
-        packed_d = self.scan_select_dispatch(buf_d, nv)
-        per_row = self.scan_select_collect(packed_d, buf_d, nv,
-                                           strict_overflow)
-        pending = self.digest_dispatch(buf_d, per_row)
-        return self.digest_collect(pending, per_row)
 
     def manifest_segments(self, segments,
                           strict_overflow: bool = False):
@@ -448,7 +431,7 @@ class DevicePipeline:
 
         def stage_one() -> bool:
             for buf, nv in it:
-                with tracing.span("pipeline.h2d_stage"):
+                with obs_trace.span("pipeline.h2d_stage"):
                     ring.append((jax.device_put(buf), nv))
                 return True
             return False
@@ -495,7 +478,7 @@ class DevicePipeline:
                 B = int(buf_d.shape[0])
                 padded = int(buf_d.shape[1]) - _HALO
                 s_cap, l_cap, cut_cap = self._caps(padded)
-                with tracing.span("pipeline.scan_digest_dispatch"):
+                with obs_trace.span("pipeline.scan_digest_dispatch"):
                     if self.pool_digest:
                         packed, acc, ovf = scan_digest_batch_pool(
                             buf_d, self._nv_device(nv),
@@ -532,7 +515,7 @@ class DevicePipeline:
         while pending:
             buf_d, nv, cut_cap, packed_d, acc_d, ovf_d = pending.popleft()
             dispatch()
-            with tracing.span("pipeline.scan_digest_collect"):
+            with obs_trace.span("pipeline.scan_digest_collect"):
                 packed = np.asarray(packed_d)
                 ovf = np.asarray(ovf_d)
             if ovf.any():
@@ -540,7 +523,7 @@ class DevicePipeline:
                     raise RuntimeError("class capacity overflow in "
                                        "device manifest")
                 # recalibrated path: host-tiled pipeline, still exact
-                yield self.manifest_resident_batch(buf_d, nv)
+                yield from self.manifest_segments([(buf_d, nv)])
                 continue
             acc = np.asarray(acc_d)
             dig8 = np.ascontiguousarray(acc.astype("<u4")).view(
@@ -640,7 +623,7 @@ class DevicePipeline:
                 bs = B // D
                 padded = row - _HALO
                 s_cap, l_cap, cut_cap = self._caps(padded)
-                with tracing.span("pipeline.mesh_dispatch"):
+                with obs_trace.span("pipeline.mesh_dispatch"):
                     buf_sh = jax.device_put(buf, sharding)
                     nv_sh = jax.device_put(nv, sharding)
                     rets = scan_digest_batch_pool_mesh(
@@ -698,7 +681,7 @@ class DevicePipeline:
             (buf, nv, B0, cut_cap, foot, packed_d, acc_d, ovf_d,
              found_d, lost_d) = pending.popleft()
             dispatch()
-            with tracing.span("pipeline.mesh_collect"):
+            with obs_trace.span("pipeline.mesh_collect"):
                 packed = np.asarray(packed_d)
                 ovf = np.asarray(ovf_d)  # (D,) per-shard flags
             state["in_flight"] -= foot
@@ -714,7 +697,7 @@ class DevicePipeline:
                     np.uint8).reshape(B, cut_cap, 32)
             found = lost = None
             if found_d is not None:
-                with tracing.span("pipeline.mesh_collect"):
+                with obs_trace.span("pipeline.mesh_collect"):
                     found = np.asarray(found_d).reshape(B, cut_cap)
                     lost = np.asarray(lost_d).reshape(B, cut_cap)
                 n_real = int(packed[packed[:, 0] == 0, 1].sum())
@@ -743,8 +726,8 @@ class DevicePipeline:
                     if hb is None:
                         hb = np.asarray(buf)
                     obs_profile.mesh_host_rerun("shard", max(0, min(r1, B0) - r0))
-                    sub = self.manifest_resident_batch(
-                        jnp.asarray(hb[r0:r1]), nv[r0:r1])
+                    (sub,) = self.manifest_segments(
+                        [(jnp.asarray(hb[r0:r1]), nv[r0:r1])])
                     for r in range(r0, min(r1, B0)):
                         out[r] = sub[r - r0]
                     continue
@@ -772,21 +755,6 @@ class DevicePipeline:
                 yield out[:B0], flags[:B0]
             else:
                 yield out[:B0]
-
-    def process_segment(self, stream: jnp.ndarray, n_valid: int,
-                        prev_tail: bytes = b"") -> Tuple[List[tuple], np.ndarray]:
-        """One resident segment -> (chunks [(offset, length)...], digests).
-
-        ``stream`` must be a device u8 array of length >= n_valid + slack
-        for the final gather (padding bytes are masked out of digests).
-        ``prev_tail`` is ignored for cut semantics here: segments fed to the
-        bench are independent streams.
-        """
-        ext = jnp.concatenate(
-            [jnp.zeros(_HALO, dtype=jnp.uint8), stream]).reshape(1, -1)
-        nv = np.full(1, n_valid, dtype=np.int32)
-        (chunks, digests), = self.manifest_resident_batch(ext, nv)
-        return chunks, digests
 
     def _manifest_prepass(self, streams, out: List) -> dict:
         """Route a stream batch: fills ``out`` for empty/tiny/long streams

@@ -27,7 +27,7 @@ Output contract: ``(B, P/32) u32`` candidate words in **position-major
 order** (word ``w`` bit ``t`` = candidate at position ``w*32 + t``) —
 bit-identical to ``_pack_bits(cand)`` of the XLA path, so the two-level
 compaction and the on-device cut selection consume either
-interchangeably (tests assert equality; bench parity-gates end to end).
+interchangeably (tests assert equality).
 """
 
 from __future__ import annotations

@@ -1,12 +1,13 @@
-"""Federation bench plane: N coordination nodes as real OS processes.
+"""Federation load plane: N coordination nodes as real OS processes.
 
 The swarm harness (``scenario/swarm.py``) proves the federation's
 end-to-end properties — failover, zero lost matchmakings across a node
 kill, bounded p99 — but its nodes share one event loop, so it cannot
-show THROUGHPUT scaling.  Bench config ``16_federation``'s scaling legs
-need nodes that genuinely run in parallel: this module spawns each node
-as its own OS process with its own ServerStore partition file, its own
-consistent-hash ring copy, and real ``/fed/steal`` HTTP between them.
+show THROUGHPUT scaling.  The multiprocess legs of
+``tests/test_federation.py`` need nodes that genuinely run in parallel:
+this module spawns each node as its own OS process with its own
+ServerStore partition file, its own consistent-hash ring copy, and real
+``/fed/steal`` HTTP between them.
 
 Deployment per node process
 ===========================
@@ -64,7 +65,7 @@ class FederationLoadSpec:
 
 def _free_ports(n: int) -> List[int]:
     """Reserve n distinct loopback ports (bind-then-close; the tiny
-    rebind race is acceptable for a bench on loopback)."""
+    rebind race is acceptable for a load leg on loopback)."""
     socks = [socket.socket() for _ in range(n)]
     try:
         for s in socks:
@@ -76,7 +77,7 @@ def _free_ports(n: int) -> List[int]:
 
 
 class _FedOnline:
-    """Always-online connection registry for the bench nodes: every
+    """Always-online connection registry for the load nodes: every
     notify lands after one loop yield.  ``enable_federation`` installs
     its relay hook here, but local notifies never fail so the relay is
     exercised only via remote-steal pushes."""
@@ -202,7 +203,7 @@ def _tail(path: Path, n: int = 12) -> str:
 def run_federation_load(spec: FederationLoadSpec, workdir) -> Dict:
     """Spawn the node processes, coordinate the shared measurement
     window, and aggregate.  Raises if any node dies or misses the
-    startup ceiling (with its log tail — a bench leg must fail loudly,
+    startup ceiling (with its log tail — a load leg must fail loudly,
     not report a partial fleet as a throughput number)."""
     workdir = Path(workdir)
     workdir.mkdir(parents=True, exist_ok=True)

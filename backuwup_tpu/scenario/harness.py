@@ -942,7 +942,7 @@ async def run_scenario(spec: ScenarioSpec, workdir,
                        backend: Optional[ChunkerBackend] = None
                        ) -> sc.Scorecard:
     """setup -> run -> teardown; the one-call entry point used by the
-    CLI (scripts/scenario.py), bench config 9, and the tests."""
+    CLI (scripts/scenario.py) and the tests."""
     harness = ScenarioHarness(spec, Path(workdir), backend=backend)
     await harness.setup()
     try:

@@ -73,7 +73,7 @@ COUNTER_FAMILIES = (
     # scale-out coordination plane (PR 10): the matchmaking economy's
     # throughput, deadline-heap expiry, per-route request counts, and
     # the write-behind store's commit modes (group vs direct is the
-    # swarm bench's off-loop evidence)
+    # swarm scenario's off-loop evidence)
     "bkw_matchmakings_total",
     "bkw_matchmaking_expired_total",
     "bkw_server_requests_total",
@@ -248,7 +248,7 @@ class Scorecard:
                 f.write(json.dumps(sample, sort_keys=True) + "\n")
 
     def render(self) -> str:
-        """Human-readable card for the CLI / bench log."""
+        """Human-readable card for the CLI."""
         lines = [f"scenario {self.scenario} (seed {self.seed}): "
                  f"{'PASS' if self.passed else 'FAIL'} "
                  f"in {self.elapsed_s:.1f}s over "

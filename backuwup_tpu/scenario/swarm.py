@@ -34,7 +34,7 @@ task that sleeps a fixed tick and records the overshoot.  A blocking
 sqlite commit on the loop shows up as a stall spike (and its thread
 ident lands in ``store.commit_threads``); the sharded tier must stay
 under ``stall_budget_s`` while the legacy tier is expected to blow
-through it — that contrast is bench config ``12_swarm``.
+through it — that contrast is ``tests/test_swarm.py``'s legacy leg.
 
 Load generation runs OFF the server's event loop: the swarm clients are
 distributed over a small pool of worker threads, each with its own
@@ -43,7 +43,7 @@ coroutines on the server's loop would make the shared loop the
 bottleneck and flatten any server-side difference (measured: both tiers
 plateau at the same matchmakings/s when co-located); with the drivers
 off-loop the main loop carries ONLY the server, so the stall detector
-and the bench's tier contrast measure the thing under test.
+and the tier contrast measure the thing under test.
 """
 
 from __future__ import annotations
@@ -80,7 +80,7 @@ _LOOP_STALL = obs_metrics.histogram(
 @dataclass(frozen=True)
 class SwarmSpec:
     """One swarm run.  ``legacy=True`` assembles the single-lock
-    StorageQueue over the direct-commit store (the bench baseline);
+    StorageQueue over the direct-commit store (the baseline shape);
     otherwise the sharded matchmaker over the write-behind store."""
 
     name: str
@@ -806,8 +806,7 @@ class SwarmHarness(ScenarioHarness):
 
 async def run_swarm(spec: SwarmSpec, workdir) -> Tuple[sc.Scorecard, Dict]:
     """setup -> run -> teardown, returning the scorecard plus the flat
-    summary bench config 12 embeds (matchmakings/s, p99, stall, commit
-    mode counts)."""
+    summary (matchmakings/s, p99, stall, commit mode counts)."""
     harness = SwarmHarness(spec, Path(workdir))
     await harness.setup()
     try:
@@ -854,7 +853,7 @@ def summarize(spec: SwarmSpec, card: sc.Scorecard, facts: Dict) -> Dict:
     }
 
 
-# --- direct matchmaking-layer load (bench config 12's speedup legs) --------
+# --- direct matchmaking-layer load (tests/test_swarm.py's legs) ------------
 #
 # The HTTP swarm above proves the end-to-end properties (p99, stall
 # budget, commits off the loop), but on a single-core box the identical
@@ -966,8 +965,7 @@ def run_match_load(spec: MatchLoadSpec, workdir) -> Dict:
 
 def builtin_swarms() -> Dict[str, SwarmSpec]:
     """``swarm`` is the tier-1 acceptance run (≈32 clients, a few
-    seconds on loopback); ``swarm_full`` is the slow-tier load shape
-    bench config 12 also uses."""
+    seconds on loopback); ``swarm_full`` is the slow-tier load shape."""
     P = Phase
     return {
         "swarm": SwarmSpec(

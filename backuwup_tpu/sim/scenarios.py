@@ -384,7 +384,7 @@ def _flush_metrics(name: str, world: SimWorld, driver: SimDriver,
 def run_sim(name: str, clients: Optional[int] = None,
             seed: Optional[int] = None,
             sim_seconds: Optional[float] = None) -> Tuple[dict, dict]:
-    """Sync entry point (scripts, bench, tests outside a loop)."""
+    """Sync entry point (scripts, tests outside a loop)."""
     spec = make_scenario(name, clients=clients, seed=seed,
                          sim_seconds=sim_seconds)
     return asyncio.run(run_scenario_async(name, spec))

@@ -29,9 +29,9 @@ from typing import Callable, List, Optional
 from .. import defaults
 from ..obs import metrics as obs_metrics
 from ..obs import profile as obs_profile
+from ..obs import trace as obs_trace
 from ..ops.backend import ChunkerBackend
 from ..ops.blake3_cpu import blake3_hash
-from ..utils import tracing
 from ..wire import Blob, BlobKind, Tree, TreeKind, TreeMetadata
 from .blob_index import BlobIndex
 from .packfile import PackfileWriter
@@ -200,12 +200,12 @@ class DirPacker:
                 # batch hands its accumulator to the sharded table on
                 # device (zero per-batch host round trips); index-stage
                 # dispatches are accounted inside the backend/driver
-                with tracing.span("packer.manifest_many"):
+                with obs_trace.span("packer.manifest_many"):
                     manifests, hint_list = \
                         self.backend.manifest_many_classified(
                             batch_data, self.dedup_index)
             else:
-                with tracing.span("packer.manifest_many"):
+                with obs_trace.span("packer.manifest_many"):
                     manifests = self.backend.manifest_many(batch_data)
             dt = time.monotonic() - t0
             self.stats.chunk_hash_s += dt
@@ -268,7 +268,7 @@ class DirPacker:
         flush_batch()
         return hashes
 
-    @tracing.traced("stream.file")
+    @obs_trace.traced("stream.file")
     def _pack_file_streaming(self, path: Path, st: os.stat_result) -> bytes:
         """Chunk one huge file through the backend's streaming manifest;
         blobs pack as chunks finalize, so memory stays ~one segment.
@@ -341,7 +341,7 @@ class DirPacker:
                                  padded_bytes=32 * len(children))
         self.stats.files += 1
         self.progress(file=str(path), bytes=st.st_size)
-        with tracing.span("stream.tree"):
+        with obs_trace.span("stream.tree"):
             return self._tree_with_split(
                 TreeKind.FILE, path.name,
                 TreeMetadata(size=st.st_size, mtime_ns=st.st_mtime_ns,

@@ -84,7 +84,7 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "dataflow: streaming backup dataflow tests (bounded"
         " inter-stage queues, backpressure, event-driven seal->send"
-        " wakeup, phased-vs-stream parity, docs/dataflow.md); all"
+        " wakeup, packfile-boundary parity, docs/dataflow.md); all"
         " tier-1")
     config.addinivalue_line(
         "markers", "sim: virtual-clock simulation-plane tests"

@@ -3,14 +3,8 @@
 import numpy as np
 import pytest
 
-import jax
-
 from backuwup_tpu.ops import cdc_cpu
-from backuwup_tpu.ops.cdc_tpu import (
-    TpuCdcScanner,
-    chunk_stream_sharded,
-    gear_hashes_tpu,
-)
+from backuwup_tpu.ops.cdc_tpu import TpuCdcScanner, gear_hashes_tpu
 from backuwup_tpu.ops.gear import CDCParams
 
 SMALL = CDCParams.from_desired(4096)  # min 1024 / desired 4096 / max 12288
@@ -59,16 +53,6 @@ def test_chunk_invariants():
     assert chunks[-1][1] <= SMALL.max_size
 
 
-def test_sharded_scan_matches_oracle():
-    devs = jax.devices()
-    assert len(devs) == 8, "conftest must provide 8 virtual devices"
-    mesh = jax.sharding.Mesh(np.array(devs), ("data",))
-    for n in (0, 1, 100_000, 777_777):
-        data = _data(n, seed=n or 2)
-        assert (chunk_stream_sharded(data, mesh, SMALL)
-                == cdc_cpu.chunk_stream(data, SMALL))
-
-
 def test_segment_overflow_falls_back_to_oracle(monkeypatch):
     # Force the sparse-word capacity below the real candidate count so the
     # oracle-rescan branch runs; output must stay bit-identical.
@@ -78,16 +62,6 @@ def test_segment_overflow_falls_back_to_oracle(monkeypatch):
     n_cand = len(cdc_cpu.candidate_positions(data[:65536], SMALL)[1])
     assert n_cand > 0  # sanity: there are candidates to overflow with
     assert scanner.chunk_stream(data) == cdc_cpu.chunk_stream(data, SMALL)
-
-
-def test_sharded_overflow_falls_back_to_oracle():
-    devs = jax.devices()
-    mesh = jax.sharding.Mesh(np.array(devs), ("data",))
-    dense = CDCParams(min_size=64, desired_size=256, max_size=1024,
-                      mask_s_bits=6, mask_l_bits=4)
-    data = _data(300_000, seed=13)
-    got = chunk_stream_sharded(data, mesh, dense, k_cap=512)
-    assert got == cdc_cpu.chunk_stream(data, dense)
 
 
 def test_scan_select_forced_cut_fallback_and_parallel_paths(rng):

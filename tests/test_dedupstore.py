@@ -285,6 +285,51 @@ def test_promotion_clock_repins_hot_cold_keys(mesh, host_index, tmp_path):
     assert (h1 - h0) / (d1 - d0) > 0.95
 
 
+def test_skewed_working_set_stays_on_the_device_under_tail_churn(
+        mesh, host_index, tmp_path):
+    """A population over ten times the hot table, then the shape of an
+    incremental backup: 97 % of every batch re-probes a working set
+    sized to the demotion keep-set (a quarter of the table, scanned in
+    rotation), 3 % is drawn uniformly over the whole population.  The uniform tail's
+    churn must not push the working set out of HBM: more than 95 % of
+    the device probes are answered on the device, every lane classifies
+    duplicate, and the table never exceeds its budget."""
+    population, batch, hot_frac = 24_000, 512, 0.97
+    budget = (population // 12) * 20
+    ti = TieredDedupIndex(mesh, host_index, cold_dir=tmp_path / "cold",
+                          hbm_budget_bytes=budget, promote_min_hits=1)
+    total_slots = mesh.shape["data"] * ti.capacity
+    assert population >= 10 * total_slots
+    hs = _hashes(population, seed=170)
+    for s in range(0, population, 2048):
+        seg = hs[s:s + 2048]
+        for h, f in zip(seg, ti.classify_insert(seg)):
+            assert f == host_index.is_duplicate(h)
+            host_index.mark_queued(h)
+    rng = np.random.default_rng(171)
+    hot_n = max(total_slots // 4, batch)
+    hot = [hs[i] for i in rng.integers(0, population, hot_n)]
+    for s in range(0, hot_n, batch):  # warm: promote the working set
+        ti.classify_insert(hot[s:s + batch])
+    d0, h0 = (_metric("bkw_tier_probes_total", path="device"),
+              _metric("bkw_tier_hits_total", path="device"))
+    n_hot, cursor = int(batch * hot_frac), 0
+    for _ in range(8):
+        leg = [hot[(cursor + i) % hot_n] for i in range(n_hot)]
+        cursor = (cursor + n_hot) % hot_n
+        leg += [hs[int(i)] for i in
+                rng.integers(0, population, batch - n_hot)]
+        assert all(ti.classify_insert(leg))
+    d1, h1 = (_metric("bkw_tier_probes_total", path="device"),
+              _metric("bkw_tier_hits_total", path="device"))
+    # lanes are deduplicated a batch, and the working set was drawn
+    # with replacement: at least half of them are probes
+    assert d1 - d0 >= 4 * n_hot
+    assert (h1 - h0) / (d1 - d0) > 0.95, (h1 - h0, d1 - d0)
+    assert not any(ti.classify_insert(_hashes(batch, seed=172)))
+    assert _metric("bkw_tier_hbm_highwater_bytes") <= budget
+
+
 def test_resolve_hints_cold_fallthrough(mesh, host_index, tmp_path):
     budget = 8 * 64 * 20
     ti = TieredDedupIndex(mesh, host_index, cold_dir=tmp_path / "cold",

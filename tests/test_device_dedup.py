@@ -110,21 +110,28 @@ def test_grows_under_pressure(mesh, host_index):
     assert all(dev.classify_insert(hs))  # now all resident
 
 
-def test_engine_auto_attaches_mesh_on_accelerator(tmp_path, monkeypatch):
-    """A plain Engine on the device backend classifies via MeshDedupIndex
-    without a caller-supplied mesh (VERDICT r2 item 5)."""
+def test_engine_auto_attaches_mesh_on_accelerator(tmp_path):
+    """A plain Engine on the device backend classifies on the HBM index
+    over every local device without a caller-supplied mesh (VERDICT r2
+    item 5); a caller that passes ``dedup_mesh=`` gets that mesh, on the
+    index and on the backend's manifest pipeline alike."""
     from backuwup_tpu.app import ClientApp
+    from backuwup_tpu.dedupstore import TieredDedupIndex
     from backuwup_tpu.ops.backend import TpuBackend
     from backuwup_tpu.ops.gear import CDCParams
 
     app = ClientApp(config_dir=tmp_path / "cfg", data_dir=tmp_path / "data",
                     server_addr="127.0.0.1:1",
                     backend=TpuBackend(CDCParams.from_desired(4096)))
-    assert app.engine.device_dedup is not None
+    dedup = app.engine.device_dedup
+    assert isinstance(dedup, TieredDedupIndex)
+    assert dedup.mesh.devices.size == jax.device_count()
 
-    monkeypatch.setenv("BKW_DEVICE_DEDUP", "0")
+    two = Mesh(np.array(jax.devices()[:2]), ("data",))
     app2 = ClientApp(config_dir=tmp_path / "cfg2",
                      data_dir=tmp_path / "data2",
                      server_addr="127.0.0.1:1",
-                     backend=TpuBackend(CDCParams.from_desired(4096)))
-    assert app2.engine.device_dedup is None
+                     backend=TpuBackend(CDCParams.from_desired(4096)),
+                     dedup_mesh=two)
+    assert app2.engine.device_dedup.mesh is two
+    assert app2.engine.backend.pipeline.mesh is two

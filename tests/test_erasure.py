@@ -238,16 +238,26 @@ def test_assemble_tree_reconstructs_and_reports(tmp_path, rng):
 # --------------------------------------------------------------------------
 
 
-def test_cpu_backend_encode_decode_matches_oracle(nprng):
-    k, m = 4, 2
+@pytest.mark.parametrize("name", ["cpu", "tpu"])
+def test_backend_encode_decode_matches_oracle(name, nprng):
+    """What the engine calls (``backend.encode_shards`` /
+    ``decode_shards``) at its own 4+2 geometry, on the oracle backend and
+    on the one a chip selects: parity equals ``gf_cpu``, and the data
+    comes back with any m shards dropped."""
+    from backuwup_tpu.ops.backend import TpuBackend
+
+    backend = BACKEND if name == "cpu" else \
+        TpuBackend(CDCParams.from_desired(4096))
+    k, m = defaults.RS_K, defaults.RS_M
     stripes = nprng.integers(0, 256, (3, k, 128), dtype=np.uint8)
-    parity = BACKEND.encode_shards(stripes, m)
+    parity = np.asarray(backend.encode_shards(stripes, m), dtype=np.uint8)
     expect = np.stack([gf_cpu.encode_stripe(s, m) for s in stripes])
     assert np.array_equal(parity, expect)
     full = np.concatenate([stripes, parity], axis=1)
-    present = [0, 2, 4, 5]
-    dec = BACKEND.decode_shards(full[:, present, :], k, m, present)
-    assert np.array_equal(dec, stripes)
+    for present in itertools.combinations(range(k + m), k):
+        dec = np.asarray(backend.decode_shards(
+            full[:, list(present), :], k, m, list(present)), dtype=np.uint8)
+        assert np.array_equal(dec, stripes), f"survivors {present}"
 
 
 def test_device_kernel_matches_oracle_on_host(nprng):

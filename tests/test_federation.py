@@ -18,7 +18,7 @@ Tier 1 covers:
   its zero-lost-matchmakings scorecard gate.
 
 The multi-process scaling legs (scenario/federation.py) and the soak
-swarm are slow — bench config 16 is their gate.
+swarm are slow.
 """
 
 import asyncio
@@ -438,10 +438,9 @@ def test_federation_swarm_soak(tmp_path, loop):
 @pytest.mark.slow
 @pytest.mark.timeout(600)
 def test_federation_multiprocess_legs(tmp_path):
-    """The bench's scaling legs end-to-end: real OS processes, real
-    /fed/steal HTTP.  Throughput gates are bench config 16's (armed on
-    >=4-CPU hosts); here every node must produce matches and the fleet
-    must complete cleanly."""
+    """The scaling legs end-to-end: real OS processes, real /fed/steal
+    HTTP.  No throughput is gated (a CPU ratio is not evidence); every
+    node must produce matches and the fleet must complete cleanly."""
     from backuwup_tpu.scenario.federation import (FederationLoadSpec,
                                                   run_federation_load)
     out = run_federation_load(

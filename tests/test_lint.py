@@ -683,6 +683,33 @@ def test_repo_is_lint_clean():
     assert report.clean
 
 
+#: The whole environment surface of the package: operator and safety
+#: settings, and the four kernel switches ROADMAP D2 keeps until a cell
+#: has timed the parity ladders.  A new name here is a new option: it
+#: needs a product caller that passes more than one value.
+BKW_ENV_NAMES = {
+    "BKW_FAULTS", "BKW_FSYNC", "BKW_JOURNAL", "BKW_STATUS_PORT",
+    "BKW_TRACE_DIR",
+    "BKW_FUSED", "BKW_FUSED_V2", "BKW_PALLAS_DIGEST", "BKW_POOL_DIGEST",
+}
+
+
+def test_repo_environment_switches_are_the_kept_nine():
+    """Every ``BKW_*`` name in the package's sources (read from the
+    environment, or only mentioned) is one of the kept nine, and each of
+    the nine is still read: a quiet knob, or a dead one, fails here."""
+    import re
+    pkg = REPO / "backuwup_tpu"
+    named, read = set(), set()
+    for path in pkg.rglob("*.py"):
+        src = path.read_text()
+        named.update(re.findall(r"\bBKW_[A-Z0-9_]+", src))
+        read.update(re.findall(
+            r"""environ(?:\.get\(|\[)\s*["\'](BKW_[A-Z0-9_]+)""", src))
+    assert named == BKW_ENV_NAMES, sorted(named ^ BKW_ENV_NAMES)
+    assert read == BKW_ENV_NAMES, sorted(read ^ BKW_ENV_NAMES)
+
+
 def test_repo_baseline_entries_all_match(tmp_path):
     """Every baseline entry matches a real finding (apply_baseline in
     reverse: nothing stale), and carries a real justification."""

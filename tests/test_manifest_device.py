@@ -96,6 +96,32 @@ def test_manifest_segments_device_driver():
             assert [bytes(d) for d in digests] == ref_digests
 
 
+@pytest.mark.parametrize("params,kw,n", [
+    pytest.param(CDCParams(), {}, 8 << 20, id="ref-1m"),
+    pytest.param(CDCParams.from_desired(64 << 10),
+                 dict(l_bucket=256, b_bucket=512), 4 << 20, id="vm-64k")])
+def test_driver_is_exact_at_deployment_widths_without_the_oracle(
+        params, kw, n):
+    """The zero-round-trip driver at the widths deployments run (the
+    shipped 256 KiB / 1 MiB / 3 MiB, and the VM-image profile's 64 KiB
+    average) over a stream with a repeated quarter.  ``strict_overflow``
+    turns every fall-back to the CPU oracle into an error, so the parity
+    below is the device path's own and not oracle against oracle."""
+    rng = np.random.default_rng(1234)
+    d = rng.integers(0, 256, n, dtype=np.uint8)
+    d[n // 2:n // 2 + n // 4] = d[:n // 4]
+    data = d.tobytes()
+    buf, nv = _stage([data], n)
+    pipe = DevicePipeline(params, **kw)
+    ((chunks, digests),), = pipe.manifest_segments_device(
+        [(buf, nv)], strict_overflow=True)
+    ref_chunks, ref_digests = _oracle(data, params)
+    assert chunks == ref_chunks
+    assert [bytes(x) for x in digests] == ref_digests
+    # the same dedup decision follows from the same digests
+    assert len({bytes(x) for x in digests}) == len(set(ref_digests))
+
+
 def test_class_overflow_falls_back():
     # all-zero data chunks entirely at max size: the top class overflows
     # its calibrated capacity once the batch is large enough, and the

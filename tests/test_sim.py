@@ -223,11 +223,21 @@ def test_matchmaker_expiry_on_virtual_clock(loop):
 # --- scenarios: determinism and the scorecard -------------------------------
 
 
-def test_same_seed_same_scorecard_byte_identical():
-    c1, _ = run_sim("flashcrowd", clients=1500)
-    c2, _ = run_sim("flashcrowd", clients=1500)
+@pytest.mark.parametrize("name,clients,sim_seconds", [
+    ("flashcrowd", 1500, None), ("regionfail", 2000, 3 * 86_400.0)])
+def test_same_seed_same_scorecard_byte_identical(name, clients, sim_seconds):
+    """The replay contract triage leans on.  ``regionfail`` carries the
+    live SLO plane through its fault, so its card also pins the burn
+    ticks, the breach time and the ranked diagnosis."""
+    c1, _ = run_sim(name, clients=clients, sim_seconds=sim_seconds)
+    c2, _ = run_sim(name, clients=clients, sim_seconds=sim_seconds)
     assert card_json(c1) == card_json(c2)
-    assert c1["passed"], json.dumps(c1["gates"], indent=1)
+    if name == "flashcrowd":
+        assert c1["passed"], json.dumps(c1["gates"], indent=1)
+    else:
+        slo = c1["slo"]
+        assert slo["first_breach_t"] >= 2 * 86_400.0
+        assert slo["diagnosis"]["causes"]
 
 
 def test_scorecard_is_wall_clock_free_and_metrics_flush():
@@ -255,17 +265,20 @@ def test_simulated_week_of_1e5_client_churn_in_tier1_minutes():
     """The headline: 10⁵ clients, a simulated week, a quarter of the
     regions lost on day 2 — real matchmaking and serverstore paths on
     the virtual clock, gates on match-rate, repair-debt drain, and
-    violation client-seconds.  Runs in well under a tier-1 minute's
-    budget; the compression-ratio gate itself lives in bench #19."""
+    violation client-seconds, and on the live SLO plane noticing the
+    failure (never before it) and naming the injection site.  Runs in
+    well under a tier-1 minute's budget."""
     card, stats = run_sim("regionfail")
     assert card["clients"] == 100_000
     assert card["sim_seconds"] == WEEK_S
-    assert {g["name"] for g in card["gates"]} == {
+    assert [g["name"] for g in card["gates"]] == [
         "match_rate>=0.90", "repair_debt_drained<=3d",
-        "violation_seconds_bounded"}
-    assert card["passed"], json.dumps(card["gates"], indent=1)
+        "violation_seconds_bounded", "slo_breach_after_fault",
+        "slo_diagnosis_names_fault"]
+    red = [g for g in card["gates"] if not g["passed"]]
+    assert not red and card["passed"], json.dumps(card["gates"], indent=1)
     # a simulated week must not cost a wall week: 3 orders of magnitude
-    # is the floor even on a loaded CI box (bench gates the real 10⁴×)
+    # is the floor even on a loaded CI box
     assert stats["time_compression"] > 1_000.0
 
 

@@ -88,10 +88,10 @@ def test_persistence_across_reopen(tmp_path):
 
 def test_tracing_spans_and_report():
     """Host tracing subsystem (SURVEY §5.1: the build adds what the
-    reference lacks): the facade's spans land in ``bkw_span_seconds``,
+    reference lacks): spans land in ``bkw_span_seconds``,
     whose per-name count and sum are the aggregate report."""
     from backuwup_tpu.obs import metrics as obs_metrics
-    from backuwup_tpu.utils import tracing
+    from backuwup_tpu.obs import trace as tracing
 
     spans = obs_metrics.registry().get("bkw_span_seconds")
     before = {n: spans.count_value(name=n)

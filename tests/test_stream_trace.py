@@ -201,7 +201,6 @@ def test_resident_stripe_uploads_a_packfile_once_in_two_dispatches(
     six tables of 4 MiB); the resident route uploads the padded shard
     matrix and the window rows, under 1.5 bytes a packfile byte, and
     waits twice: once in each span, which still bound the work."""
-    monkeypatch.setenv("BKW_DEVICE_DEDUP", "0")  # no index in this test
     store = Store(directory=tmp_path / "cfg", data_base=tmp_path / "data")
     engine = Engine(KEYS, store, server=None, node=None,
                     backend=TpuBackend(SMALL))

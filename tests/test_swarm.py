@@ -6,7 +6,7 @@ matchmaking economy flowed, the request p99 was measured from
 ``bkw_server_request_seconds``, the event loop never stalled past
 budget, and no sqlite commit ran on the loop thread.  A second tier-1
 run pins the LEGACY tier's expected contrast: its direct-commit store
-commits on the event loop (that is the baseline the bench beats).  The
+commits on the event loop (the baseline shape, ROADMAP D3b).  The
 192-client load shape and the measured speedup legs are slow.
 """
 
@@ -57,7 +57,7 @@ def test_swarm_acceptance(tmp_path, loop):
 
 @pytest.mark.timeout(240)
 def test_swarm_legacy_commits_on_loop(tmp_path, loop):
-    """The baseline contrast the bench measures: the legacy tier's
+    """The baseline contrast: the legacy tier's
     direct-commit store fsyncs on the event-loop thread (visible in
     ``commit_threads``), which is exactly what the sharded tier's
     ``commits_off_event_loop`` gate forbids."""
@@ -71,8 +71,8 @@ def test_swarm_legacy_commits_on_loop(tmp_path, loop):
 
 
 def test_match_load_smoke(tmp_path):
-    """Both speedup legs produce matches on a short window (the >= 2x
-    gate itself is bench config 12 and the slow test below)."""
+    """Both legs produce matches on a short window (the slow test
+    below compares their rates)."""
     spec = MatchLoadSpec(clients=16, duration_s=0.3, audit_history=64)
     legacy = run_match_load(dataclasses.replace(spec, legacy=True), tmp_path)
     sharded = run_match_load(spec, tmp_path)
@@ -94,7 +94,7 @@ def test_swarm_full_load_shape(tmp_path, loop):
 @pytest.mark.slow
 @pytest.mark.timeout(300)
 def test_match_load_speedup(tmp_path):
-    """The bench gate's shape at full weight; the test bound is kept
+    """The load shape at full weight; the test bound is kept
     conservative (>= 1.3x) so scheduler noise cannot flake it while a
     real regression — sharded no faster than the single lock — still
     fails loudly."""
