@@ -59,6 +59,10 @@ def _file_digest(data) -> bytes:
 _P2P_BYTES = obs_metrics.counter(
     "bkw_p2p_bytes_sent_total",
     "Signed frame bytes shipped through the transport send chokepoint")
+_P2P_DEFLATED = obs_metrics.counter(
+    "bkw_p2p_bytes_deflated_total",
+    "Of those, bytes shipped on a socket that negotiated a websocket "
+    "extension (permessage-deflate): 0 between peers of this version")
 _SEQ_BREAKS = obs_metrics.counter(
     "bkw_p2p_sequence_breaks_total",
     "Receiver sequence-validation failures (replay protection tripped)")
@@ -208,6 +212,15 @@ def _verify_msg(raw: bytes, peer_id: bytes) -> wire.P2PBody:
     return body
 
 
+def _negotiated_extensions(ws) -> Tuple[str, ...]:
+    """Names of the websocket extensions the handshake of ``ws`` agreed
+    on: empty between peers of this version (docs/transfer.md)."""
+    # websockets' asyncio API keeps them on ``ws.protocol``; its legacy
+    # API and utils/ws_compat on the connection itself
+    found = getattr(getattr(ws, "protocol", ws), "extensions", None) or ()
+    return tuple(str(getattr(e, "name", e)) for e in found)
+
+
 class Transport:
     """Send side: ordered, signed, acked file transfer (transport.rs)."""
 
@@ -222,6 +235,14 @@ class Transport:
         self._listen_done = False
         self._ack_task: Optional[asyncio.Task] = None
         self._recv_queue: asyncio.Queue = asyncio.Queue()
+        self.extensions = _negotiated_extensions(ws)
+
+    def journal_open(self, role: str) -> None:
+        """One journal line a socket: which end this is (``dial`` or
+        ``listen``) and what its handshake negotiated."""
+        obs_journal.emit("p2p_socket_open", role=role,
+                         peer=self.peer_id.hex()[:16],
+                         extensions=list(self.extensions))
 
     def start(self) -> None:
         if self._ack_task is None:
@@ -284,6 +305,8 @@ class Transport:
             if action == faults.ACT_CORRUPT:
                 raw = plane.corrupt(raw, self.peer_id)
         _P2P_BYTES.inc(len(raw))
+        if self.extensions:
+            _P2P_DEFLATED.inc(len(raw))
         try:
             await asyncio.wait_for(
                 self.ws.send(raw),
@@ -893,8 +916,12 @@ class P2PNode:
         # dial retries (handle_connections.rs:145-165) through the unified
         # retry policy: 3 dials with jittered exponential backoff
         async def _dial():
+            # no extension offered: what crosses this socket is sealed
+            # (or a nonce, a digest, a signature) and deflate shrinks none
+            # of it while costing the loop ~20 ms a shard (PERF.md, PR 31)
             return await websockets.connect(
-                f"ws://{addr}", max_size=defaults.MAX_P2P_MESSAGE_SIZE)
+                f"ws://{addr}", max_size=defaults.MAX_P2P_MESSAGE_SIZE,
+                compression=None)
 
         try:
             ws = await retry.retry_async(_dial, retry.DIAL,
@@ -907,6 +934,7 @@ class P2PNode:
             request_type=purpose)
         await ws.send(_sign_body(self.keys, init))
         t = Transport(ws, self.keys, peer_id, nonce)
+        t.journal_open("dial")
         t.start()
         return t
 
@@ -940,6 +968,7 @@ class P2PNode:
                     websockets.ConnectionClosed):
                 return
             t = Transport(ws, self.keys, source, expected_nonce)
+            t.journal_open("listen")
             t.start()
             done = asyncio.Event()
             await accepted.put((body.request_type, t, done))
@@ -950,7 +979,7 @@ class P2PNode:
         # cancelled mid-await (client shutdown)
         server = await websockets.serve(
             handler, self.bind_host, 0,
-            max_size=defaults.MAX_P2P_MESSAGE_SIZE)
+            max_size=defaults.MAX_P2P_MESSAGE_SIZE, compression=None)
         try:
             port = server.sockets[0].getsockname()[1]
             await self.server.p2p_connection_confirm(

@@ -202,6 +202,12 @@ _SEND_DISPATCHES = _metrics.counter(
     "Blocking device round trips of the send stage (upload, programs, "
     "download awaited)")
 _send_thread = threading.local()
+# What left through the P2P sockets meanwhile, from the transport's own
+# counters (``net/p2p.py`` ``Transport._ship``; read by name, 0 where no
+# transport was ever imported): every signed frame byte, and those
+# shipped on a socket that negotiated permessage-deflate.
+SEND_WIRE_COUNTERS = {"wire_bytes": "bkw_p2p_bytes_sent_total",
+                      "deflated_bytes": "bkw_p2p_bytes_deflated_total"}
 
 # Span names whose bkw_span_seconds sums a pipeline report attributes as
 # per-stage wall time: the batched route's dispatch/collect pairs and
@@ -428,6 +434,9 @@ def baseline() -> Dict[str, Dict[str, float]]:
     out["send"] = {f"{k}_bytes": _SEND_BYTES.value(kind=k)
                    for k in SEND_BYTE_KINDS}
     out["send"]["dispatches"] = _SEND_DISPATCHES.value()
+    for key, name in SEND_WIRE_COUNTERS.items():
+        fam = _metrics.registry().get(name)
+        out["send"][key] = fam.value() if fam is not None else 0.0
     spans = _metrics.registry().get("bkw_span_seconds")
     if spans is not None:
         for name in REPORT_SPANS:

@@ -9,9 +9,12 @@ Containers that lack the ``websockets`` wheel always have aiohttp here
         from ..utils import ws_compat as websockets
 
 Only the surface :mod:`backuwup_tpu.net.p2p` touches is provided:
-``connect(url, max_size=)``, ``serve(handler, host, port, max_size=)``
-(-> object with ``.sockets`` and a sync ``.close()``), connection objects
-with ``send``/``recv``/``close``/async-iteration, and ``ConnectionClosed``.
+``connect(url, max_size=, compression=)``, ``serve(handler, host, port,
+max_size=, compression=)`` (-> object with ``.sockets`` and a sync
+``.close()``), connection objects with ``send``/``recv``/``close``/
+async-iteration and ``.extensions``, and ``ConnectionClosed``.
+``compression`` is websockets' keyword: ``"deflate"`` (its default)
+offers / accepts permessage-deflate, ``None`` negotiates nothing.
 """
 
 from __future__ import annotations
@@ -33,6 +36,12 @@ class _WS:
     def __init__(self, ws, session: Optional[aiohttp.ClientSession] = None):
         self._ws = ws
         self._session = session
+
+    @property
+    def extensions(self) -> tuple:
+        """Names of the negotiated extensions, as websockets' legacy
+        API spells the attribute."""
+        return ("permessage-deflate",) if self._ws.compress else ()
 
     async def send(self, data) -> None:
         try:
@@ -67,11 +76,13 @@ class _WS:
             self._session = None
 
 
-async def connect(url: str, max_size: Optional[int] = None) -> _WS:
+async def connect(url: str, max_size: Optional[int] = None,
+                  compression: Optional[str] = "deflate") -> _WS:
     session = aiohttp.ClientSession()
     try:
         ws = await session.ws_connect(
-            url, max_msg_size=max_size or 4 * 2 ** 20, autoping=True)
+            url, max_msg_size=max_size or 4 * 2 ** 20, autoping=True,
+            compress=15 if compression else 0)
     except aiohttp.ClientError as e:
         await session.close()
         # net/p2p dial-retry loops catch OSError, the type websockets raises
@@ -102,9 +113,11 @@ class _Server:
 
 
 async def serve(handler, host: str, port: int,
-                max_size: Optional[int] = None) -> _Server:
+                max_size: Optional[int] = None,
+                compression: Optional[str] = "deflate") -> _Server:
     async def http_handler(request: web.BaseRequest):
-        ws = web.WebSocketResponse(max_msg_size=max_size or 4 * 2 ** 20)
+        ws = web.WebSocketResponse(max_msg_size=max_size or 4 * 2 ** 20,
+                                   compress=bool(compression))
         await ws.prepare(request)
         await handler(_WS(ws))
         return ws
