@@ -1743,16 +1743,20 @@ class Engine:
                 if peer.free_storage < min_free:
                     continue  # capacity-ordered now, so keep scanning:
                     # a later (slower) peer may still have the space
-                if key in orch.active_transports:
-                    continue  # already weighed in the reuse pass
-                try:
-                    t = await self.node.connect(
-                        key, wire.RequestType.TRANSPORT, timeout=3.0)
-                except (P2PError, ServerError, OSError,
-                        asyncio.TimeoutError) as e:
-                    self._log(f"dial {key.hex()[:8]} failed: {e}")
-                    continue
-                orch.active_transports[key] = t
+                # a transport that is there now was opened by a sibling
+                # tick since the reuse pass (a backup's first packfiles
+                # come in a burst, each tick dialling): take it, or this
+                # stripe comes up one peer short and goes out whole
+                t = orch.active_transports.get(key)
+                if t is None:
+                    try:
+                        t = await self.node.connect(
+                            key, wire.RequestType.TRANSPORT, timeout=3.0)
+                    except (P2PError, ServerError, OSError,
+                            asyncio.TimeoutError) as e:
+                        self._log(f"dial {key.hex()[:8]} failed: {e}")
+                        continue
+                    orch.active_transports[key] = t
                 conns.append((t, key, peer.free_storage))
                 chosen.add(key)
         return conns

@@ -47,6 +47,7 @@ from __future__ import annotations
 import functools
 import os
 import threading
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -256,10 +257,17 @@ def warm(k: int, m: int, packfile_bytes: int) -> None:
     with _warm_lock:  # a burst's other stripes wait here, not compile too
         if key in _warmed:
             return
-        for leaves in defaults.BLAKE3_LEAF_BUCKETS:
-            bucket = leaves * CHUNK_LEN
-            if top is not None and bucket > top:
-                break
-            ResidentStripe(bytes(k * bucket), k, m, bucket,
-                           range(k + m)).challenge_tables()
+        buckets = [leaves * CHUNK_LEN
+                   for leaves in defaults.BLAKE3_LEAF_BUCKETS
+                   if top is None or leaves * CHUNK_LEN <= top]
+        # a thread a bucket: the buckets' programs differ in shape alone
+        # and XLA compiles outside the interpreter lock, so a first
+        # stripe waits for the slowest bucket and not for their sum
+        with ThreadPoolExecutor(len(buckets)) as pool:
+            for fut in [pool.submit(
+                    lambda b=b: ResidentStripe(
+                        bytes(k * b), k, m, b,
+                        range(k + m)).challenge_tables())
+                    for b in buckets]:
+                fut.result()
         _warmed.add(key)

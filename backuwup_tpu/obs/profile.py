@@ -171,6 +171,46 @@ STREAM_GROUPS = {
     "stream.tree": "emit",
 }
 
+# The same for one pack batch of whole files (the batched route:
+# ``Packer._pack_files`` -> ``manifest_many_classified`` ->
+# ``DevicePipeline.manifest_batch_classified``): reading the files,
+# building host batches and decoding what came down, waiting for the
+# device (the mesh program's dispatch and collect, the tiny files'
+# digest batch, a long file's segmented scan and digest, the index
+# answering what the device could not), and the packer's per-chunk loop
+# and tree nodes.  :func:`report` sums them into its ``batch`` section.
+# Beside those four, ``compile``: the programs a backup's batches need,
+# lowered one after the other and compiled side by side before the first
+# batch (a process's first backup only; outside ``packer.manifest_many``).
+BATCH_GROUPS = {
+    "batch.read": "read",
+    "batch.stage": "host_stage",
+    "batch.decode": "host_stage",
+    "batch.compile": "compile",
+    "pipeline.mesh_dispatch": "device_wait",
+    "pipeline.mesh_collect": "device_wait",
+    "batch.tiny_digest": "device_wait",
+    "batch.long_stream": "device_wait",
+    "batch.resolve": "device_wait",
+    "batch.emit": "emit",
+}
+# Who decided whether a chunk of the batched route is a duplicate: the
+# HBM table's found-vector, downloaded with the batch
+# (``device_decided``), or ``resolve_hints`` for what the device could
+# not classify (tiny and long files, fallback rows, lost lanes:
+# ``host_resolved``).  And which way a file went through the prepass.
+BATCH_VERDICTS = ("device_decided", "host_resolved")
+BATCH_ROUTES = ("tiny", "bucketed", "long")
+_BATCH_CHUNKS = _metrics.counter(
+    "bkw_batch_chunks_total",
+    "Chunks of the batched route by who classified them: the device's "
+    "found-vector or the index's resolve_hints", labelnames=("verdict",))
+_BATCH_FILES = _metrics.counter(
+    "bkw_batch_files_total",
+    "Files of the batched route by prepass route: one tiny-file digest "
+    "batch, padded buckets, or the long-stream scan",
+    labelnames=("route",))
+
 # Bytes the resident streaming route (ops/resident.py) moved for a
 # streamed file: ``uploaded`` is everything it put on the device
 # (window blocks, chunk rows), about the file's size when every byte
@@ -210,9 +250,10 @@ SEND_WIRE_COUNTERS = {"wire_bytes": "bkw_p2p_bytes_sent_total",
                       "deflated_bytes": "bkw_p2p_bytes_deflated_total"}
 
 # Span names whose bkw_span_seconds sums a pipeline report attributes as
-# per-stage wall time: the batched route's dispatch/collect pairs and
-# the packer entry point that drives them, the streamed file and its
-# parts, the index classify, and the send stage's steps.
+# per-stage wall time: the batched route's dispatch/collect pairs, the
+# packer entry point that drives them and the batch's own parts, the
+# streamed file and its parts, the index classify, and the send stage's
+# steps.
 REPORT_SPANS = (
     "pipeline.scan_select_dispatch",
     "pipeline.cut_collect",
@@ -220,12 +261,11 @@ REPORT_SPANS = (
     "pipeline.digest_collect",
     "pipeline.scan_digest_dispatch",
     "pipeline.scan_digest_collect",
-    "pipeline.mesh_dispatch",
-    "pipeline.mesh_collect",
     "pipeline.h2d_stage",
     "packer.manifest_many",
     "stream.file",
     *STREAM_GROUPS,
+    *BATCH_GROUPS,  # pipeline.mesh_dispatch / mesh_collect among them
     "index.classify",
     "send.dial",
     "send.rs_encode",
@@ -354,6 +394,22 @@ def stream_bytes(kind: str, n: int) -> None:
         _STREAM_BYTES.inc(n, kind=kind)
 
 
+def batch_chunks(verdict: str, n: int) -> None:
+    """``n`` chunks of one pack batch classified by ``verdict``."""
+    if verdict not in BATCH_VERDICTS:
+        raise ValueError(f"unknown batch verdict {verdict!r}")
+    if n:
+        _BATCH_CHUNKS.inc(n, verdict=verdict)
+
+
+def batch_files(route: str, n: int) -> None:
+    """``n`` files of one pack batch sent down ``route``."""
+    if route not in BATCH_ROUTES:
+        raise ValueError(f"unknown batch route {route!r}")
+    if n:
+        _BATCH_FILES.inc(n, route=route)
+
+
 @contextlib.contextmanager
 def send_stage(packfile_bytes: int) -> Iterator[None]:
     """The calling thread codes one packfile of ``packfile_bytes`` for
@@ -431,6 +487,10 @@ def baseline() -> Dict[str, Dict[str, float]]:
     out["tier"] = tier
     out["stream_bytes"] = {k: _STREAM_BYTES.value(kind=k)
                            for k in STREAM_BYTE_KINDS}
+    out["batch_chunks"] = {v: _BATCH_CHUNKS.value(verdict=v)
+                           for v in BATCH_VERDICTS}
+    out["batch_files"] = {r: _BATCH_FILES.value(route=r)
+                          for r in BATCH_ROUTES}
     out["send"] = {f"{k}_bytes": _SEND_BYTES.value(kind=k)
                    for k in SEND_BYTE_KINDS}
     out["send"]["dispatches"] = _SEND_DISPATCHES.value()
@@ -470,6 +530,12 @@ def report(base: Optional[dict] = None) -> dict:
     stream = {k: round(v, 6) for k, v in stream.items()}
     for kind, n in _delta("stream_bytes").items():
         stream[f"{kind}_bytes"] = int(n)
+    batch: dict = dict.fromkeys(BATCH_GROUPS.values(), 0.0)
+    for name, group in BATCH_GROUPS.items():
+        batch[group] += span_s.get(name, 0.0)
+    batch = {k: round(v, 6) for k, v in batch.items()}
+    batch["chunks"] = {k: int(v) for k, v in _delta("batch_chunks").items()}
+    batch["files"] = {k: int(v) for k, v in _delta("batch_files").items()}
     compile_s = {fun: round(dt, 6)
                  for fun, dt in _delta("compile_s").items() if dt > 0}
     # per-device split of the mesh-pipeline launches: {device: {stage: n}}
@@ -497,6 +563,7 @@ def report(base: Optional[dict] = None) -> dict:
         "pad_efficiency": efficiency,
         "stage_seconds": stage_seconds,
         "stream": stream,
+        "batch": batch,
         "send": {k: int(v) for k, v in _delta("send").items()},
         "compile_s": compile_s,
         "compile_total_s": round(sum(compile_s.values()), 6),

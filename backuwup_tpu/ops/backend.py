@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass
-from typing import Callable, List, Optional, Sequence
+from typing import Callable, Iterable, List, Optional, Sequence
 
 import numpy as np
 
@@ -122,6 +122,15 @@ class ChunkerBackend:
 
     def manifest(self, data) -> List[ChunkRef]:
         return self.manifest_many([data])[0]
+
+    def prepare_batches(self, batches: Iterable[Sequence[int]],
+                        dedup) -> None:
+        """The lengths of the files of every pack batch a backup is about
+        to hand to :meth:`manifest_many_classified`, before the first
+        (an iterable the packer fills from a walk of its own: read it
+        only to use it).  A backend that compiles programs per shape
+        compiles them side by side here (:class:`TpuBackend`); the
+        others have nothing to do."""
 
     def manifest_many_classified(self, streams: Sequence[bytes], dedup):
         """Manifest + dedup-classify one batch in a single call.
@@ -430,6 +439,22 @@ class TpuBackend(ChunkerBackend):
                 for k, (off, ln) in enumerate(chunks)])
         return out
 
+    def _rides_mesh_of(self, dedup) -> bool:
+        """True where ``dedup`` hands off on the device and on the
+        pipeline's mesh (the pipeline takes the index's mesh if it has
+        none yet)."""
+        pipe = self.pipeline
+        if getattr(dedup, "classify_dispatch", None) is None:
+            return False
+        if pipe.mesh is None:
+            pipe.mesh = dedup.mesh
+            pipe.mesh_axis = dedup.axis
+        return pipe.mesh is dedup.mesh and pipe.mesh_axis == dedup.axis
+
+    def prepare_batches(self, batches, dedup):
+        if self._rides_mesh_of(dedup):
+            self.pipeline.compile_side_by_side(batches, emit_queries=True)
+
     def manifest_many_classified(self, streams, dedup):
         """Mesh-sharded manifest with the on-device dedup handoff: the
         digest accumulator feeds ``ShardedDedupIndex.insert_device``
@@ -438,12 +463,7 @@ class TpuBackend(ChunkerBackend):
         two-pass base when ``dedup`` has no device handoff or rides a
         different mesh than the pipeline."""
         pipe = self.pipeline
-        if getattr(dedup, "classify_dispatch", None) is None:
-            return super().manifest_many_classified(streams, dedup)
-        if pipe.mesh is None:
-            pipe.mesh = dedup.mesh
-            pipe.mesh_axis = dedup.axis
-        if pipe.mesh is not dedup.mesh or pipe.mesh_axis != dedup.axis:
+        if not self._rides_mesh_of(dedup):
             return super().manifest_many_classified(streams, dedup)
         results, rowflags = pipe.manifest_batch_classified(streams, dedup)
         out = []
@@ -457,7 +477,11 @@ class TpuBackend(ChunkerBackend):
             for k, ref in enumerate(refs):
                 hashes.append(ref.hash)
                 raw.append(None if fl is None else bool(fl[k]))
-        return out, dedup.resolve_hints(hashes, raw)
+        undecided = sum(1 for f in raw if f is None)
+        obs_profile.batch_chunks("device_decided", len(raw) - undecided)
+        obs_profile.batch_chunks("host_resolved", undecided)
+        with obs_trace.span("batch.resolve"):
+            return out, dedup.resolve_hints(hashes, raw)
 
 
 def _host_view(carry: memoryview, window: memoryview, off: int,

@@ -50,8 +50,16 @@ from .blake3_cpu import (
 _IV_NP = np.array(IV, dtype=np.uint32)
 
 
+# The compression is written in ``jax.lax`` primitives, not operators: an
+# operator on a tracer goes through ``jax.numpy``'s dispatch (~0.1-0.2 ms a
+# call where the primitive binds in ~0.02-0.05 ms), and a manifest program
+# traces ~8,000 of them in its compressions.  The jaxpr is the same.
+_add, _xor, _or = jax.lax.add, jax.lax.bitwise_xor, jax.lax.bitwise_or
+
+
 def _rotr(x, n: int):
-    return (x >> jnp.uint32(n)) | (x << jnp.uint32(32 - n))
+    return _or(jax.lax.shift_right_logical(x, np.uint32(n)),
+               jax.lax.shift_left(x, np.uint32(32 - n)))
 
 
 def _vma_of(*xs) -> frozenset:
@@ -105,20 +113,20 @@ def _compress_cols(cv, m, counter_lo, counter_hi, block_len, flags):
         state, m = list(carry[0]), list(carry[1])
         for i, (a, b, c, d) in enumerate(G_SCHEDULE):
             mx, my = m[2 * i], m[2 * i + 1]
-            state[a] = state[a] + state[b] + mx
-            state[d] = _rotr(state[d] ^ state[a], 16)
-            state[c] = state[c] + state[d]
-            state[b] = _rotr(state[b] ^ state[c], 12)
-            state[a] = state[a] + state[b] + my
-            state[d] = _rotr(state[d] ^ state[a], 8)
-            state[c] = state[c] + state[d]
-            state[b] = _rotr(state[b] ^ state[c], 7)
+            state[a] = _add(_add(state[a], state[b]), mx)
+            state[d] = _rotr(_xor(state[d], state[a]), 16)
+            state[c] = _add(state[c], state[d])
+            state[b] = _rotr(_xor(state[b], state[c]), 12)
+            state[a] = _add(_add(state[a], state[b]), my)
+            state[d] = _rotr(_xor(state[d], state[a]), 8)
+            state[c] = _add(state[c], state[d])
+            state[b] = _rotr(_xor(state[b], state[c]), 7)
         # permuting after the final round too is harmless (m is dropped);
         # keeping it unconditional lets the 7 rounds share one loop body
         return tuple(state), tuple(m[p] for p in MSG_PERMUTATION)
 
     state, _ = jax.lax.fori_loop(0, 7, round_body, (tuple(state), tuple(m)))
-    return [state[i] ^ state[i + 8] for i in range(8)]
+    return [_xor(state[i], state[i + 8]) for i in range(8)]
 
 
 def _bytes_to_words(buf: jnp.ndarray) -> jnp.ndarray:
@@ -142,9 +150,10 @@ def digest_padded(buf: jnp.ndarray, lens: jnp.ndarray, *, L: int,
     compression in the graph, not 16); the single-chunk ROOT variant is
     produced by stashing the last block's inputs during the scan and
     recompressing once over B lanes afterwards, instead of running a second
-    full scan.  Tree levels are unrolled (log2 L of them) with the
-    PARENT|ROOT compression computed only for pair 0, the only pair that can
-    ever finalize the root.
+    full scan.  The wide tree levels are unrolled, the narrow ones share
+    one loop (:func:`tree_reduce_groups`), and the PARENT|ROOT compression
+    is computed only for pair 0, the only pair that can ever finalize the
+    root.
 
     ``pallas=True`` swaps the leaf scan for the VMEM-resident Mosaic
     kernel (bit-identical; callers gate on
@@ -248,7 +257,121 @@ def digest_padded(buf: jnp.ndarray, lens: jnp.ndarray, *, L: int,
     return tree_reduce_cvs(leaf_cv, n_chunks, root_cv)
 
 
+# A tier's levels from 1/64 of its widest span down run as ONE loop over
+# a fixed width (at most six levels stay unrolled).  A level in the graph
+# is a compression of its own to trace, lower and compile (~0.45 s of
+# compile each on a v5e's compiler), and the narrow levels are most of
+# them and next to none of the work: the loop recomputes at its full
+# width what the unrolled form would halve, ~6 % more tree lanes.
+_TAIL_SHARE = 64
+
+
+def _merge_level(cvs, counts, root_cv):
+    """One tree level over 8 (B, cur) columns: pairs (2i, 2i+1) merge
+    into slot i where both exist in the row, an unpaired node rides up.
+    Returns the (B, ceil(cur / 2)) columns, the new counts and roots."""
+    B, cur = cvs[0].shape
+    Pn = cur // 2
+    left = [c[:, 0:2 * Pn:2] for c in cvs]   # (B, Pn)
+    right = [c[:, 1:2 * Pn:2] for c in cvs]
+    # one compression a level: the B * Pn pair merges and, behind
+    # them, pair 0 of every row again under PARENT | ROOT (the root
+    # merge, count 2 -> 1, always happens at pair 0).  A compression
+    # of their own for those B lanes doubled the loops a digest
+    # program carries, and with them what a first backup traces and
+    # compiles.
+    lanes_p = B * Pn
+    m = [jnp.concatenate([x.reshape(-1), x[:, 0]]) for x in left + right]
+    zero = jnp.zeros(lanes_p + B, dtype=jnp.uint32)
+    ivc = [jnp.broadcast_to(jnp.uint32(_IV_NP[i]), (lanes_p + B,))
+           for i in range(8)]
+    bl = jnp.full(lanes_p + B, BLOCK_LEN, dtype=jnp.uint32)
+    flags = jnp.concatenate([
+        jnp.full(lanes_p, PARENT, dtype=jnp.uint32),
+        jnp.full(B, PARENT | ROOT, dtype=jnp.uint32)])
+    both = _compress_cols(ivc, m, zero, zero, bl, flags)
+    merged = [x[:lanes_p].reshape(B, Pn) for x in both]
+    merged_root0 = [x[lanes_p:] for x in both]
+    pair_idx = jnp.arange(Pn, dtype=jnp.int32)
+    pair_merges = (2 * pair_idx[None, :] + 1) < counts[:, None]  # (B, Pn)
+    nxt = []
+    for ci in range(8):
+        col = jnp.where(pair_merges, merged[ci], left[ci])
+        if cur % 2:
+            col = jnp.concatenate([col, cvs[ci][:, -1:]], axis=1)
+        nxt.append(col)
+    is_root_merge = (counts == 2)
+    root_cv = [jnp.where(is_root_merge, mr0, rc)
+               for mr0, rc in zip(merged_root0, root_cv)]
+    counts = jnp.where(counts > 1, (counts + 1) // 2, counts)
+    return nxt, counts, root_cv
+
+
+def _merge_tail(cvs, counts, root_cv):
+    """Every remaining level of 8 (B, cur) columns in one loop: the
+    columns are padded to a power of two and each pass merges the whole
+    width (slots past a row's count hold junk that no later pass reads:
+    a pair merges only under its row's count)."""
+    cur = cvs[0].shape[1]
+    levels = max(1, (cur - 1).bit_length())
+    width = 1 << levels
+    cvs = [jnp.pad(c, ((0, 0), (0, width - cur))) for c in cvs]
+
+    def body(_, carry):
+        nxt, counts, root_cv = _merge_level(*carry)
+        return ([jnp.concatenate([c, jnp.zeros_like(c)], axis=1)
+                 for c in nxt], counts, root_cv)
+
+    _, _, root_cv = jax.lax.fori_loop(
+        0, levels, body, _vary_like((cvs, counts, list(root_cv))))
+    return root_cv
+
+
 @jax.named_scope("blake3_tree_reduce")
+def tree_reduce_groups(groups):
+    """BLAKE3 tree reductions of several groups of inputs in the levels
+    of the widest: ``groups`` is a list of ``(leaf_cv, counts, root_cv)``
+    as :func:`tree_reduce_cvs` takes them, of any row counts and spans.
+    The widest group is merged level by level; a narrower one joins its
+    rows when the width has come down to its span (rows are independent:
+    every mask is the row's own count), so the tiers of a leaf pool cost
+    the levels of one tier.  Returns one (B_i, 8) array a group, in the
+    order given.
+    """
+    order = sorted(range(len(groups)),
+                   key=lambda i: -groups[i][0][0].shape[1])
+    waiting = [groups[i] for i in order]
+    rows = [g[1].shape[0] for g in waiting]
+    cvs, counts, root_cv = (list(waiting[0][0]), waiting[0][1],
+                            list(waiting[0][2]))
+    waiting = waiting[1:]
+    tail = cvs[0].shape[1] // _TAIL_SHARE
+    while True:
+        cur = cvs[0].shape[1]
+        while waiting and waiting[0][0][0].shape[1] >= cur:
+            g_cvs, g_counts, g_root = waiting.pop(0)
+            span = g_cvs[0].shape[1]
+            cvs = [jnp.concatenate(
+                [jnp.pad(a, ((0, 0), (0, span - cur))), b])
+                for a, b in zip(cvs, g_cvs)]
+            counts = jnp.concatenate([counts, g_counts])
+            root_cv = [jnp.concatenate([a, b])
+                       for a, b in zip(root_cv, g_root)]
+            cur = span
+        if cur == 1:
+            break
+        if not waiting and 2 < cur <= tail:
+            root_cv = _merge_tail(cvs, counts, root_cv)
+            break
+        cvs, counts, root_cv = _merge_level(cvs, counts, root_cv)
+    out = jnp.stack(root_cv, axis=1)  # (sum of rows, 8) u32
+    parts, at = [None] * len(groups), 0
+    for i, n in zip(order, rows):
+        parts[i] = out[at:at + n]
+        at += n
+    return parts
+
+
 def tree_reduce_cvs(leaf_cv, counts, root_cv):
     """BLAKE3 tree reduction over per-input leaf chaining values.
 
@@ -258,45 +381,7 @@ def tree_reduce_cvs(leaf_cv, counts, root_cv):
     level; an unpaired rightmost node rides up unchanged, reproducing
     BLAKE3's largest-power-of-two-left split exactly.  Returns (B, 8).
     """
-    B = leaf_cv[0].shape[0]
-    cvs = leaf_cv  # list of 8 (B, cur) arrays
-    cur = leaf_cv[0].shape[1]
-    while cur > 1:
-        Pn = cur // 2
-        left = [c[:, 0:2 * Pn:2] for c in cvs]   # (B, Pn)
-        right = [c[:, 1:2 * Pn:2] for c in cvs]
-        m = [l.reshape(-1) for l in left] + [r.reshape(-1) for r in right]
-        lanes_p = B * Pn
-        zero = jnp.zeros(lanes_p, dtype=jnp.uint32)
-        ivc = [jnp.broadcast_to(jnp.uint32(_IV_NP[i]), (lanes_p,))
-               for i in range(8)]
-        bl = jnp.full(lanes_p, BLOCK_LEN, dtype=jnp.uint32)
-        merged = _compress_cols(ivc, m, zero, zero, bl,
-                                jnp.full(lanes_p, PARENT, dtype=jnp.uint32))
-        merged = [x.reshape(B, Pn) for x in merged]
-        # the root merge (count 2 -> 1) always happens at pair 0
-        zb = jnp.zeros(B, dtype=jnp.uint32)
-        merged_root0 = _compress_cols(
-            [jnp.broadcast_to(jnp.uint32(_IV_NP[i]), (B,)) for i in range(8)],
-            [l[:, 0] for l in left] + [r[:, 0] for r in right],
-            zb, zb, jnp.full(B, BLOCK_LEN, dtype=jnp.uint32),
-            jnp.full(B, PARENT | ROOT, dtype=jnp.uint32))
-        pair_idx = jnp.arange(Pn, dtype=jnp.int32)
-        pair_merges = (2 * pair_idx[None, :] + 1) < counts[:, None]  # (B, Pn)
-        nxt = []
-        for ci in range(8):
-            col = jnp.where(pair_merges, merged[ci], left[ci])
-            if cur % 2:
-                col = jnp.concatenate([col, cvs[ci][:, -1:]], axis=1)
-            nxt.append(col)
-        is_root_merge = (counts == 2)
-        root_cv = [jnp.where(is_root_merge, mr0, rc)
-                   for mr0, rc in zip(merged_root0, root_cv)]
-        cvs = nxt
-        counts = jnp.where(counts > 1, (counts + 1) // 2, counts)
-        cur = (cur + 1) // 2
-
-    return jnp.stack(root_cv, axis=1)  # (B, 8) u32
+    return tree_reduce_groups([(leaf_cv, counts, root_cv)])[0]
 
 
 # ---------------------------------------------------------------------------

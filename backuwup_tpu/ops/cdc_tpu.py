@@ -301,23 +301,27 @@ def _make_lookup(pos, cum, cap: int, padded: int, bb: int, probes: int = 6):
     gathered position against an in-stream bound; gap-jump targets gather
     the same sentinel either way).
     """
-    nb1 = cum.shape[0] - 1
+    return functools.partial(_lookup, pos, cum, cap=cap, padded=padded,
+                             bb=bb, probes=probes)
 
-    def lookup(q):
-        qc = jnp.clip(q, 0, padded)
-        idx0 = cum[jnp.minimum(qc >> bb, nb1)]
-        adv = jnp.zeros_like(idx0)
-        over = None
-        for k in range(probes + 1):
-            i = idx0 + k
-            below = (i < cap) & (pos[jnp.minimum(i, cap - 1)] < qc)
-            if k < probes:
-                adv = adv + below.astype(jnp.int32)
-            else:
-                over = below
-        return idx0 + adv, over
 
-    return lookup
+# jitted, so that the ~26 lookups of one ``_parallel_select`` are traced
+# once a query shape and bound as calls: seven gathers each, they were
+# more than half of what tracing a manifest program's scan + select cost
+@functools.partial(jax.jit, static_argnames=("cap", "padded", "bb", "probes"))
+def _lookup(pos, cum, q, *, cap: int, padded: int, bb: int, probes: int):
+    qc = jnp.clip(q, 0, padded)
+    idx0 = cum[jnp.minimum(qc >> bb, cum.shape[0] - 1)]
+    adv = jnp.zeros_like(idx0)
+    over = None
+    for k in range(probes + 1):
+        i = idx0 + k
+        below = (i < cap) & (pos[jnp.minimum(i, cap - 1)] < qc)
+        if k < probes:
+            adv = adv + below.astype(jnp.int32)
+        else:
+            over = below
+    return idx0 + adv, over
 
 
 def _parallel_select(pos_l, pos_s, n, *, min_size: int, desired_size: int,

@@ -28,7 +28,7 @@ flat pool of leaves through a single scan:
    essentially all the FLOPs.
 3. **Tiny tiered tree.**  Leaf chaining values (32 B/leaf — 32x smaller
    than payload) are gathered per chunk into 2-3 geometric leaf-count
-   tiers and pair-merged by :func:`blake3_tpu.tree_reduce_cvs`; tier
+   tiers and pair-merged by :func:`blake3_tpu.tree_reduce_groups`; tier
    padding costs ~1/16 of leaf work at worst, so coarse tiers are fine
    where payload-level class tiles were not.  Tier capacities cascade
    upward exactly like the class cascade (excess hands to the next tier;
@@ -76,7 +76,7 @@ from .blake3_tpu import (
     _compress_cols,
     _leaf_scan_pallas,
     _vary_like,
-    tree_reduce_cvs,
+    tree_reduce_groups,
 )
 
 
@@ -206,8 +206,8 @@ def pool_digest(flat: jnp.ndarray, offs: jnp.ndarray, lens: jnp.ndarray, *,
     cls = jnp.zeros(C, dtype=jnp.int32)
     for span, _cap in tiers[:-1]:
         cls = cls + (lv > span).astype(jnp.int32)
-    acc = jnp.zeros((C, 8), dtype=jnp.uint32)
     carry = jnp.zeros(C, dtype=bool)
+    placed = []  # a tier with room: (span, slots' chunk ids, base lanes, counts)
     for i, (span, cap) in enumerate(tiers):
         if cap == 0:
             carry = carry | (valid & (cls == i))
@@ -219,30 +219,41 @@ def pool_digest(flat: jnp.ndarray, offs: jnp.ndarray, lens: jnp.ndarray, *,
         (idx,) = jnp.nonzero(take, size=cap, fill_value=C)
         safe = jnp.clip(idx, 0, C - 1)
         got = idx < C
-        b = jnp.where(got, jnp.minimum(base[safe], leaf_cap - 1), 0)
-        cnt = jnp.where(got, lv[safe], 1)
-
-        def tile(bb):
-            return jax.lax.dynamic_slice(cv_pool, (bb, 0), (span, 8))
-
-        leaf_mat = jax.vmap(tile)(b)  # (cap, span, 8)
-        leaf_cols = [leaf_mat[:, :, ci] for ci in range(8)]
-        # single-leaf chunks: recompress leaf 0's final block with ROOT
+        placed.append((span, idx,
+                       jnp.where(got, jnp.minimum(base[safe], leaf_cap - 1), 0),
+                       jnp.where(got, lv[safe], 1)))
+    acc = jnp.zeros((C, 8), dtype=jnp.uint32)
+    if placed:
+        # single-leaf chunks: recompress leaf 0's final block with ROOT,
+        # every tier's slots in one compression
+        b = jnp.concatenate([p[2] for p in placed])
+        cnt = jnp.concatenate([p[3] for p in placed])
         nb0 = nb[b]
         m0 = jnp.take_along_axis(
-            words[b], (nb0 - 1)[:, None, None], axis=1)[:, 0]  # (cap, 16)
+            words[b], (nb0 - 1)[:, None, None], axis=1)[:, 0]  # (slots, 16)
         flags0 = (jnp.where(nb0 == 1, jnp.uint32(CHUNK_START), jnp.uint32(0))
                   | jnp.uint32(CHUNK_END) | jnp.uint32(ROOT))
-        zb = jnp.zeros(cap, dtype=jnp.uint32)
+        zb = jnp.zeros_like(lbl[b])
         root_single = _compress_cols(
             [cvpre_mat[b, ci] for ci in range(8)],
             [m0[:, w] for w in range(16)], zb, zb, lbl[b], flags0)
         root_seed = [jnp.where(cnt == 1, rs, jnp.uint32(0))
                      for rs in root_single]
-        out_tile = tree_reduce_cvs(leaf_cols, cnt, root_seed)  # (cap, 8)
-        # fill slots keep idx == C: out of range -> dropped (clipping to
-        # C-1 would duplicate-write a real chunk's row, undefined order)
-        acc = acc.at[idx].set(out_tile, mode="drop")
+        groups, at = [], 0
+        for span, idx, b_t, cnt_t in placed:
+            leaf_mat = jax.vmap(lambda bb, span=span: jax.lax.dynamic_slice(
+                cv_pool, (bb, 0), (span, 8)))(b_t)  # (cap, span, 8)
+            n = idx.shape[0]
+            groups.append(([leaf_mat[:, :, ci] for ci in range(8)], cnt_t,
+                           [rs[at:at + n] for rs in root_seed]))
+            at += n
+        # the tiers' trees in the levels of the widest (tree_reduce_groups)
+        for (_span, idx, _b, _cnt), out_tile in zip(
+                placed, tree_reduce_groups(groups)):
+            # fill slots keep idx == C: out of range -> dropped (clipping
+            # to C-1 would duplicate-write a real chunk's row, undefined
+            # order)
+            acc = acc.at[idx].set(out_tile, mode="drop")
     ovf = (jnp.sum(carry.astype(jnp.int32)) + pool_short)[None]
     return acc, ovf
 

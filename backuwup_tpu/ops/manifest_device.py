@@ -325,7 +325,8 @@ def scan_digest_batch_pool_mesh(buf_d, nv_b, *, mesh, axis: str,
                                 fused: bool, leaf_cap: int,
                                 tiers: Tuple[Tuple[int, int], ...],
                                 pallas_digest: bool = False,
-                                emit_queries: bool = False):
+                                emit_queries: bool = False,
+                                lower: bool = False):
     """Mesh twin of :func:`scan_digest_batch_pool` — same contract,
     data-parallel over the row axis with ``shard_map``.
 
@@ -336,9 +337,12 @@ def scan_digest_batch_pool_mesh(buf_d, nv_b, *, mesh, axis: str,
     per-shard overflow vector.  Bit-identical to the single-device path:
     a shard sees exactly the rows a ``B/D``-row single-device batch would,
     and every kernel is row-independent (parity-ladder posture — a mesh
-    that mis-lowers loses speed, never correctness).
+    that mis-lowers loses speed, never correctness).  With ``lower``
+    the two arguments are shapes (``jax.ShapeDtypeStruct``) and the
+    program is traced and lowered for them, not run
+    (``jax.stages.Lowered``).
     """
     fn = _mesh_scan_digest_fn(mesh, axis, min_size, desired_size, max_size,
                               mask_s, mask_l, s_cap, l_cap, cut_cap, fused,
                               leaf_cap, tiers, pallas_digest, emit_queries)
-    return fn(buf_d, nv_b)
+    return (fn.lower if lower else fn)(buf_d, nv_b)

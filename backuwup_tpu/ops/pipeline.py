@@ -28,21 +28,29 @@ time on the CPU (``dir_packer.rs:246-311``).
 from __future__ import annotations
 
 import functools
-from collections import OrderedDict, deque
+from collections import Counter, OrderedDict, deque
+from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import journal as obs_journal
 from ..obs import profile as obs_profile
 from ..obs import trace as obs_trace
-from .blake3_tpu import blake3_many_tpu, digest_padded
+from .blake3_tpu import (
+    _batch_bucket,
+    _leaf_bucket,
+    blake3_many_tpu,
+    digest_padded,
+)
 from .cdc_cpu import chunk_stream as chunk_stream_cpu
 from .cdc_tpu import (
     _HALO,
     TpuCdcScanner,
     _round_up,
+    _scan_segment,
     _segment_bucket,
     scan_select_batch,
 )
@@ -52,6 +60,16 @@ CHUNK_LEN = 1024
 
 # cap on one vmapped-scan dispatch (rows x row bytes)
 _SCAN_DISPATCH_BYTES = 128 * 1024 * 1024
+# a long stream's leaf-pool digest is compiled for its length rounded up
+# to this step (at most four programs between the scan segment and the
+# packer's batch_bytes)
+_POOL_STREAM_STEP = 32 * 1024 * 1024
+
+# programs of the batched route this process has compiled ahead (see
+# DevicePipeline.compile_side_by_side); process-wide, as jit's caches are
+_RAN: set = set()
+# their kinds (the first element of a key), the longest to compile first
+_COMPILE_ORDER = ("mesh", "pool", "scan", "tiny")
 
 
 def _pad_to(arr: np.ndarray, n: int) -> np.ndarray:
@@ -556,6 +574,37 @@ class DevicePipeline:
             self.mesh = Mesh(np.array(jax.devices()), (self.mesh_axis,))
         return self.mesh
 
+    def _row_sharding(self):
+        """Rows over the mesh axis: how a batch goes to the mesh driver."""
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        return NamedSharding(self._ensure_mesh(), P(self.mesh_axis))
+
+    def _mesh_program(self, buf_sh, nv_sh, emit_queries: bool,
+                      lower: bool = False):
+        """The shard-mapped manifest program over one sharded
+        ``(B, _HALO + padded)`` batch; the batch's shape and this
+        pipeline's selections name the program.  ``lower``: the
+        arguments are shapes, and the program is traced and lowered for
+        them, not run."""
+        from .digest_pool import leaf_capacity
+        from .manifest_device import scan_digest_batch_pool_mesh, tier_plan
+
+        p = self.params
+        bs = int(buf_sh.shape[0]) // int(self.mesh.devices.size)
+        padded = int(buf_sh.shape[1]) - _HALO
+        s_cap, l_cap, cut_cap = self._caps(padded)
+        return scan_digest_batch_pool_mesh(
+            buf_sh, nv_sh, mesh=self.mesh, axis=self.mesh_axis,
+            min_size=p.min_size, desired_size=p.desired_size,
+            max_size=p.max_size, mask_s=p.mask_s, mask_l=p.mask_l,
+            s_cap=s_cap, l_cap=l_cap, cut_cap=cut_cap, fused=self.fused,
+            leaf_cap=leaf_capacity(bs * padded, bs * cut_cap),
+            tiers=tier_plan(p, bs * padded, bs),
+            pallas_digest=self.pallas_digest, emit_queries=emit_queries,
+            lower=lower)
+
     def manifest_segments_mesh(self, segments, strict_overflow: bool = False,
                                window: int = 4, dedup=None):
         """Multi-device pipelined driver (generator): the zero-round-trip
@@ -583,12 +632,6 @@ class DevicePipeline:
         dup hints.  Without ``dedup`` it yields plain rows, bit-identical
         to the single-device driver.
         """
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
-
-        from .digest_pool import leaf_capacity
-        from .manifest_device import scan_digest_batch_pool_mesh, tier_plan
-
         if not self.pool_digest:
             # parity ladder: no mesh twin for the class-tile digest —
             # fall back to the single-device driver (flags all None, the
@@ -599,11 +642,8 @@ class DevicePipeline:
                     else rows
             return
 
-        mesh = self._ensure_mesh()
-        axis = self.mesh_axis
-        D = int(mesh.devices.size)
-        sharding = NamedSharding(mesh, P(axis))
-        p = self.params
+        sharding = self._row_sharding()
+        D = int(self.mesh.devices.size)
         it = iter(segments)
         pending: deque = deque()
         state = {"in_flight": 0}
@@ -622,20 +662,11 @@ class DevicePipeline:
                     nv = np.pad(nv, (0, B - B0))
                 bs = B // D
                 padded = row - _HALO
-                s_cap, l_cap, cut_cap = self._caps(padded)
+                cut_cap = self._caps(padded)[2]
                 with obs_trace.span("pipeline.mesh_dispatch"):
-                    buf_sh = jax.device_put(buf, sharding)
-                    nv_sh = jax.device_put(nv, sharding)
-                    rets = scan_digest_batch_pool_mesh(
-                        buf_sh, nv_sh, mesh=mesh, axis=axis,
-                        min_size=p.min_size, desired_size=p.desired_size,
-                        max_size=p.max_size, mask_s=p.mask_s,
-                        mask_l=p.mask_l, s_cap=s_cap, l_cap=l_cap,
-                        cut_cap=cut_cap, fused=self.fused,
-                        leaf_cap=leaf_capacity(bs * padded, bs * cut_cap),
-                        tiers=tier_plan(p, bs * padded, bs),
-                        pallas_digest=self.pallas_digest,
-                        emit_queries=dedup is not None)
+                    rets = self._mesh_program(
+                        jax.device_put(buf, sharding),
+                        jax.device_put(nv, sharding), dedup is not None)
                     if dedup is not None:
                         packed, acc, ovf, q = rets
                         found_d, lost_d = dedup.classify_dispatch(q)
@@ -717,87 +748,192 @@ class DevicePipeline:
             hb = buf if isinstance(buf, np.ndarray) else None
             out: List = [None] * B
             flags: List = [None] * B
-            for s in range(D):
-                r0, r1 = s * bs, (s + 1) * bs
-                if s in bad:
-                    # per-shard fallback: ONLY this shard's rows re-run on
-                    # the host-tiled path (the tentpole's whole point —
-                    # adversarial data costs one shard, not the batch)
-                    if hb is None:
-                        hb = np.asarray(buf)
-                    obs_profile.mesh_host_rerun("shard", max(0, min(r1, B0) - r0))
-                    (sub,) = self.manifest_segments(
-                        [(jnp.asarray(hb[r0:r1]), nv[r0:r1])])
-                    for r in range(r0, min(r1, B0)):
-                        out[r] = sub[r - r0]
-                    continue
-                for r in range(r0, min(r1, B0)):
-                    overflow, chunks = _decode_cut_row(packed[r])
-                    if overflow:
-                        if strict_overflow:
-                            raise RuntimeError(
-                                "candidate overflow in scan+select")
-                        obs_profile.mesh_host_rerun("row", 1)
+            with obs_trace.span("batch.decode"):
+                for s in range(D):
+                    r0, r1 = s * bs, (s + 1) * bs
+                    if s in bad:
+                        # per-shard fallback: ONLY this shard's rows re-run
+                        # on the host-tiled path (the tentpole's whole point:
+                        # adversarial data costs one shard, not the batch)
                         if hb is None:
                             hb = np.asarray(buf)
-                        rowb = bytes(hb[r, _HALO:_HALO + int(nv[r])])
-                        chunks = chunk_stream_cpu(rowb, self.params)
-                        digs = np.stack([np.frombuffer(
-                            _blake3_host(rowb[o:o + ln]), dtype=np.uint8)
-                            for o, ln in chunks]) if chunks else \
-                            np.zeros((0, 32), dtype=np.uint8)
-                        out[r] = (chunks, digs)
+                        obs_profile.mesh_host_rerun(
+                            "shard", max(0, min(r1, B0) - r0))
+                        (sub,) = self.manifest_segments(
+                            [(jnp.asarray(hb[r0:r1]), nv[r0:r1])])
+                        for r in range(r0, min(r1, B0)):
+                            out[r] = sub[r - r0]
                         continue
-                    out[r] = (chunks, dig8[r, :len(chunks)].copy())
-                    if found is not None and not lost[r, :len(chunks)].any():
-                        flags[r] = found[r, :len(chunks)] != 0
+                    for r in range(r0, min(r1, B0)):
+                        overflow, chunks = _decode_cut_row(packed[r])
+                        if overflow:
+                            if strict_overflow:
+                                raise RuntimeError(
+                                    "candidate overflow in scan+select")
+                            obs_profile.mesh_host_rerun("row", 1)
+                            if hb is None:
+                                hb = np.asarray(buf)
+                            rowb = bytes(hb[r, _HALO:_HALO + int(nv[r])])
+                            chunks = chunk_stream_cpu(rowb, self.params)
+                            digs = np.stack([np.frombuffer(
+                                _blake3_host(rowb[o:o + ln]), dtype=np.uint8)
+                                for o, ln in chunks]) if chunks else \
+                                np.zeros((0, 32), dtype=np.uint8)
+                            out[r] = (chunks, digs)
+                            continue
+                        out[r] = (chunks, dig8[r, :len(chunks)].copy())
+                        if found is not None \
+                                and not lost[r, :len(chunks)].any():
+                            flags[r] = found[r, :len(chunks)] != 0
             if dedup is not None:
                 yield out[:B0], flags[:B0]
             else:
                 yield out[:B0]
 
-    def _manifest_prepass(self, streams, out: List) -> dict:
-        """Route a stream batch: fills ``out`` for empty/tiny/long streams
-        (the non-batched shapes) and returns the {padded_len: [idx...]}
-        groups the resident batch drivers consume."""
+    def _route(self, sizes):
+        """One batch's stream indices by length: (empty, tiny, long,
+        {padded length: [idx...]} for the resident batch drivers)."""
         p = self.params
+        empty: List[int] = []
         tiny: List[int] = []
+        long: List[int] = []
         groups: dict = {}
-        for i, s in enumerate(streams):
-            n = len(s)
+        for i, n in enumerate(sizes):
             if n == 0:
-                out[i] = ([], np.zeros((0, 32), dtype=np.uint8))
+                empty.append(i)
             elif n <= p.min_size:
                 # sub-min streams are always exactly one chunk (select_cuts
                 # first rule), so the scan is skipped entirely — many tiny
                 # files cost one batched digest, not 64 KiB-padded scans
                 tiny.append(i)
             elif n > self.scanner.segment_size:
-                # long stream: segmented device scan, then resident digest
+                long.append(i)
+            else:
+                groups.setdefault(_segment_bucket(n), []).append(i)
+        return empty, tiny, long, groups
+
+    def _first_use_jobs(self, sizes, emit_queries: Optional[bool]) -> dict:
+        """{program key: a thunk that traces and lowers it} for every
+        program one batch of streams of these ``sizes`` runs: a digest
+        batch a leaf class of tiny files, two scans and a leaf pool a
+        long stream, the manifest program a bucket shape
+        (``emit_queries`` names the mesh driver's; None: the caller's
+        driver is not the mesh).  Each lowers the jitted callable the
+        data goes through, with the same static arguments, dtypes and
+        shardings, so the batch's own call finds it compiled."""
+        p = self.params
+        spec = jax.ShapeDtypeStruct
+        _empty, tiny, long, groups = self._route(sizes)
+        jobs: dict = {}
+        for L, n in Counter(_leaf_bucket(sizes[i]) for i in tiny).items():
+            B = _batch_bucket(n)
+            jobs["tiny", B, L] = lambda B=B, L=L: digest_padded.lower(
+                spec((B, L * CHUNK_LEN), jnp.uint8), spec((B,), jnp.int32),
+                L=L)
+        seg = self.scanner.segment_size
+        for n in {sizes[i] for i in long}:
+            for part in {seg, n % seg} - {0}:
+                padded = _segment_bucket(part)
+                jobs["scan", padded] = \
+                    lambda padded=padded: _scan_segment.lower(
+                        spec((_HALO + padded,), jnp.uint8),
+                        spec((), jnp.int32), spec((), jnp.uint32),
+                        spec((), jnp.uint32),
+                        k_cap=self.scanner._k_cap(padded))
+            if self.pool_digest:
+                step = -(-n // _POOL_STREAM_STEP) * _POOL_STREAM_STEP
+                jobs["pool", step] = lambda step=step: self._pool_program(
+                    spec((step + CHUNK_LEN,), jnp.uint8),
+                    *[spec((step // p.min_size + 1,), jnp.int32)] * 2,
+                    lower=True)
+        if emit_queries is not None and self.pool_digest:
+            sharding = self._row_sharding()
+            D = int(self.mesh.devices.size)
+            for padded, _part, B in self._batch_shapes(groups):
+                shape = (-(-B // D) * D, _HALO + padded)
+                jobs["mesh", shape, emit_queries] = \
+                    lambda shape=shape: self._mesh_program(
+                        spec(shape, jnp.uint8, sharding=sharding),
+                        spec(shape[:1], jnp.int32, sharding=sharding),
+                        emit_queries, lower=True)
+        return jobs
+
+    def compile_side_by_side(self, batches,
+                             emit_queries: Optional[bool]) -> None:
+        """Compile every program that batches of streams of these sizes
+        (``batches``: a list of lists of lengths) need and this process
+        has not compiled: traced and lowered here, one after the other
+        (tracing holds the interpreter lock, and threads that trace side
+        by side only take turns at it, at twice the cost), each handed
+        to a thread of its own as soon as it is lowered, where XLA
+        compiles it outside the lock.  The programs compile side by side
+        instead of in the order the backup reaches them, and the batch's
+        own call finds them in ``jit``'s caches.  Nothing to do once
+        they are compiled."""
+        jobs: dict = {}
+        for sizes in batches:
+            jobs.update(self._first_use_jobs(sizes, emit_queries))
+        # the longest to compile first: the last one lowered is the one
+        # whose compile nothing overlaps
+        todo = [job for key, job in sorted(
+            jobs.items(), key=lambda kv: _COMPILE_ORDER.index(kv[0][0]))
+            if key not in _RAN]
+        if len(todo) > 1:
+            with obs_trace.span("batch.compile"), \
+                    ThreadPoolExecutor(len(todo)) as pool:
+                failed = []
+                futs = []
+                for job in todo:
+                    try:
+                        futs.append(pool.submit(job().compile))
+                    except Exception as e:  # noqa: BLE001
+                        failed.append(e)
+                failed += [e for e in (f.exception() for f in futs) if e]
+                for e in failed:
+                    # left to the batch itself, which meets the fault
+                    # where its cause can be told
+                    obs_journal.emit("compile_ahead_failed", error=repr(e))
+        _RAN.update(jobs)
+
+    def _manifest_prepass(self, streams, out: List) -> dict:
+        """Route a stream batch: fills ``out`` for empty/tiny/long streams
+        (the non-batched shapes) and returns the {padded_len: [idx...]}
+        groups the resident batch drivers consume."""
+        sizes = [len(s) for s in streams]
+        empty, tiny, long, groups = self._route(sizes)
+        for i in empty:
+            out[i] = ([], np.zeros((0, 32), dtype=np.uint8))
+        for i in long:
+            # long stream: segmented device scan, then resident digest
+            s, n = streams[i], sizes[i]
+            with obs_trace.span("batch.long_stream"):
                 chunks = self.scanner.chunk_stream(s)
                 obs_profile.dispatch("scan", actual_bytes=n, padded_bytes=n)
                 obs_profile.dispatch("select", actual_bytes=n,
                                      padded_bytes=n)
                 dev = jnp.asarray(np.frombuffer(bytes(s), dtype=np.uint8))
                 out[i] = (chunks, self.digest_chunks(dev, chunks))
-            else:
-                groups.setdefault(_segment_bucket(n), []).append(i)
+        obs_profile.batch_files("long", len(long))
+        obs_profile.batch_files("tiny", len(tiny))
+        obs_profile.batch_files("bucketed",
+                                sum(len(g) for g in groups.values()))
         if tiny:
-            digs = blake3_many_tpu([streams[i] for i in tiny])
-            tiny_bytes = sum(len(streams[i]) for i in tiny)
+            with obs_trace.span("batch.tiny_digest"):
+                digs = blake3_many_tpu([streams[i] for i in tiny])
+            tiny_bytes = sum(sizes[i] for i in tiny)
             obs_profile.dispatch("digest", actual_bytes=tiny_bytes,
                                  padded_bytes=tiny_bytes)
             for i, d in zip(tiny, digs):
-                out[i] = ([(0, len(streams[i]))],
+                out[i] = ([(0, sizes[i])],
                           np.frombuffer(d, dtype=np.uint8).reshape(1, 32))
         return groups
 
-    def _bucketed_batches(self, streams, groups: dict, batch_rows: deque):
-        """Generator of (host buf, nv) resident batches for the grouped
-        streams; appends each batch's stream indices to ``batch_rows``."""
+    @staticmethod
+    def _batch_shapes(groups: dict):
+        """(padded length, stream indices, rows) of every resident batch
+        the grouped streams make, in dispatch order."""
         for padded, idxs in sorted(groups.items()):
-            row = _HALO + padded
-            max_rows = max(1, _SCAN_DISPATCH_BYTES // row)
+            max_rows = max(1, _SCAN_DISPATCH_BYTES // (_HALO + padded))
             # pow2 row padding, clamped by the dispatch budget (largest
             # pow2 <= max_rows): a lone 128 MiB stream must not balloon
             # to 8 identical rows, and a full part must not double past
@@ -808,14 +944,21 @@ class DevicePipeline:
                 B = min(8, b_cap)
                 while B < len(part):
                     B *= 2
-                buf = np.zeros((B, row), dtype=np.uint8)
+                yield padded, part, B
+
+    def _bucketed_batches(self, streams, groups: dict, batch_rows: deque):
+        """Generator of (host buf, nv) resident batches for the grouped
+        streams; appends each batch's stream indices to ``batch_rows``."""
+        for padded, part, B in self._batch_shapes(groups):
+            with obs_trace.span("batch.stage"):
+                buf = np.zeros((B, _HALO + padded), dtype=np.uint8)
                 nv = np.zeros(B, dtype=np.int32)
                 for r, i in enumerate(part):
                     d = np.frombuffer(bytes(streams[i]), dtype=np.uint8)
                     buf[r, _HALO:_HALO + len(d)] = d
                     nv[r] = len(d)
-                batch_rows.append(part)
-                yield buf, nv
+            batch_rows.append(part)
+            yield buf, nv
 
     def manifest_batch(self, streams) -> List[Tuple[List[tuple], np.ndarray]]:
         """Chunk + fingerprint a batch of independent streams, resident.
@@ -870,6 +1013,53 @@ class DevicePipeline:
             b *= 2
         return min(b, self.l_bucket) if need <= self.l_bucket else need
 
+    def _pool_program(self, flat, offs, lens, lower: bool = False):
+        """The leaf pool over one padded stream (``_POOL_STREAM_STEP``
+        bytes a step, ``CHUNK_LEN`` of slack) and its chunk table; the
+        two shapes and this pipeline's selections name the program.
+        ``lower``: the arguments are shapes, and the program is traced
+        and lowered for them, not run."""
+        from .digest_pool import leaf_capacity, pool_digest
+        from .manifest_device import tier_plan
+
+        padded = int(flat.shape[0]) - CHUNK_LEN
+        return (pool_digest.lower if lower else pool_digest)(
+            flat, offs, lens,
+            leaf_cap=leaf_capacity(padded, int(offs.shape[0])),
+            tiers=tier_plan(self.params, padded, 1),
+            pallas=self.pallas_digest)
+
+    def _digest_chunks_pool(self, stream: jnp.ndarray,
+                            chunks: List[tuple]) -> Optional[np.ndarray]:
+        """The chunks of one resident stream through the leaf pool
+        (:func:`..digest_pool.pool_digest`), as the mesh program digests
+        a batch: ONE program a stream-length step where the class tiles
+        below are one for every (rows, leaves) pair a file's chunk
+        lengths happen to fill (six for a 136 MiB file at 1 MiB chunks,
+        each a first backup's compile).  The stream is padded to the
+        next multiple of ``_POOL_STREAM_STEP`` so that files of
+        different lengths share programs.  None where the tier cascade
+        overflowed (lengths far from the expected histogram): the caller
+        falls back to the tiles, bit-exact either way."""
+        n = int(stream.shape[0])
+        padded = -(-n // _POOL_STREAM_STEP) * _POOL_STREAM_STEP
+        cap = padded // self.params.min_size + 1
+        if len(chunks) > cap:
+            return None
+        meta = np.zeros((2, cap), dtype=np.int32)
+        meta[:, :len(chunks)] = np.asarray(chunks, dtype=np.int32).T
+        acc, ovf = self._pool_program(
+            jnp.pad(stream, (0, padded + CHUNK_LEN - n)),
+            jnp.asarray(meta[0]), jnp.asarray(meta[1]))
+        for stage in ("gather", "digest"):
+            obs_profile.dispatch(stage, actual_bytes=int(meta[1].sum()),
+                                 padded_bytes=padded)
+        if int(np.asarray(ovf)[0]):
+            return None
+        got = np.asarray(acc)[:len(chunks)]
+        return np.ascontiguousarray(got.astype("<u4")).view(
+            np.uint8).reshape(len(chunks), 32)
+
     def digest_chunks(self, stream: jnp.ndarray, chunks: List[tuple]) -> np.ndarray:
         """Gather + digest chunk spans of a resident stream; (N, 32) u8.
 
@@ -878,6 +1068,10 @@ class DevicePipeline:
         """
         if not chunks:
             return np.zeros((0, 32), dtype=np.uint8)
+        if self.pool_digest:
+            pooled = self._digest_chunks_pool(stream, chunks)
+            if pooled is not None:
+                return pooled
         # slack so the fixed-span gathers never clamp (dynamic_slice clips
         # out-of-range starts, which would shift data)
         stream = jnp.pad(stream, (0, self.l_bucket * CHUNK_LEN))

@@ -184,7 +184,24 @@ class DirPacker:
         batch_data: List[bytes] = []
         batch_meta: List[TreeMetadata] = []
 
+        to_read: List[tuple] = []
+
         def flush_batch():
+            if to_read:
+                with obs_trace.span("batch.read"):
+                    for i, path, st in to_read:
+                        try:
+                            data = path.read_bytes()
+                        except OSError:
+                            self.stats.failed_files += 1
+                            continue
+                        self.stats.bytes_read += len(data)
+                        batch_idx.append(i)
+                        batch_data.append(data)
+                        batch_meta.append(TreeMetadata(
+                            size=len(data), mtime_ns=st.st_mtime_ns,
+                            ctime_ns=st.st_ctime_ns))
+                to_read.clear()
             if not batch_idx:
                 return
             t0 = time.monotonic()
@@ -225,18 +242,20 @@ class DirPacker:
                 self._flush_device_sync()
                 hints = iter(self.dedup_batch(
                     [ref.hash for m in manifests for ref in m]))
-            for i, data, meta, manifest in zip(batch_idx, batch_data,
-                                               batch_meta, manifests):
-                for ref in manifest:
-                    self.stats.chunks += 1
-                    self._add_blob(ref.hash, BlobKind.FILE_CHUNK,
-                                   data[ref.offset:ref.offset + ref.length],
-                                   dup_hint=next(hints, None))
-                hashes[i] = self._tree_with_split(
-                    TreeKind.FILE, files[i].name, meta,
-                    [ref.hash for ref in manifest])
-                self.stats.files += 1
-                self.progress(file=str(files[i]), bytes=len(data))
+            with obs_trace.span("batch.emit"):
+                for i, data, meta, manifest in zip(batch_idx, batch_data,
+                                                   batch_meta, manifests):
+                    for ref in manifest:
+                        self.stats.chunks += 1
+                        self._add_blob(
+                            ref.hash, BlobKind.FILE_CHUNK,
+                            data[ref.offset:ref.offset + ref.length],
+                            dup_hint=next(hints, None))
+                    hashes[i] = self._tree_with_split(
+                        TreeKind.FILE, files[i].name, meta,
+                        [ref.hash for ref in manifest])
+                    self.stats.files += 1
+                    self.progress(file=str(files[i]), bytes=len(data))
             self._flush_device_sync()
             self._maybe_emit_partial()
             batch_idx.clear()
@@ -251,17 +270,11 @@ class DirPacker:
                     # oversized file: stream it so memory stays bounded
                     hashes[i] = self._pack_file_streaming(path, st)
                     continue
-                data = path.read_bytes()
             except OSError:
                 self.stats.failed_files += 1
                 continue
-            self.stats.bytes_read += len(data)
-            batch_idx.append(i)
-            batch_data.append(data)
-            batch_meta.append(TreeMetadata(
-                size=len(data), mtime_ns=st.st_mtime_ns,
-                ctime_ns=st.st_ctime_ns))
-            pending += len(data)
+            to_read.append((i, path, st))
+            pending += st.st_size
             if pending >= self.batch_bytes:
                 flush_batch()
                 pending = 0
@@ -350,6 +363,39 @@ class DirPacker:
 
     # --- directory walk ----------------------------------------------------
 
+    def _batch_sizes(self, dirs: List[Path]):
+        """The file lengths of the pack batches this backup will make,
+        a list a batch (a directory's files up to ``batch_bytes``, as
+        :meth:`_pack_files` cuts them; a larger file is streamed), for
+        ``ChunkerBackend.prepare_batches``: a backend that compiles a
+        program a shape compiles side by side what they need, any other
+        never starts this walk.  One ``lstat`` a file; a file that
+        changes before it is read costs a compile in its batch, nothing
+        else."""
+        for d in dirs:
+            sizes: List[int] = []
+            pending = 0
+            try:
+                entries = sorted(d.iterdir())
+            except OSError:
+                continue
+            for p in entries:
+                try:
+                    if p.is_symlink() or not p.is_file():
+                        continue
+                    n = p.lstat().st_size
+                except OSError:
+                    continue
+                if n > self.batch_bytes:
+                    continue
+                sizes.append(n)
+                pending += n
+                if pending >= self.batch_bytes:
+                    yield sizes
+                    sizes, pending = [], 0
+            if sizes:
+                yield sizes
+
     def pack(self, root: Path) -> bytes:
         """Pack ``root`` recursively; returns the snapshot id (root hash)."""
         root = Path(root)
@@ -365,6 +411,9 @@ class DirPacker:
             except OSError:
                 subdirs = []
             order.extend(subdirs)
+        if self.dedup_index is not None:
+            self.backend.prepare_batches(self._batch_sizes(order),
+                                         self.dedup_index)
         dir_hash: dict = {}
         for d in reversed(order):
             try:

@@ -1,0 +1,6 @@
+"""``batch_hbm_floor_share``: ``hbm_floor_share`` read in the batched
+route's cell: the least time the chip's memory could take over the traced
+backup's user bytes, as a share of the seconds an operation actually ran
+on the device.  Bound: memory (one read of every byte)."""
+
+from benchmark.layer_metrics.hbm_floor_share import read  # noqa: F401

@@ -3,6 +3,7 @@
 import random
 
 import numpy as np
+import pytest
 import jax.numpy as jnp
 
 from backuwup_tpu.ops.blake3_cpu import blake3_hash
@@ -53,6 +54,21 @@ def test_digest_padded_direct():
     root = np.asarray(digest_padded(jnp.asarray(buf), jnp.asarray(lens), L=16))
     for i, d in enumerate(datas):
         assert root[i].astype("<u4").tobytes() == blake3_hash(d)
+
+
+@pytest.mark.parametrize("L", [3, 200, 768, 3072])
+def test_digest_padded_tree_levels(L):
+    """The tree reduction at widths whose narrow levels run unrolled (3,
+    200) and in the tail loop (768: from 12 columns; 3072: from 48), with
+    lengths at both ends of the row and around leaf boundaries."""
+    from backuwup_tpu.ops.pipeline import _blake3_host
+    rng = np.random.default_rng(L)
+    lens = np.array([L * 1024, (L - 1) * 1024 + 1, 2049, 0], dtype=np.int32)
+    buf = rng.integers(0, 256, (len(lens), L * 1024), dtype=np.uint8)
+    root = np.asarray(digest_padded(jnp.asarray(buf), jnp.asarray(lens), L=L))
+    for i, n in enumerate(lens):
+        assert root[i].astype("<u4").tobytes() == \
+            _blake3_host(buf[i, :n].tobytes()), (L, n)
 
 
 def test_bucketing_covers_all_inputs_once():

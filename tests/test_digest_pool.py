@@ -110,6 +110,41 @@ def test_pool_digest_tier_cascade_and_overflow():
     assert ovf > 0
 
 
+@pytest.mark.parametrize("spans, lens", [
+    # the shipped constants' tiers: the trees join level by level and the
+    # levels under 3072 / 64 run in the tail loop
+    ((768, 1536, 3072), [3 << 20, (2 << 20) + 7, (1 << 20) + 1025,
+                         700 * 1024, 300_001, 1024, 1, 1025]),
+    # spans that are no halves of each other: the wider group is padded
+    # up to the narrower one's span where they join
+    ((56, 100), [100 * 1024, 99 * 1024 + 1, 57 * 1024, 56 * 1024,
+                 55 * 1024 + 3, 2049, 64]),
+    # one tier, a span that is no power of two, tail loop over 3 columns
+    ((200,), [200 * 1024, 199 * 1024 + 5, 3 * 1024 + 1, 2 * 1024, 7]),
+], ids=["shipped-768-1536-3072", "uneven-56-100", "single-200"])
+def test_pool_digest_tiers_share_levels(spans, lens):
+    """``tree_reduce_groups``: every tier's chunks digest to the oracle's
+    value whatever the spans, with fewer slots in a tier than chunks of
+    its class so that some cascade into the next."""
+    from backuwup_tpu.ops.pipeline import _blake3_host
+    rng = np.random.default_rng(len(spans))
+    offs, cur = [], 0
+    for ln in lens:
+        offs.append(cur)
+        cur += ln
+    flat = rng.integers(0, 256, cur, dtype=np.uint8)
+    C = 16
+    tiers = tuple((s, 4 if i < len(spans) - 1 else C)
+                  for i, s in enumerate(spans))
+    acc, ovf = _run_pool(flat, offs, lens, C=C, tiers=tiers)
+    assert ovf == 0
+    got = _digests_of(acc)
+    for i, ln in enumerate(lens):
+        assert got[i] == _blake3_host(flat[offs[i]:offs[i] + ln].tobytes()), \
+            (spans, ln)
+    assert not acc[len(lens):].any()  # unused slots stay zero
+
+
 def test_pool_digest_leaf_cap_shortfall_flagged():
     flat = np.zeros(32 * 1024, np.uint8)
     acc, ovf = _run_pool(flat, [0, 8192], [8192, 8192], C=4,
