@@ -523,8 +523,9 @@ def scan_select_batch(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
     the oracle.
 
     With ``fused=True`` the hash+mask+pack front end runs as the Mosaic
-    strip kernel (:func:`backuwup_tpu.ops.scan_fused.fused_candidate_words`,
-    ~7x less wall clock than the XLA ladder); callers gate on
+    strip kernel (:func:`backuwup_tpu.ops.scan_fused.fused_candidate_words`;
+    PERF.md section 5 has its device seconds beside the XLA ladder's);
+    callers gate on
     :func:`backuwup_tpu.ops.scan_fused.fused_scan_available`, which
     parity-checks the kernel against the XLA path on the live runtime.
     """

@@ -2,21 +2,21 @@
 
 The XLA scan (:func:`.cdc_tpu._hash_ext_fast`) pays HBM for every pass:
 the fmix32 gear values and each of the five doubling-ladder passes
-materialize a u32 array the size of 4x the stream (~45 bytes of HBM
-traffic per stream byte, the measured ~200 ms/256 MiB floor).  This
-kernel runs the whole scan per VMEM-resident tile and writes only the
-packed candidate words (1/4 byte per stream byte), so HBM traffic drops
-to ~1.3 bytes per stream byte — within striking distance of the
-bandwidth floor.
+materialize a u32 array the size of 4x the stream.  This kernel runs the
+whole scan per VMEM-resident tile and writes only the packed candidate
+words (1/4 byte per stream byte), so HBM sees the stream as bytes (the
+row, its aligned copy, the strip matrix written and read) and the words.
+Device time on one TPU v5e (PR 35's probe; PERF.md section 5 has the
+table): a ``(1, 31 + 64 MiB)`` row 3.0 ms, of it the aligned copy 1.1,
+the strip transpose 0.5 and the v2 kernel 1.4 (v1's kernel 1.6); 32 rows
+of 1 MiB 1.0 ms; 128 rows of 1 MiB 3.9 ms.
 
-Layout — the **strip decomposition** (PERF.md round-4 direction 2): the
-P-byte stream is split into 128 contiguous strips of S = P/128 bytes;
-strip ``l`` occupies lane ``l`` of a ``(S, 128)`` u8 array with stream
-position ``l*S + r`` at row ``r``.  A shift by ``s`` positions is then a
-pure **sublane** shift (rows), never a lane relayout — the failure mode
-that sank round 3's flat-layout ladder kernel (~100-130 ms; PERF.md
-"dead ends").  Each strip carries a 32-byte halo of the previous strip's
-tail (real bytes, so hashes at strip starts are exact; only global
+Layout — the **strip decomposition**: the P-byte stream is split into
+128 contiguous strips of S = P/128 bytes; strip ``l`` occupies lane
+``l`` of a ``(S, 128)`` u8 array with stream position ``l*S + r`` at row
+``r``.  A shift by ``s`` positions is then a pure **sublane** shift
+(rows), never a lane relayout.  Each strip carries a 32-byte halo of the
+previous strip's tail (real bytes, so hashes at strip starts are exact; only global
 position 0 sees the spec's zero halo), and each grid step's tile carries
 a 32-row halo of the previous tile via a second clamped BlockSpec.
 
@@ -65,29 +65,52 @@ def _words_shape(B: int, S: int, *inputs):
     return jax.ShapeDtypeStruct((B, S // 32, _LANES), jnp.uint32, vma=vma)
 
 
-def _make_scan_kernel_u32(mask_s: int, mask_l: int, S: int, R32: int):
-    """v2 kernel: the stream stays packed 4 bytes/u32 END TO END.
+def _words(ref):
+    """A ``(1, 4R, 128) u8`` strip block as its ``(R, 128) u32`` words."""
+    return pltpu.bitcast(ref[0], jnp.uint32)
 
-    v1 transposes the full u8 stream into strip-major layout (the
-    dominant XLA-side cost of the fused scan: a 256 MiB u8 relayout) and
-    re-expands bytes to u32 inside the kernel.  Here the host-side
-    transpose moves S/4 u32 rows (4x fewer elements, register-width
-    lanes), and the kernel never materializes per-byte arrays at all:
+
+def _strip_matrix(ext_b: jnp.ndarray):
+    """``(B, 31+P) u8`` rows as the strip matrix the kernels read:
+    ``body[b, r, l] = stream[b, l*S + r]`` (``(B, S, 128) u8``, one
+    transpose) and ``halo0`` (``(B, 32, 128) u8``), the 32 bytes ahead of
+    each strip: strip ``l-1``'s tail, and for strip 0 the spec's zero byte
+    plus the row's 31 halo bytes."""
+    B, n = ext_b.shape
+    S = (n - 31) // _LANES
+    ext32 = jnp.pad(ext_b, ((0, 0), (1, 0)))
+    body = ext32[:, 32:].reshape(B, _LANES, S).transpose(0, 2, 1)
+    halo0 = jnp.concatenate(
+        [ext32[:, :32, None], body[:, S - _HALO_ROWS:, :-1]], axis=2)
+    return body, halo0
+
+
+def _make_scan_kernel_u32(mask_s: int, mask_l: int, S: int, R: int):
+    """v2 kernel: four stream bytes a u32 word, from the load on.
+
+    Fed v1's ``u8`` strip matrix.  On the TPU four consecutive sublane
+    rows of one lane of a ``u8`` array share a 32-bit word (the
+    ``(32, 128)`` byte tile is ``(8, 128)`` words), so a ``(4R, 128)``
+    ``u8`` block in VMEM *is* the ``(R, 128)`` ``u32`` block of packed
+    stream words: ``pltpu.bitcast`` names it, nothing moves.  The kernel
+    never materializes per-byte arrays:
     positions p = 4r+k live in four interleaved (rows, 128) u32 gear
     planes, a ladder shift by s byte positions is a plane permutation
     ``k -> (k-s) mod 4`` plus a sublane shift of ``(s+k'-k)/4`` rows,
     and the 32:1 bit-pack ORs plane bits at ``4r'+k``.  Bit-identical to
-    v1/_pack_bits by construction; the import-time parity gate
-    (:func:`fused_scan_available`) proves it on the live runtime before
+    v1/_pack_bits by construction; the byte order inside the word is
+    proven, not assumed, by the parity gate
+    (:func:`fused_scan_available`) on the live runtime before
     production use.
     """
     HR = _HALO_ROWS // 4  # 8 u32 rows = the 32-byte halo
+    R32 = R // 4  # word rows a grid step
 
     def kernel(nv_ref, halo0_ref, main_ref, prev_ref, wl_ref, ws_ref):
         b = pl.program_id(0)
         i = pl.program_id(1)
-        halo = jnp.where(i > 0, prev_ref[0], halo0_ref[0])  # (HR, 128) u32
-        w = jnp.concatenate([halo, main_ref[0]], axis=0)  # (R32+HR, 128)
+        halo = jnp.where(i > 0, _words(prev_ref), _words(halo0_ref))  # (HR, 128)
+        w = jnp.concatenate([halo, _words(main_ref)], axis=0)  # (R32+HR, 128)
         rows = R32 + HR
         # per-byte gear values, one plane per byte-in-word slot
         g = [_fmix32_u32((w >> jnp.uint32(8 * k)) & jnp.uint32(0xFF))
@@ -137,67 +160,21 @@ def _make_scan_kernel_u32(mask_s: int, mask_l: int, S: int, R32: int):
 def _fused_candidate_words_u32(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
                                mask_s: int, mask_l: int,
                                interpret: bool = False):
-    """v2 driver: packed-u32 strip layout (see :func:`_make_scan_kernel_u32`).
+    """v2 driver: v1's ``u8`` strip matrix, read as packed words (see
+    :func:`_make_scan_kernel_u32`).
 
     Same contract as :func:`fused_candidate_words` v1: position-major
     candidate words, bit-identical to the XLA ``_pack_bits`` path.
-    """
-    B, n = ext_b.shape
-    P = n - 31
-    assert P % (128 * 32) == 0, "P must be a multiple of 4096"
-    S = P // _LANES
-    S32 = S // 4
-    R32 = (_DEF_R // 4) if S32 % (_DEF_R // 4) == 0 else S32
-    HR = _HALO_ROWS // 4
-    ext32 = jnp.pad(ext_b, ((0, 0), (1, 0)))
-    # strip-contiguous view, packed 4 bytes/word, then a u32 transpose
-    # (4x fewer elements than v1's u8 transpose).  The pack is four
-    # stride-4 slices of the flat row, not a (…, 4) u8 -> u32 bitcast:
-    # the v5e compiler pads that minor dimension of 4 to 128 lanes, so
-    # the bitcast form took 5-9 GB of temporaries at 8-64 MiB rows and
-    # did not fit the chip at 128 MiB (compile table in CHANGES.md, PR 22)
-    flat = ext32[:, 32:]
-    body_w = functools.reduce(jnp.bitwise_or, (
-        flat[:, k::4].astype(jnp.uint32) << jnp.uint32(8 * k)
-        for k in range(4)))  # (B, P/4) little-endian words
-    body = body_w.reshape(B, _LANES, S32).transpose(0, 2, 1)  # (B,S32,128)
-    head_w = jax.lax.bitcast_convert_type(
-        ext32[:, :32].reshape(B, HR, 4), jnp.uint32)  # (B, HR)
-    halo0 = jnp.concatenate(
-        [head_w[:, :, None], body[:, S32 - HR:, :-1]], axis=2)  # (B,HR,128)
-    nv = nv_b.astype(jnp.int32)
 
-    kernel = _make_scan_kernel_u32(mask_s, mask_l, S, R32)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, S32 // R32),
-        in_specs=[
-            pl.BlockSpec((1, HR, _LANES), lambda b, i, *_: (b, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, R32, _LANES), lambda b, i, *_: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, HR, _LANES),
-                         lambda b, i, *_: (b, jnp.maximum(
-                             i * (R32 // HR) - 1, 0), 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, R32 // 8, _LANES), lambda b, i, *_: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, R32 // 8, _LANES), lambda b, i, *_: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-    )
-    wl, ws = pl.pallas_call(
-        kernel,
-        out_shape=[_words_shape(B, S, body, nv)] * 2,
-        grid_spec=grid_spec,
-        interpret=interpret,
-        name="cdc_scan_fused_v2",
-    )(nv, halo0, body, body)
-    wl = wl.transpose(0, 2, 1).reshape(B, P // 32)
-    ws = ws.transpose(0, 2, 1).reshape(B, P // 32)
-    return wl, ws
+    Packing the words in XLA ahead of the kernel is what not to do on
+    the v5e: a ``(…, 4)`` u8 -> u32 bitcast pads its minor dimension of 4
+    to 128 lanes (5-9 GB of temporaries at 8-64 MiB rows, no fit at
+    128 MiB; PR 22), and four ``flat[:, k::4]`` slices fit but are each
+    lowered as a gather over an index vector, 0.164 s a 64 MiB row each
+    where everything above takes 0.003 s (PR 35).
+    """
+    return _scan_strips(_make_scan_kernel_u32, "cdc_scan_fused_v2", ext_b,
+                        nv_b, mask_s, mask_l, interpret)
 
 
 def _make_scan_kernel(mask_s: int, mask_l: int, S: int, R: int):
@@ -237,52 +214,18 @@ def _make_scan_kernel(mask_s: int, mask_l: int, S: int, R: int):
     return kernel
 
 
-# selected kernel variant; decided ONCE by fused_scan_available()'s
-# parity ladder before any production trace (the dispatcher below reads
-# it at trace time, so flipping it after a trace would go unnoticed —
-# DevicePipeline/callers always probe first)
-_V2_SELECTED = False
-
-
-def fused_candidate_words(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
-                          mask_s: int, mask_l: int):
-    """``(B, 31+P) u8 -> ((B, P/32) u32, (B, P/32) u32)`` candidate words.
-
-    Trace-time dispatcher over the kernel variants: v2 (packed-u32
-    strips, no byte-stream relayout) when the parity ladder selected it
-    on this runtime, else v1.  Both are bit-identical to the XLA path's
-    ``_pack_bits(cand)``; ``P`` must be a multiple of 4096.
-    """
-    # run the ladder if no caller has yet (lru_cached: once per process)
-    # so standalone probes/scripts measure the variant production uses
-    fused_scan_available()
-    if _V2_SELECTED:
-        return _fused_candidate_words_u32(ext_b, nv_b,
-                                          mask_s=mask_s, mask_l=mask_l)
-    return _fused_candidate_words_v1(ext_b, nv_b,
-                                     mask_s=mask_s, mask_l=mask_l)
-
-
-@functools.partial(jax.jit, static_argnames=("mask_s", "mask_l"))
-def _fused_candidate_words_v1(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
-                              mask_s: int, mask_l: int):
-    """v1 driver: u8 strip layout (full-stream byte transpose on the
-    XLA side; see module docstring)."""
+def _scan_strips(make_kernel, name: str, ext_b, nv_b, mask_s: int,
+                 mask_l: int, interpret: bool = False):
+    """One scan kernel over the strip matrix of ``ext_b``: both variants
+    read the same ``u8`` blocks (``R`` strip rows a grid step, the tile
+    ahead's last 32 rows as halo) and write 32 positions a word."""
     B, n = ext_b.shape
     P = n - 31
     assert P % (128 * 32) == 0, "P must be a multiple of 4096"
     S = P // _LANES
     R = _DEF_R if S % _DEF_R == 0 else S  # small buckets: one grid step
-    # strip matrix: strips[b, r, l] = ext32[b, 32 + l*S + r]
-    ext32 = jnp.pad(ext_b, ((0, 0), (1, 0)))
-    body = ext32[:, 32:].reshape(B, _LANES, S).transpose(0, 2, 1)  # (B,S,128)
-    # cross-strip halo: 32 bytes preceding each strip (strip l-1's tail;
-    # strip 0 gets the spec zero byte + the row's 31 halo bytes)
-    halo0 = jnp.concatenate(
-        [ext32[:, :32, None], body[:, S - 32:, :-1]], axis=2)  # (B, 32, 128)
+    body, halo0 = _strip_matrix(ext_b)
     nv = nv_b.astype(jnp.int32)
-
-    kernel = _make_scan_kernel(mask_s, mask_l, S, R)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, S // R),
@@ -306,10 +249,11 @@ def _fused_candidate_words_v1(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
         ],
     )
     wl, ws = pl.pallas_call(
-        kernel,
+        make_kernel(mask_s, mask_l, S, R),
         out_shape=[_words_shape(B, S, body, nv)] * 2,
         grid_spec=grid_spec,
-        name="cdc_scan_fused_v1",
+        interpret=interpret,
+        name=name,
     )(nv, halo0, body, body)
     # strip-major -> position-major: word (w, l) covers positions
     # l*S + w*32 ..+31, so transposing to (l, w) and flattening yields
@@ -317,6 +261,40 @@ def _fused_candidate_words_v1(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
     wl = wl.transpose(0, 2, 1).reshape(B, P // 32)
     ws = ws.transpose(0, 2, 1).reshape(B, P // 32)
     return wl, ws
+
+
+# selected kernel variant; decided ONCE by fused_scan_available()'s
+# parity ladder before any production trace (the dispatcher below reads
+# it at trace time, so flipping it after a trace would go unnoticed —
+# DevicePipeline/callers always probe first)
+_V2_SELECTED = False
+
+
+def fused_candidate_words(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
+                          mask_s: int, mask_l: int):
+    """``(B, 31+P) u8 -> ((B, P/32) u32, (B, P/32) u32)`` candidate words.
+
+    Trace-time dispatcher over the kernel variants: v2 (the strip
+    matrix read four bytes a word) when the parity ladder selected it on
+    this runtime, else v1 (a byte a lane element).  Both are bit-identical to the XLA path's
+    ``_pack_bits(cand)``; ``P`` must be a multiple of 4096.
+    """
+    # run the ladder if no caller has yet (lru_cached: once per process)
+    # so standalone probes/scripts measure the variant production uses
+    fused_scan_available()
+    if _V2_SELECTED:
+        return _fused_candidate_words_u32(ext_b, nv_b,
+                                          mask_s=mask_s, mask_l=mask_l)
+    return _fused_candidate_words_v1(ext_b, nv_b,
+                                     mask_s=mask_s, mask_l=mask_l)
+
+
+@functools.partial(jax.jit, static_argnames=("mask_s", "mask_l"))
+def _fused_candidate_words_v1(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
+                              mask_s: int, mask_l: int):
+    """v1 driver: the strip matrix, expanded a byte a u32 in the kernel."""
+    return _scan_strips(_make_scan_kernel, "cdc_scan_fused_v1", ext_b, nv_b,
+                        mask_s, mask_l)
 
 
 def _check_variant(fn, name: str) -> None:

@@ -13,7 +13,10 @@ it plans, never that it is right or fast.
 What they have caught: every ``pallas_call`` under ``jax.shard_map``
 needs ``vma`` on its ``out_shape``; the scan v2 driver's ``(..., 4)`` u8
 -> u32 bitcast took 5-9 GB of temporaries at 8-64 MiB rows and did not
-fit the chip at 128 MiB.
+fit the chip at 128 MiB.  What they did not catch, because a compile has
+no clock: the four stride-4 slices that replaced that bitcast fit, and
+were four gathers of 0.16 s a 64 MiB row each on the chip (PR 35), so
+the relayout ahead of the scan kernel is now held to planning no gather.
 
 Only one process may load libtpu, and it keeps it until it exits, so
 the topology is described inside a module-scoped fixture — never at
@@ -47,6 +50,8 @@ PARAMS = CDCParams()  # production 256 KiB / 1 MiB / 3 MiB
 # (rows, row bytes): the two ends of the engine's dispatch budget
 WIDE = (1, _SCAN_DISPATCH_BYTES)
 MANY = (_SCAN_DISPATCH_BYTES >> 20, 1 << 20)
+# the bucket `ref-1m.incr` dispatches twice a night: one 48-49 MiB file a row
+CELL = (1, 64 << 20)
 # smallest manifest bucket a production tree reaches: files just above
 # min_size (smaller ones are one chunk and skip the scan), 8 rows
 SMALLEST = (8, 2 * PARAMS.min_size)
@@ -92,19 +97,39 @@ def _temp_bytes(lowered) -> int:
     return lowered.compile().memory_analysis().temp_size_in_bytes
 
 
-@pytest.mark.parametrize("variant", ["v1", "v2"])
-@pytest.mark.parametrize("rows,width", [WIDE, MANY])
-def test_scan_kernel_compiles_at_dispatch_widths(one_chip, variant, rows,
-                                                 width):
+def _lower_scan(variant, rows, width, one_chip):
     fn = {"v1": scan_fused._fused_candidate_words_v1,
           "v2": scan_fused._fused_candidate_words_u32}[variant]
-    lowered = fn.lower(
+    return fn.lower(
         jax.ShapeDtypeStruct((rows, 31 + width), jnp.uint8, sharding=one_chip),
         jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip),
         mask_s=PARAMS.mask_s, mask_l=PARAMS.mask_l)
+
+
+@pytest.mark.parametrize("variant", ["v1", "v2"])
+@pytest.mark.parametrize("rows,width", [WIDE, MANY, CELL],
+                         ids=["wide", "many", "cell"])
+def test_scan_kernel_compiles_at_dispatch_widths(one_chip, variant, rows,
+                                                 width):
     # the XLA-side strip prep must stay a small multiple of the batch
     # (128 MiB): v2's bitcast form planned 9 GB here, then 16.5 GB
-    assert _temp_bytes(lowered) < 1 * GiB
+    assert _temp_bytes(_lower_scan(variant, rows, width, one_chip)) < 1 * GiB
+
+
+@pytest.mark.parametrize("rows,width", [WIDE, MANY, CELL],
+                         ids=["wide", "many", "cell"])
+def test_scan_relayout_plans_no_gather_and_fits(one_chip, rows, width):
+    """The bytes reach the v2 kernel by copies and one transpose: no
+    gather (what a stride-4 slice of a ``u8`` row becomes on the v5e), and
+    arguments, outputs and temporaries together under 4 GiB (a ``(1, N)
+    u8`` argument alone is laid out at four times its bytes)."""
+    compiled = _lower_scan("v2", rows, width, one_chip).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and " gather(" not in hlo
+    mem = compiled.memory_analysis()
+    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes)
+    assert planned < 4 * GiB
 
 
 def test_leaf_digest_kernel_compiles_at_dispatch_width(one_chip):
