@@ -52,6 +52,7 @@ from .erasure import gf_cpu
 from .erasure import stripe as rs_stripe
 from .net.client import NoBackups, ServerClient, ServerError
 from .net.p2p import (
+    DialUnconfirmed,
     P2PError,
     P2PNode,
     PartialStore,
@@ -188,6 +189,13 @@ class Orchestrator:
         # of polling on a backoff timer
         self._packfile_event = asyncio.Event()
         self.active_transports: Dict[bytes, Transport] = {}
+        # stripe dials in flight, one a peer: a sibling tick that wants
+        # the same peer awaits that attempt and shares its transport
+        self.dials: Dict[bytes, "asyncio.Future[Transport]"] = {}
+        # dials in a row a peer left unconfirmed in this backup; up to
+        # the dial policy's retries such a peer is waited for, not
+        # counted as gone (Engine._get_stripe_connections)
+        self.unconfirmed_dials: Dict[bytes, int] = {}
 
     def notify_packfile(self) -> None:
         """Event-loop side of the seal wakeup: a packfile committed (or
@@ -1526,6 +1534,7 @@ class Engine:
             await self._blocking(path.unlink)
             orch.bytes_sent += size
             orch.adjust_buffer(-size)
+            obs_profile.send_packfile("whole")
             self._progress(bytes_transmitted=orch.bytes_sent)
         return job
 
@@ -1556,8 +1565,11 @@ class Engine:
         the local file only once all k+m shards are acked.  Returns
         (files for the legacy whole-file path, bytes fully placed).  A
         packfile that already has a whole-file placement, or that cannot
-        reach enough distinct peers this tick, is handed back for the
-        legacy path — never stranded.
+        reach enough distinct peers, is handed back for the legacy path —
+        never stranded.  Where enough peers are there and one only left
+        its dial unconfirmed, the packfile is in neither: it stays on
+        disk for the next tick (``deferred``), so a slow rendezvous costs
+        a wait and never the stripe.
         """
         geom = self._stripe_geometry()
         if geom is None:
@@ -1566,6 +1578,9 @@ class Engine:
         n = k + m
         leftover = []
         placed_bytes = 0
+        # peers that left a dial unconfirmed in this tick: not dialled
+        # again for its later packfiles, which wait with the first
+        unanswered: set = set()
         for pid, path, size in unsent:
             holders: Dict[int, bytes] = {}
             whole_placed = False
@@ -1587,53 +1602,77 @@ class Engine:
             exclude = set(holders.values()) | self._avoid_peers
             with obs_trace.span("send.dial"):
                 conns = await self._get_stripe_connections(
-                    orch, len(missing), exclude, shard_size)
+                    orch, len(missing), exclude, shard_size, unanswered)
             if len(conns) < len(missing):
-                leftover.append((pid, path, size))
+                waiting = unanswered - exclude - {c[1] for c in conns}
+                if len(conns) + len(waiting) >= len(missing):
+                    obs_profile.send_packfile("deferred")
+                    self._log(f"packfile {bytes(pid).hex()[:8]} waits for"
+                              f" {len(waiting)} unconfirmed dial(s)")
+                else:
+                    leftover.append((pid, path, size))
                 continue
-            try:
-                data = await self._blocking(path.read_bytes)
-            except OSError as e:
-                # never swallow the read failure: report it and hand the
-                # packfile back so the next tick retries instead of the
-                # stripe silently vanishing from this run
-                self._log(f"packfile {bytes(pid).hex()[:8]} read failed:"
-                          f" {e}; queued for retry")
+            with obs_trace.span("send.stripe"):
+                placed = await self._send_stripe(
+                    orch, sched, (pid, path, size), holders,
+                    list(zip(missing, conns)), k, m)
+            if placed:
+                placed_bytes += size
+            else:
+                # read failed or partial stripe: handed back (placed
+                # shards skip when it is striped again)
                 leftover.append((pid, path, size))
-                continue
-            # GF(2^8) matmul (device or numpy oracle) and the shards'
-            # audit tables: off the event loop, in one executor call
-            containers = await self._blocking(
-                self._encode_stripe, obs_trace.current_trace_id(), pid,
-                data, k, m, missing)
-            pairs = list(zip(missing, conns))
+        return leftover, placed_bytes
+
+    async def _send_stripe(self, orch: Orchestrator,
+                           sched: TransferScheduler, packfile: tuple,
+                           holders: Dict[int, bytes], pairs: list,
+                           k: int, m: int) -> bool:
+        """One packfile's stripe, from its read to ``_finish_stripe``:
+        code it, send the shard of each ``(index, connection)`` pair to
+        its peer, all in flight together.  True once all k+m are acked
+        (``holders`` gains each acked index)."""
+        pid, path, size = packfile
+        try:
+            data = await self._blocking(path.read_bytes)
+        except OSError as e:
+            # never swallow the read failure: report it, and the caller
+            # hands the packfile back instead of the stripe silently
+            # vanishing from this run
+            self._log(f"packfile {bytes(pid).hex()[:8]} read failed:"
+                      f" {e}; queued for retry")
+            return False
+        # GF(2^8) matmul (device or numpy oracle) and the shards'
+        # audit tables: off the event loop, in one executor call
+        containers = await self._blocking(
+            self._encode_stripe, obs_trace.current_trace_id(), pid,
+            data, k, m, [i for i, _conn in pairs])
+        with obs_trace.span("send.wire"):
             tasks = [
                 sched.submit(peer_id, len(containers[i]),
                              self._shard_job(orch, transport, peer_id, pid,
                                              i, containers[i]),
                              label=f"shard:{bytes(pid).hex()[:8]}:{i}")
                 for i, (transport, peer_id, _free) in pairs]
-            all_acked = True
-            for ((i, (_t, peer_id, _f)), r) in zip(
-                    pairs, await sched.gather(tasks)):
-                if r.ok:
-                    holders[i] = bytes(peer_id)
-                else:
-                    # this shard's failure stays its own: the siblings
-                    # already completed to THEIR peers
-                    all_acked = False
-                    if isinstance(r.error, P2PError):
-                        await self._drop_transport(orch, peer_id)
-            if all_acked and len(holders) == n:
-                await self._finish_stripe(orch, pid, path, size)
-                placed_bytes += size
-                if self.messenger is not None:
-                    self.messenger.erasure(bytes(pid).hex(), "placed",
-                                           shards=n, rebuilt=0)
+            results = await sched.gather(tasks)
+        all_acked = True
+        for ((i, (_t, peer_id, _f)), r) in zip(pairs, results):
+            if r.ok:
+                holders[i] = bytes(peer_id)
             else:
-                # partial stripe: retried next tick (placed shards skip)
-                leftover.append((pid, path, size))
-        return leftover, placed_bytes
+                # this shard's failure stays its own: the siblings
+                # already completed to THEIR peers
+                all_acked = False
+                if isinstance(r.error, P2PError):
+                    await self._drop_transport(orch, peer_id)
+        if not (all_acked and len(holders) == k + m):
+            return False
+        await self._finish_stripe(orch, pid, path, size)
+        obs_profile.send_packfile("striped")
+        if self.messenger is not None:
+            self.messenger.erasure(bytes(pid).hex(), "placed",
+                                   shards=k + m, rebuilt=0)
+        return True
 
     def _shard_job(self, orch: Orchestrator, transport, peer_id: bytes,
                    pid: bytes, index: int, container: bytes):
@@ -1713,11 +1752,21 @@ class Engine:
                       f" failed: {e}")
 
     async def _get_stripe_connections(self, orch: Orchestrator, need: int,
-                                      exclude: set, min_free: int) -> list:
+                                      exclude: set, min_free: int,
+                                      unanswered: Optional[set] = None
+                                      ) -> list:
         """Up to ``need`` transports to DISTINCT peers outside ``exclude``,
         each with ``min_free`` bytes of allowance: reuse actives first,
         then dial known peers in measured-capacity order (the same
-        ordering ``find_peers_with_storage`` gives the legacy path)."""
+        ordering ``find_peers_with_storage`` gives the legacy path).
+
+        ``unanswered`` (a stripe's caller passes it) collects the peers
+        that took a rendezvous and did not confirm it in time, while the
+        dial policy's retries last in this backup: such a peer is busy,
+        or this loop was held, and the caller waits for it.  A peer in
+        the set is not dialled again.  A dial refused, a peer the server
+        cannot reach, or retries used up leave the peer out of the set:
+        it is gone for this backup's stripes."""
         conns = []
         chosen = set()
         # capacity demotion applies to active transports too: an open
@@ -1743,23 +1792,52 @@ class Engine:
                 if peer.free_storage < min_free:
                     continue  # capacity-ordered now, so keep scanning:
                     # a later (slower) peer may still have the space
-                # a transport that is there now was opened by a sibling
-                # tick since the reuse pass (a backup's first packfiles
-                # come in a burst, each tick dialling): take it, or this
-                # stripe comes up one peer short and goes out whole
-                t = orch.active_transports.get(key)
-                if t is None:
-                    try:
-                        t = await self.node.connect(
-                            key, wire.RequestType.TRANSPORT, timeout=3.0)
-                    except (P2PError, ServerError, OSError,
-                            asyncio.TimeoutError) as e:
-                        self._log(f"dial {key.hex()[:8]} failed: {e}")
-                        continue
-                    orch.active_transports[key] = t
+                if unanswered is not None and key in unanswered:
+                    continue
+                try:
+                    t = await self._dial_shared(orch, key)
+                except (P2PError, ServerError, OSError,
+                        asyncio.TimeoutError) as e:
+                    self._log(f"dial {key.hex()[:8]} failed: {e}")
+                    if (isinstance(e, DialUnconfirmed)
+                            and unanswered is not None
+                            and orch.unconfirmed_dials[key]
+                            <= retry.DIAL.max_attempts):
+                        unanswered.add(key)
+                    continue
                 conns.append((t, key, peer.free_storage))
                 chosen.add(key)
         return conns
+
+    async def _dial_shared(self, orch: Orchestrator, key: bytes):
+        """The open transport to ``key``, or one dial of it that every
+        tick wanting the peer meanwhile awaits: a backup's first
+        packfiles come in a burst, each tick dialling, and a second
+        rendezvous behind the first would open a second socket and read
+        one slow confirmation as two."""
+        t = orch.active_transports.get(key)
+        if t is not None:
+            return t
+        dial = orch.dials.get(key)
+        if dial is None:
+            async def connect():
+                try:
+                    t = await self.node.connect(
+                        key, wire.RequestType.TRANSPORT, timeout=3.0)
+                except DialUnconfirmed:
+                    orch.unconfirmed_dials[key] = \
+                        orch.unconfirmed_dials.get(key, 0) + 1
+                    raise
+                finally:
+                    del orch.dials[key]
+                orch.unconfirmed_dials.pop(key, None)
+                orch.active_transports[key] = t
+                return t
+            dial = orch.dials[key] = asyncio.ensure_future(connect())
+            # a tick cancelled at teardown leaves the dial to end alone
+            dial.add_done_callback(
+                lambda d: d.cancelled() or d.exception())
+        return await asyncio.shield(dial)
 
     async def _send_index_files(self, orch, estimate, fulfilled) -> None:
         request_timer = retry.RetryTimer(retry.STORAGE_REQUEST)

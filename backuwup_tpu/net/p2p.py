@@ -98,6 +98,12 @@ class P2PError(Exception):
     pass
 
 
+class DialUnconfirmed(P2PError):
+    """The peer took the rendezvous request (the server delivered it)
+    and did not confirm in time: busy, or this loop was held, not
+    gone.  A peer the server cannot reach fails the request at once."""
+
+
 def obfuscate(data: bytes, key: bytes) -> bytes:
     """XOR with a repeating 4-byte key (net_p2p/mod.rs:38-47); involutive."""
     if len(key) != 4:
@@ -909,7 +915,8 @@ class P2PNode:
             except BaseException as e:
                 self.requests.discard(peer_id)
                 if isinstance(e, asyncio.TimeoutError):
-                    raise P2PError("peer did not confirm p2p connection")
+                    raise DialUnconfirmed(
+                        "peer did not confirm p2p connection")
                 raise
             nonce, purpose = self.requests.finalize(peer_id)
 
@@ -948,8 +955,10 @@ class P2PNode:
     async def _handle_incoming(self, msg: wire.IncomingP2PConnection) -> None:
         source = bytes(msg.source_client_id)
         plane = faults.PLANE
-        if plane is not None and plane.is_dead(self.keys.client_id):
-            return  # injected death: a dead host answers no rendezvous
+        if plane is not None and (
+                plane.is_dead(self.keys.client_id)
+                or plane.rendezvous_unanswered(self.keys.client_id)):
+            return  # injected: dead, or too busy to answer this one
         if self.store.get_peer(source) is None:
             return  # unknown peer: refuse (handle_connections.rs:31-45)
         expected_nonce = msg.session_nonce

@@ -242,6 +242,16 @@ _SEND_DISPATCHES = _metrics.counter(
     "Blocking device round trips of the send stage (upload, programs, "
     "download awaited)")
 _send_thread = threading.local()
+# How each packfile the send stage took up left it: ``striped`` (k+m
+# shards acked by k+m holders), ``whole`` (one copy on one holder: the
+# swarm cannot carry a stripe), ``deferred`` (k+m holders are there but
+# one did not answer its dial in this tick: handed back for the next).
+SEND_OUTCOMES = {"striped": "stripes", "whole": "whole",
+                 "deferred": "deferred"}  # outcome -> report["send"] key
+_SEND_PACKFILES = _metrics.counter(
+    "bkw_send_packfiles_total",
+    "Packfiles by how the send stage's tick left them",
+    labelnames=("outcome",))
 # What left through the P2P sockets meanwhile, from the transport's own
 # counters (``net/p2p.py`` ``Transport._ship``; read by name, 0 where no
 # transport was ever imported): every signed frame byte, and those
@@ -253,7 +263,8 @@ SEND_WIRE_COUNTERS = {"wire_bytes": "bkw_p2p_bytes_sent_total",
 # per-stage wall time: the batched route's dispatch/collect pairs, the
 # packer entry point that drives them and the batch's own parts, the
 # streamed file and its parts, the index classify, and the send stage's
-# steps.
+# steps (``send.stripe`` holds ``send.rs_encode``, ``send.challenge_tables``
+# and ``send.wire`` of one packfile).
 REPORT_SPANS = (
     "pipeline.scan_select_dispatch",
     "pipeline.cut_collect",
@@ -270,6 +281,8 @@ REPORT_SPANS = (
     "send.dial",
     "send.rs_encode",
     "send.challenge_tables",
+    "send.stripe",
+    "send.wire",
 )
 
 # Streaming-dataflow overlap families (the engine's stage graph,
@@ -424,6 +437,13 @@ def send_stage(packfile_bytes: int) -> Iterator[None]:
         _send_thread.depth -= 1
 
 
+def send_packfile(outcome: str) -> None:
+    """One packfile left a send tick as ``outcome``."""
+    if outcome not in SEND_OUTCOMES:
+        raise ValueError(f"unknown send outcome {outcome!r}")
+    _SEND_PACKFILES.inc(outcome=outcome)
+
+
 def device_upload(n: int) -> None:
     """``n`` bytes staged on the device by the digest and erasure seams
     the send stage codes through; counted where :func:`send_stage` is
@@ -494,6 +514,8 @@ def baseline() -> Dict[str, Dict[str, float]]:
     out["send"] = {f"{k}_bytes": _SEND_BYTES.value(kind=k)
                    for k in SEND_BYTE_KINDS}
     out["send"]["dispatches"] = _SEND_DISPATCHES.value()
+    for outcome, key in SEND_OUTCOMES.items():
+        out["send"][key] = _SEND_PACKFILES.value(outcome=outcome)
     for key, name in SEND_WIRE_COUNTERS.items():
         fam = _metrics.registry().get(name)
         out["send"][key] = fam.value() if fam is not None else 0.0

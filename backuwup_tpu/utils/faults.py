@@ -23,6 +23,9 @@ Transport/Node seam in :mod:`backuwup_tpu.net.p2p` start injecting
   protocol (docs/transfer.md) must continue from the persisted offset.
 * **flaky reconnect** — ``reconnect_fail`` makes a fraction of p2p dials
   fail outright, the residential-NAT reconnect lottery.
+* **unanswered rendezvous** — an armed ``dial.unanswered:<peer hex>``
+  query makes a live peer miss one rendezvous: the dialer's confirm
+  window runs out (``DialUnconfirmed``), its next dial is answered.
 * **crash points** — named :func:`crashpoint` sites at every multi-step
   commit seam (pack-seal, blob-index save, challenge-table save,
   placement insert, stripe finish, repair re-home, partial sink).  When
@@ -290,6 +293,13 @@ class FaultPlane:
         refused, as a flaky residential peer would."""
         return self.decide(f"dial.flaky:{bytes(peer_id).hex()}",
                            self.reconnect_fail)
+
+    def rendezvous_unanswered(self, peer_id: bytes) -> bool:
+        """Called by P2PNode._handle_incoming of ``peer_id``: True = this
+        rendezvous goes unanswered (a live peer too busy to confirm in
+        the dialer's window; the dialer's next request is answered).
+        Armed only: ``arm("dial.unanswered:<hex>", n)``."""
+        return self.decide(f"dial.unanswered:{bytes(peer_id).hex()}", 0.0)
 
     def corrupt(self, raw: bytes, peer_id: bytes) -> bytes:
         """Flip one deterministically chosen byte of the signed frame."""

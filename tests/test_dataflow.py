@@ -122,6 +122,21 @@ def test_backpressure_bounds_buffer_and_drains(tmp_path, loop, monkeypatch):
     def pause_seen(self):
         pauses.append(self.buffer_bytes)
         pause(self)
+        paused.set()  # on the event loop: the send loop pauses
+
+    paused = asyncio.Event()
+
+    class HeldWire(faults.FaultPlane):
+        """No FILE frame leaves before the packer has been paused once:
+        beside busy cores the packer (pure Python here) seals a packfile
+        slower than the slow wire sends one, the buffer never holds more
+        than the cap when the send loop looks, and whether the cap is
+        tested at all would be the machine's load to decide."""
+
+        async def on_send(self, peer_id):
+            with contextlib.suppress(asyncio.TimeoutError):
+                await asyncio.wait_for(paused.wait(), 60)
+            return await super().on_send(peer_id)
 
     monkeypatch.setattr(engine_mod.Orchestrator, "adjust_buffer",
                         adjust_seen)
@@ -130,8 +145,7 @@ def test_backpressure_bounds_buffer_and_drains(tmp_path, loop, monkeypatch):
     async def run():
         # ONE holder and a genuinely slow wire: the single send lane
         # must fall far behind the packer or the cap is never tested
-        faults.install(faults.FaultPlane(seed=31, latency=1.0,
-                                         latency_s=0.08))
+        faults.install(HeldWire(seed=31, latency=1.0, latency_s=0.08))
         try:
             async with _universe(tmp_path, src, "bp", peers=1) as a:
                 snap = await asyncio.wait_for(a.backup(), 120)

@@ -240,7 +240,8 @@ def test_resident_stripe_uploads_a_packfile_once_in_two_dispatches(
     engine.backend.digest_many([data[:70000]])
     assert obs_profile.report(base)["send"] == {
         "uploaded_bytes": 0, "packfile_bytes": 0, "dispatches": 0,
-        "wire_bytes": 0, "deflated_bytes": 0}
+        "wire_bytes": 0, "deflated_bytes": 0,
+        "stripes": 0, "whole": 0, "deferred": 0}
     store.close()
 
 

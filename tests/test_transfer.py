@@ -107,7 +107,7 @@ def test_stripe_wall_clock_bounded_by_slowest_shard(engine, plane):
     peers = [bytes([i + 1]) * 32 for i in range(6)]
     conns = [(FaultedTransport(p), p, 1 << 30) for p in peers]
 
-    async def fake_conns(orch, need, exclude, min_free):
+    async def fake_conns(orch, need, exclude, min_free, unanswered=None):
         return conns[:need]
 
     engine._get_stripe_connections = fake_conns
@@ -138,7 +138,7 @@ def test_midflight_peer_death_fails_only_that_shard(engine, plane):
     plane.kill_after(dead, 0)  # the very next send finds the peer dead
     conns = [(FaultedTransport(p), p, 1 << 30) for p in peers]
 
-    async def fake_conns(orch, need, exclude, min_free):
+    async def fake_conns(orch, need, exclude, min_free, unanswered=None):
         # mirror P2PNode.connect: dead peers accept no dial
         return [c for c in conns
                 if c[1] not in exclude and not faults.PLANE.is_dead(c[1])
@@ -186,7 +186,7 @@ def test_stripe_read_failure_requeues_for_retry(engine, plane):
     peers = [bytes([i + 0x30]) * 32 for i in range(6)]
     conns = [(FaultedTransport(p), p, 1 << 30) for p in peers]
 
-    async def fake_conns(orch, need, exclude, min_free):
+    async def fake_conns(orch, need, exclude, min_free, unanswered=None):
         return conns[:need]
 
     engine._get_stripe_connections = fake_conns
@@ -209,7 +209,7 @@ def test_whole_files_fan_out_across_peers(engine, monkeypatch):
                             min_free=1):
         return ta, peer_a, 10_000
 
-    async def fake_conns(orch, need, exclude, min_free):
+    async def fake_conns(orch, need, exclude, min_free, unanswered=None):
         assert peer_a in exclude  # the first peer is never doubled up
         return [(tb, peer_b, 10_000)]
 
