@@ -3,8 +3,9 @@
 Layers:
 
 * :mod:`.gf_cpu` — pure-numpy GF(2^8) Reed-Solomon oracle (ground truth).
-* :mod:`.rs_tpu` — batched device kernel (table-lookup multiply +
-  XOR-accumulate under ``jit(vmap)``), bit-exact against the oracle.
+* :mod:`.rs_tpu` — batched device kernel (the matrix as 8 x 8 bit
+  blocks times the data's bit planes, an integer matmul mod 2 under
+  ``jit``; no table indexed by data), bit-exact against the oracle.
 * :mod:`.stripe` — self-describing shard containers, split/assemble/
   rebuild, and the restore-side stripe assembly tree walk.
 

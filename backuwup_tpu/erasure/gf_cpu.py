@@ -37,7 +37,9 @@ for _i in range(255):
 GF_EXP[255:510] = GF_EXP[0:255]
 
 # Full product table: MUL_TABLE[a, b] = a * b in GF(2^8).  64 KiB; serves
-# the vectorized CPU row-ops below AND the device gather in rs_tpu.py.
+# the vectorized CPU row-ops below, and rs_tpu.bit_matrix takes each
+# coefficient's products with the eight powers of two from it (the device
+# indexes no table by data).
 _a = np.arange(256)
 MUL_TABLE = np.zeros((256, 256), dtype=np.uint8)
 _nz = _a[1:]

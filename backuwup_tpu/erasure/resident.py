@@ -9,7 +9,7 @@ bytes out, in two waits for the device where that one makes eight.
 Dispatch one (awaited by the constructor, inside ``send.rs_encode``):
 the packfile goes up once, zero-padded into the ``(k, Lb)`` matrix of
 its shard-length bucket; the RS product runs on it (:mod:`.rs_tpu`'s
-program, unchanged); data and parity rows are stacked into one resident
+program); data and parity rows are stacked into one resident
 ``(rows, Lb)`` array and digested in place by their true length; the
 parity rows and k + m digests come down.  The containers are packed on
 the host: header, then a view of the packfile (data shards) or of the
@@ -29,9 +29,11 @@ uses for a table; one accumulator of 32 bytes a window comes down.  A
 night's packfiles leave in one burst, each on a thread of its own, and
 the device runs programs in the order they were launched: a stripe
 whose tables were launched only after its parity came down would wait
-behind every other stripe's 75 ms RS program (0.3 s for the first
-stripe of a burst of four, PERF.md section 6, PR 29), and the first
-shard on the wire with it.
+behind every other stripe's dispatch one, and the first shard on the
+wire with it (0.3 s for the first stripe of a burst of four when the RS
+program took 75-97 ms, PERF.md section 6, PR 29; since PR 37 the product
+is 0.08 ms a 3 MiB packfile on the v5e and a stripe's two dispatches
+are ~15 ms of ``digest_padded``, PERF.md section 5).
 
 Which programs can ever run is a function of ``(k, m)`` and the
 shard-length bucket alone.  The buckets are the digest's leaf classes
@@ -136,9 +138,8 @@ def _put_digests(acc: jnp.ndarray, root: jnp.ndarray,
 
 
 @functools.lru_cache(maxsize=None)
-def _parity_matrix(k: int, m: int) -> np.ndarray:
-    return np.ascontiguousarray(gf_cpu.generator_matrix(k, m)[k:],
-                                dtype=np.uint8)
+def _parity_bits(k: int, m: int) -> np.ndarray:
+    return rs_tpu.bit_matrix(gf_cpu.generator_matrix(k, m)[k:])
 
 
 class ResidentStripe:
@@ -159,12 +160,13 @@ class ResidentStripe:
         for i in range(k):
             part = flat[i * sl:(i + 1) * sl]
             host[0, i, :part.size] = part
-        mat = _parity_matrix(k, m)
+        bits = _parity_bits(k, m)
         lens = np.zeros(rows, dtype=np.int32)
         lens[:n] = sl
         stripe = jnp.asarray(host)
-        obs_profile.device_upload(host.nbytes + mat.nbytes + lens.nbytes)
-        parity = rs_tpu._matmul_batched()(jnp.asarray(mat), stripe)
+        obs_profile.device_upload(
+            host.nbytes + bits.nbytes + lens.nbytes)
+        parity = rs_tpu._matmul_batched()(jnp.asarray(bits), stripe)
         payloads = _shard_rows(stripe, parity, rows=rows)
         root = digest_padded(payloads, jnp.asarray(lens),
                              L=bucket // CHUNK_LEN)

@@ -1,11 +1,16 @@
 """Batched device Reed-Solomon encode/decode.
 
-The GF(2^8) generator-matrix product is expressed as a table-lookup
-multiply plus XOR-accumulate: gather ``MUL_TABLE[mat[i, j], shard[j, l]]``
-and reduce over ``j`` with ``lax.bitwise_xor``.  Following the
-``blake3_tpu`` idiom, the kernel is plain jnp/lax under
-``jit(vmap(...))`` over shard stripes — no per-byte host work — and must
-be bit-exact against the :mod:`.gf_cpu` oracle (tests pin the parity).
+Multiplication by a constant in GF(2^8) is linear over GF(2):
+``c * x = XOR_b (bit_b(x) ? c * 2^b : 0)``, an 8 x 8 bit matrix applied
+to the bits of ``x``.  So the generator-matrix product needs no table
+indexed by data: the host writes the matrix's coefficients out as one
+``(8r, 8j)`` 0/1 matrix (:func:`bit_matrix`, from the products of each
+coefficient with the eight powers of two in the :mod:`.gf_cpu` oracle's
+table, which is where the field's polynomial is stated), the device
+multiplies it with the shard bytes' bit planes as an integer matmul and
+keeps the sums' parity.  The kernel is plain jnp/lax under ``jit`` over
+a batch of shard stripes (no per-byte host work, no gather) and must be
+bit-exact against the :mod:`.gf_cpu` oracle (tests pin the parity).
 
 The k x k recovery-matrix inversion stays on the host (:func:`gf_cpu.
 decode_matrix`): it is an O(k^3) operation on a <= 32-wide matrix, far
@@ -25,25 +30,43 @@ from ..obs import profile as obs_profile
 from . import gf_cpu
 
 
+_BITS = np.arange(8, dtype=np.uint8)
+_POW2_PRODUCTS = gf_cpu.MUL_TABLE[:, 1 << _BITS]  # [c, b] = c * 2^b
+
+
+def bit_matrix(mat: np.ndarray) -> np.ndarray:
+    """``(r, j)`` GF(2^8) matrix -> the ``(8r, 8j)`` int8 0/1 matrix that
+    does the same product on bits: row ``8r + i``, column ``8j + b`` is
+    bit ``i`` of ``mat[r, j] * 2^b`` (out of the oracle's table)."""
+    prods = _POW2_PRODUCTS[np.asarray(mat, dtype=np.uint8)]    # (r, j, b)
+    r, j, _ = prods.shape
+    rows = (prods[:, None, :, :] >> _BITS[None, :, None, None]) & 1
+    return np.ascontiguousarray(rows.reshape(8 * r, 8 * j), dtype=np.int8)
+
+
 @functools.lru_cache(maxsize=None)
 def _matmul_batched():
-    """jit(vmap) GF(2^8) matmul: (mat (r, j), stripes (B, j, L)) -> (B, r, L).
+    """jit GF(2^8) matmul: (bits (8r, 8j), stripes (B, j, L)) ->
+    (B, r, L), ``bits`` the matrix's :func:`bit_matrix`.
 
-    The multiplication table is closed over as a device constant; jit
-    caches per (r, j, B, L) shape bucket.
+    The stripe's bit planes are an ``(8j, L)`` 0/1 matrix; bit ``i`` of
+    ``out[r, l]`` is the parity of row ``8r + i`` of ``bits`` times
+    column ``l`` of it: an int8 matmul the MXU does, exact in int32 (a
+    sum is at most ``8j``).  Of the forms probed on the v5e the fastest
+    at a sealed packfile's ``(1, 4, 1 MiB)``, 0.08 ms (PERF.md section
+    5, PR 37).  jit caches per (r, j, B, L) shape bucket.
     """
-    table = jnp.asarray(gf_cpu.MUL_TABLE)
-
-    def one(mat, stripe):
-        with jax.named_scope("rs_gather_xor"):
-            prods = table[mat.astype(jnp.int32)[:, :, None],
-                          stripe.astype(jnp.int32)[None, :, :]]
-            return jax.lax.reduce(prods, np.uint8(0), jax.lax.bitwise_xor,
-                                  (1,))
-
     # the jitted function's name is the program's name in a device trace
-    def rs_gf_matmul(mat, stripes):
-        return jax.vmap(one, in_axes=(None, 0))(mat, stripes)
+    def rs_gf_matmul(bits, stripes):
+        with jax.named_scope("rs_bitplane_matmul"):
+            b, j, ln = stripes.shape
+            planes = ((stripes[:, :, None, :] >> _BITS[None, None, :, None])
+                      & np.uint8(1)).reshape(b, 8 * j, ln)
+            sums = jnp.einsum("pq,bql->bpl", bits, planes.astype(jnp.int8),
+                              preferred_element_type=jnp.int32)
+            out = (sums & 1).reshape(b, -1, 8, ln) << _BITS.astype(
+                np.int32)[None, None, :, None]
+            return jnp.sum(out, axis=2).astype(jnp.uint8)
 
     return jax.jit(rs_gf_matmul)
 
@@ -59,14 +82,14 @@ def _length_bucket(n: int) -> int:
 
 def gf_matmul_stripes(mat: np.ndarray, stripes: np.ndarray) -> np.ndarray:
     """Device GF(2^8) matmul over a batch of stripes; returns host uint8."""
-    mat = np.asarray(mat, dtype=np.uint8)
     stripes = np.asarray(stripes, dtype=np.uint8)
     ln = stripes.shape[2]
     pad = _length_bucket(ln) - ln
     if pad:
         stripes = np.pad(stripes, ((0, 0), (0, 0), (0, pad)))
-    obs_profile.device_upload(mat.nbytes + stripes.nbytes)
-    out = _matmul_batched()(jnp.asarray(mat), jnp.asarray(stripes))
+    bits = bit_matrix(mat)
+    obs_profile.device_upload(bits.nbytes + stripes.nbytes)
+    out = _matmul_batched()(jnp.asarray(bits), jnp.asarray(stripes))
     obs_profile.device_wait()
     return np.asarray(jax.device_get(out), dtype=np.uint8)[:, :, :ln]
 

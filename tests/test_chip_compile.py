@@ -17,6 +17,10 @@ fit the chip at 128 MiB.  What they did not catch, because a compile has
 no clock: the four stride-4 slices that replaced that bitcast fit, and
 were four gathers of 0.16 s a 64 MiB row each on the chip (PR 35), so
 the relayout ahead of the scan kernel is now held to planning no gather.
+Nor the RS product: ``MUL_TABLE[mat, shard]`` compiled and fitted for
+seven PRs and was a gather of 82-97 ms a 3 MiB packfile on the chip, the
+largest program of two cells' traces (PR 37), so ``rs_gf_matmul`` is held
+to planning none either.
 
 Only one process may load libtpu, and it keeps it until it exits, so
 the topology is described inside a module-scoped fixture — never at
@@ -270,3 +274,29 @@ def test_resident_stripe_route_compiles_at_a_sealed_packfiles_bucket(one_chip):
         shape(((k + m) * count, 8), jnp.uint32),
         shape((count, 8), jnp.uint32), i32)
     assert put.compile().memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("bucket", [1 << 20, 3 << 20], ids=["1MiB", "3MiB"])
+@pytest.mark.parametrize("r", [2, 4], ids=["parity-2x4", "recovery-4x4"])
+def test_rs_product_plans_no_gather_and_fits(one_chip, r, bucket):
+    """``rs_gf_matmul`` at RS 4+2 as the send stage launches it (the
+    parity block, ``(1, 4, Lb)``) and as a restore does (a ``(4, 4)``
+    recovery matrix), at a sealed packfile's bucket and at the largest,
+    which is no power of two: the matrix's bit blocks times the data's
+    bit planes, so no operand is indexed by data, and neither the planes
+    nor the ``int32`` sums reach HBM (read from the compile, PR 37: 0
+    bytes of temporaries at every one of the four; a burst has several
+    stripes in flight, so the 3 MiB bucket may never plan 256 MiB)."""
+    from backuwup_tpu.erasure import rs_tpu
+
+    compiled = rs_tpu._matmul_batched().lower(
+        jax.ShapeDtypeStruct((8 * r, 32), jnp.int8, sharding=one_chip),
+        jax.ShapeDtypeStruct((1, 4, bucket), jnp.uint8, sharding=one_chip)
+    ).compile()
+    assert " gather(" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 << 20
+    # a (1, 4, Lb) u8 argument and a (1, r <= 4, Lb) result, four rows a
+    # word: nothing padded, nothing copied beside them
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            < 8 * bucket + (1 << 20))

@@ -261,7 +261,7 @@ def test_backend_encode_decode_matches_oracle(name, nprng):
 
 
 def test_device_kernel_matches_oracle_on_host(nprng):
-    # rs_tpu's jit(vmap) table-gather kernel runs on whatever platform jax
+    # rs_tpu's jitted bit-matrix kernel runs on whatever platform jax
     # is pinned to — under the tier-1 cpu pin this IS the parity check the
     # subsystem's ground truth demands (bit-for-bit vs the numpy oracle)
     from backuwup_tpu.erasure import rs_tpu
@@ -295,6 +295,60 @@ def test_device_kernel_matches_oracle_on_accelerator(nprng):
     dec = np.asarray(rs_tpu.decode_stripes(
         full[:, present, :], k, m, present))
     assert np.array_equal(dec, stripes)
+
+
+def _all_products():
+    """Every coefficient against every byte value: the 65,536 products
+    of the field, one by one."""
+    every = np.arange(256, dtype=np.uint8)
+    return every.reshape(256, 1), every.reshape(1, 1, 256)
+
+
+def _random_product(r, j, batch, length):
+    rng = np.random.default_rng([r, j, batch, length])
+    return (rng.integers(0, 256, (r, j), dtype=np.uint8),
+            rng.integers(0, 256, (batch, j, length), dtype=np.uint8))
+
+
+_PRODUCT_CASES = [pytest.param(_all_products, id="all-65536-products")] + [
+    pytest.param(lambda r=r, j=j, b=b, n=n: _random_product(r, j, b, n),
+                 id=f"{r}x{j}-batch{b}-len{n}")
+    for r, j in [(2, 4), (4, 4), (1, 1), (3, 5), (6, 10)]
+    for b in (1, 3) for n in (1, 255, 4096, 4097)]
+
+
+@pytest.mark.parametrize("case", _PRODUCT_CASES)
+def test_device_product_matches_oracle(case):
+    """The device program multiplies bit matrices, the oracle looks
+    its table up: any matrix (a parity block, a recovery matrix, any
+    coefficients), any batch, lengths on and off ``gf_matmul_stripes``'s
+    power-of-two buckets, the same bytes."""
+    from backuwup_tpu.erasure import rs_tpu
+
+    mat, stripes = case()
+    out = rs_tpu.gf_matmul_stripes(mat, stripes)
+    assert out.dtype == np.uint8
+    expect = np.stack([gf_cpu.gf_matmul(mat, s) for s in stripes])
+    assert np.array_equal(out, expect)
+
+
+def test_device_product_at_the_bucket_that_is_no_power_of_two(nprng):
+    """``_matmul_batched()`` as ``ResidentStripe`` calls it, at RS 4+2
+    and the 3 MiB bucket (``defaults.BLAKE3_LEAF_BUCKETS``' last): the
+    one shard length that ``gf_matmul_stripes``'s padding never makes."""
+    import jax.numpy as jnp
+
+    from backuwup_tpu.erasure import resident, rs_tpu
+
+    k, m = defaults.RS_K, defaults.RS_M
+    bucket = resident.shard_bucket(3 << 20)
+    assert bucket == 3 << 20 and bucket & (bucket - 1)
+    stripe = nprng.integers(0, 256, (1, k, bucket), dtype=np.uint8)
+    parity = rs_tpu._matmul_batched()(
+        jnp.asarray(resident._parity_bits(k, m)), jnp.asarray(stripe))
+    assert parity.shape == (1, m, bucket) and parity.dtype == jnp.uint8
+    assert np.array_equal(np.asarray(parity)[0],
+                          gf_cpu.encode_stripe(stripe[0], m))
 
 
 def _seeded_rand(seed: int):
