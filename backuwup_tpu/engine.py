@@ -163,6 +163,15 @@ def _registry_stage_sums() -> Dict[str, float]:
     return out
 
 
+def _in_span(name: str, trace_id: Optional[str], fn, *args):
+    """``fn(*args)`` inside span ``name``, for an executor thread: off
+    the event loop the span lies on the profiler's host plane, and the
+    backup's trace id is handed in (contextvars do not cross
+    ``run_in_executor``)."""
+    with obs_trace.bind(trace_id), obs_trace.span(name):
+        return fn(*args)
+
+
 class Orchestrator:
     """Cross-task shared state (backup_orchestrator.rs:20-45)."""
 
@@ -1086,6 +1095,9 @@ class Engine:
             return snapshot
 
     async def _run_backup_locked(self, root: Optional[Path]) -> bytes:
+        # the backup's wall, phase by phase (obs/profile.WALL_PHASES):
+        # monotonic reads on this coroutine, the entry first
+        marks = [time.monotonic()]
         root = Path(root or (self.store.get_backup_path() or ""))
         if not root.is_dir():
             raise EngineError(f"backup path {root} is not a directory")
@@ -1093,9 +1105,14 @@ class Engine:
         profile_base = obs_profile.baseline()
         orch = self.orchestrator = Orchestrator()
         loop = asyncio.get_running_loop()
+        # contextvars do not cross run_in_executor: hand the backup's
+        # trace id to its threads so their spans journal under it
+        backup_tid = obs_trace.current_trace_id()
         # the size estimate walks the whole tree: keep it off the event
         # loop (backup/mod.rs:207-238 runs it blocking; we cannot)
-        estimate = await loop.run_in_executor(None, self.estimate_size, root)
+        estimate = await self._blocking(
+            _in_span, "backup.estimate", backup_tid, self.estimate_size, root)
+        marks.append(time.monotonic())
         orch.set_buffer(self._buffer_bytes())  # leftovers from past runs
         self._log(f"backup started, estimated {estimate} bytes")
         self._progress(size_estimate=estimate, running=True)
@@ -1105,28 +1122,27 @@ class Engine:
         # join against this, persisted atomically with the lineage row.
         # Written only from the single pack thread, read after it joins.
         manifest: Dict[bytes, int] = {}
-        # contextvars do not cross run_in_executor: hand the backup's
-        # trace id to the pack thread so its spans journal under it
-        backup_tid = obs_trace.current_trace_id()
 
         def pack_thread() -> None:
             writer = PackfileWriter(
                 self.keys, self._pack_dir(),
-                on_packfile=self._on_packfile_threadsafe(loop),
+                on_packfile=self._on_packfile_threadsafe(loop, backup_tid),
                 seal_workers=defaults.PACK_SEAL_WORKERS)
             packer = DirPacker(self.backend, writer, self.index,
                                progress=self._pack_progress,
                                should_pause=orch.block_if_paused,
                                dedup_index=self.device_dedup,
                                on_blob=lambda h, s: manifest.setdefault(h, s))
-            try:
-                with obs_trace.bind(backup_tid), \
-                        obs_trace.span("engine.pack"), \
-                        obs_trace.jax_profiler("backup_pack"):
-                    snapshot_holder["hash"] = packer.pack(root)
-                snapshot_holder["stats"] = packer.stats
-            finally:
-                writer.shutdown()
+            # ``engine.pack`` is the pack thread's whole: the report's
+            # ``pack`` section is closed against it (obs/profile.PACK_STEPS)
+            with obs_trace.bind(backup_tid), obs_trace.span("engine.pack"):
+                try:
+                    with obs_trace.jax_profiler("backup_pack"):
+                        snapshot_holder["hash"] = packer.pack(root)
+                    snapshot_holder["stats"] = packer.stats
+                finally:
+                    with obs_trace.span("pack.flush"):
+                        writer.shutdown()
 
         # the streaming dataflow: pack, seal and send all concurrently
         # busy, linked by bounded queues (docs/dataflow.md)
@@ -1137,10 +1153,13 @@ class Engine:
             await pack_fut
             orch.packing_completed = True
             packed_t = time.monotonic()
+            marks.append(packed_t)
             # wake a send loop parked on the seal event: no more seal
             # commits are coming, the drain check must run now
             orch.notify_packfile()
-            await self._blocking(self.index.flush)
+            await self._blocking(_in_span, "backup.index_flush", backup_tid,
+                                 self.index.flush)
+            marks.append(time.monotonic())
         except BaseException:
             # BaseException on purpose: an injected CrashInjected (and a
             # cancel of this coroutine) must still tear down the send
@@ -1153,6 +1172,7 @@ class Engine:
         except asyncio.CancelledError:
             raise EngineError("send pipeline cancelled")
         done_t = time.monotonic()
+        marks.append(done_t)
         wall_s = done_t - wall_t0
         snapshot = snapshot_holder["hash"]
         self.last_pack_stats = snapshot_holder["stats"]
@@ -1174,17 +1194,22 @@ class Engine:
         # chain (docs/lifecycle.md)
         parent = self.store.latest_snapshot()
         await self._blocking(
+            _in_span, "backup.record_snapshot", backup_tid,
             self.store.record_snapshot, snapshot,
             None if parent is None else parent.hash,
             snapshot_holder["stats"].bytes_read, list(manifest.items()))
+        # held across an await on the loop, so clock reads and no span
+        t_server = time.monotonic()
         await self.server.backup_done(snapshot)
+        backup_done_s = time.monotonic() - t_server
         self.store.add_event(EVENT_BACKUP, {
             "size": snapshot_holder["stats"].bytes_read,
             "snapshot": snapshot.hex()})
         # per-backup pipeline report: dispatch counts, bytes, padding
-        # efficiency, stage seconds — the number the round-5 digest-merge
-        # gate watches (PERF.md)
-        self.last_pipeline_report = obs_profile.report(profile_base)
+        # efficiency, stage seconds, and the backup's wall closed phase
+        # by phase (the last, ``commit``, ends where the report is built)
+        self.last_pipeline_report = obs_profile.report(
+            profile_base, wall_marks=marks, backup_done_s=backup_done_s)
         obs_profile.emit_report(
             self.last_pipeline_report, snapshot=snapshot.hex(),
             backend=getattr(self.backend, "name", "?"),
@@ -1199,22 +1224,28 @@ class Engine:
     def _pack_progress(self, **kw) -> None:
         self._progress(**kw)
 
-    def _on_packfile_threadsafe(self, loop):
+    def _on_packfile_threadsafe(self, loop, tid: Optional[str] = None):
+        """The callback a written packfile, on the writer thread; ``tid``:
+        the backup's trace id (contextvars do not cross threads)."""
         def cb(pid, path, hashes, size):
-            self.index.finalize_packfile(pid, hashes)
-            # Precompute the audit challenge table while the plaintext
-            # packfile is still local (it is unlinked after the peer's
-            # ack) — hashed in one device batch alongside packing.  A
-            # failure here degrades auditing, never the backup itself.
-            try:
-                if not self.challenge_tables.has(pid):
-                    self.challenge_tables.save(
-                        pid, build_challenge_table(
-                            self.backend, path.read_bytes(),
-                            count=defaults.AUDIT_CHALLENGES_PER_PACKFILE))
-            except Exception as e:
-                self._log(f"challenge table for {bytes(pid).hex()[:8]}"
-                          f" failed: {e}")
+            # after ``write`` was observed, with every queued packfile
+            # waiting behind it: a span of its own, beside the pack
+            # thread's steps (``report["pack"]["seal_table_s"]``)
+            with obs_trace.bind(tid), obs_trace.span("pack.seal_table"):
+                self.index.finalize_packfile(pid, hashes)
+                # Precompute the audit challenge table while the plaintext
+                # packfile is still local (it is unlinked after the peer's
+                # ack) — hashed in one device batch alongside packing.  A
+                # failure here degrades auditing, never the backup itself.
+                try:
+                    if not self.challenge_tables.has(pid):
+                        self.challenge_tables.save(
+                            pid, build_challenge_table(
+                                self.backend, path.read_bytes(),
+                                count=defaults.AUDIT_CHALLENGES_PER_PACKFILE))
+                except Exception as e:
+                    self._log(f"challenge table for {bytes(pid).hex()[:8]}"
+                              f" failed: {e}")
             self.orchestrator.bytes_written += size
             self.orchestrator.adjust_buffer(size)
             self._progress(bytes_on_disk=self.orchestrator.bytes_written)

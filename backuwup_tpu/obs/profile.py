@@ -46,7 +46,8 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Dict, Iterator, Optional
+import time
+from typing import Dict, Iterator, Optional, Sequence
 
 from . import journal as _journal
 from . import metrics as _metrics
@@ -259,13 +260,47 @@ _SEND_PACKFILES = _metrics.counter(
 SEND_WIRE_COUNTERS = {"wire_bytes": "bkw_p2p_bytes_sent_total",
                       "deflated_bytes": "bkw_p2p_bytes_deflated_total"}
 
+# One backup's wall, closed (the engine's ``_run_backup_locked``).  Its
+# phases follow each other on the backup's coroutine and are told apart
+# by clock reads there (a span held across an await cannot nest on the
+# loop's line): the size estimate's walk; the pack thread, from its
+# start to its pools' shutdown; the blob index's flush; the send loop's
+# rest; the snapshot's record, the server's ``backup_done`` and the
+# report itself.  :func:`report` makes its ``wall`` section of them.
+WALL_PHASES = ("estimate", "pack", "index_flush", "drain", "commit")
+# The pack thread's wall (span ``engine.pack``), the same way: its
+# top-level spans by step, each opened where the work is and none inside
+# another of these, so a step's seconds are exclusive and the steps sum
+# to ``engine.pack`` less its self time.  ``walk`` is the directory
+# discovery, then a directory's listing a time, and the ``lstat`` walk
+# that tells the backend the batches to come (``batch.compile`` inside
+# it in a process's first backup); ``device_sync`` the host-classified
+# hashes pushed into the HBM table between batches (``index.classify``
+# inside); ``flush`` the wait for every seal and write in flight and
+# the pools' shutdown.  :func:`report` makes its ``pack`` section of
+# them, with ``stall`` (the packer blocked on the writer's double
+# buffer, inside whichever step added the blob) and ``pack.seal_table``
+# (the writer thread's work after a packfile's write) beside them.
+PACK_STEPS = {
+    "pack.walk": "walk",
+    "pack.prepare": "walk",
+    "batch.read": "read",
+    "packer.manifest_many": "manifest",
+    "batch.emit": "emit",
+    "stream.file": "stream",
+    "pack.device_sync": "device_sync",
+    "pack.dir_tree": "dir_tree",
+    "pack.flush": "flush",
+}
+
 # Span names whose bkw_span_seconds sums a pipeline report attributes as
 # per-stage wall time: the batched route's dispatch/collect pairs, the
 # packer entry point that drives them and the batch's own parts, the
-# streamed file and its parts, the index classify, and the send stage's
+# streamed file and its parts, the index classify, the send stage's
 # steps (``send.stripe`` holds ``send.rs_encode``, ``send.challenge_tables``
-# and ``send.wire`` of one packfile).
-REPORT_SPANS = (
+# and ``send.wire`` of one packfile), and the backup's wall: the pack
+# thread and its steps, and what carries the other phases' host work.
+REPORT_SPANS = tuple(dict.fromkeys((
     "pipeline.scan_select_dispatch",
     "pipeline.cut_collect",
     "pipeline.digest_dispatch",
@@ -283,7 +318,13 @@ REPORT_SPANS = (
     "send.challenge_tables",
     "send.stripe",
     "send.wire",
-)
+    "engine.pack",
+    *PACK_STEPS,
+    "pack.seal_table",
+    "backup.estimate",
+    "backup.index_flush",
+    "backup.record_snapshot",
+)))
 
 # Streaming-dataflow overlap families (the engine's stage graph,
 # docs/dataflow.md): per-stage busy seconds attributed to one backup at
@@ -523,12 +564,32 @@ def baseline() -> Dict[str, Dict[str, float]]:
     if spans is not None:
         for name in REPORT_SPANS:
             out["span_s"][name] = spans.sum_value(name=name)
+    # declared in snapshot/packfile.py, read by name as the transport's
+    pack = _metrics.registry().get("bkw_pack_stage_seconds")
+    out["pack_stage_s"] = {
+        "stall": pack.sum_value(stage="stall") if pack is not None else 0.0}
     return out
 
 
-def report(base: Optional[dict] = None) -> dict:
+def _by_group(span_s: Dict[str, float],
+              groups: Dict[str, str]) -> Dict[str, float]:
+    """Span seconds summed by the group ``groups`` puts each name in."""
+    out = dict.fromkeys(groups.values(), 0.0)
+    for name, group in groups.items():
+        out[group] += span_s.get(name, 0.0)
+    return {k: round(v, 6) for k, v in out.items()}
+
+
+def report(base: Optional[dict] = None,
+           wall_marks: Optional[Sequence[float]] = None,
+           backup_done_s: float = 0.0) -> dict:
     """Dispatch counts, bytes, padding efficiency, and stage seconds
-    since ``base`` (or process start when ``base`` is None)."""
+    since ``base`` (or process start when ``base`` is None).
+
+    ``wall_marks``: ``time.monotonic()`` reads on the backup's coroutine,
+    its entry and then the end of each of ``WALL_PHASES`` but the last,
+    which ends here.  ``backup_done_s``: the part of ``commit`` spent
+    awaiting the server (loop time, so a clock read as well)."""
     now = baseline()
     base = base or {}
 
@@ -546,18 +607,16 @@ def report(base: Optional[dict] = None) -> dict:
     span_s = _delta("span_s")
     stage_seconds = {name: round(dt, 6)
                      for name, dt in span_s.items() if dt > 0}
-    stream = dict.fromkeys(STREAM_GROUPS.values(), 0.0)
-    for name, group in STREAM_GROUPS.items():
-        stream[group] += span_s.get(name, 0.0)
-    stream = {k: round(v, 6) for k, v in stream.items()}
+    stream: dict = _by_group(span_s, STREAM_GROUPS)
     for kind, n in _delta("stream_bytes").items():
         stream[f"{kind}_bytes"] = int(n)
-    batch: dict = dict.fromkeys(BATCH_GROUPS.values(), 0.0)
-    for name, group in BATCH_GROUPS.items():
-        batch[group] += span_s.get(name, 0.0)
-    batch = {k: round(v, 6) for k, v in batch.items()}
+    batch: dict = _by_group(span_s, BATCH_GROUPS)
     batch["chunks"] = {k: int(v) for k, v in _delta("batch_chunks").items()}
     batch["files"] = {k: int(v) for k, v in _delta("batch_files").items()}
+    pack = {"total_s": round(span_s.get("engine.pack", 0.0), 6),
+            "steps": _by_group(span_s, PACK_STEPS),
+            "stall_s": round(_delta("pack_stage_s")["stall"], 6),
+            "seal_table_s": round(span_s.get("pack.seal_table", 0.0), 6)}
     compile_s = {fun: round(dt, 6)
                  for fun, dt in _delta("compile_s").items() if dt > 0}
     # per-device split of the mesh-pipeline launches: {device: {stage: n}}
@@ -586,6 +645,7 @@ def report(base: Optional[dict] = None) -> dict:
         "stage_seconds": stage_seconds,
         "stream": stream,
         "batch": batch,
+        "pack": pack,
         "send": {k: int(v) for k, v in _delta("send").items()},
         "compile_s": compile_s,
         "compile_total_s": round(sum(compile_s.values()), 6),
@@ -610,6 +670,13 @@ def report(base: Optional[dict] = None) -> dict:
             d: by_device[d] for d in sorted(by_device, key=int)}
         out["device_pad_efficiency"] = {
             d: eff_device.get(d, {}) for d in sorted(by_device, key=int)}
+    if wall_marks is not None:
+        ends = [*wall_marks[1:], time.monotonic()]
+        out["wall"] = {
+            "total_s": round(ends[-1] - wall_marks[0], 6),
+            "phases": {phase: round(end - start, 6) for phase, start, end
+                       in zip(WALL_PHASES, wall_marks, ends)},
+            "backup_done_s": round(backup_done_s, 6)}
     return out
 
 
@@ -631,8 +698,9 @@ def overlap_report(stage_busy: Dict[str, float], wall_s: float,
     the slowest stage (perfect overlap).  Concurrent fan-out can
     legitimately push a stage's summed busy seconds past the wall, so
     values above 1.0 are kept as-is.
-    ``drain_s`` is what the send stage added after the packer was done:
-    from the last blob packed to the last packfile acked."""
+    ``drain_s`` is what came after the pack thread was done: the blob
+    index's flush, then the send loop's rest up to the last packfile
+    acked (the report's ``wall`` section tells the two apart)."""
     busy = {k: max(float(v), 0.0) for k, v in stage_busy.items()}
     for stage, dt in busy.items():
         if dt > 0:

@@ -136,7 +136,10 @@ class DirPacker:
 
     def _flush_device_sync(self) -> None:
         if self.dedup_batch is not None and self._device_sync:
-            self.dedup_batch(self._device_sync)
+            # a step of the pack thread's own (obs/profile.PACK_STEPS):
+            # between the route's spans, with ``index.classify`` inside
+            with obs_trace.span("pack.device_sync"):
+                self.dedup_batch(self._device_sync)
             self._device_sync.clear()
 
     def _maybe_emit_partial(self) -> None:
@@ -404,38 +407,48 @@ class DirPacker:
         # discover directories breadth-first, then process deepest-first so
         # children always hash before parents (dir_packer.rs:89-132)
         order: List[Path] = [root]
-        for d in order:
-            try:
-                subdirs = sorted(p for p in d.iterdir()
-                                 if p.is_dir() and not p.is_symlink())
-            except OSError:
-                subdirs = []
-            order.extend(subdirs)
+        with obs_trace.span("pack.walk"):
+            for d in order:
+                try:
+                    subdirs = sorted(p for p in d.iterdir()
+                                     if p.is_dir() and not p.is_symlink())
+                except OSError:
+                    subdirs = []
+                order.extend(subdirs)
         if self.dedup_index is not None:
-            self.backend.prepare_batches(self._batch_sizes(order),
-                                         self.dedup_index)
+            with obs_trace.span("pack.prepare"):
+                self.backend.prepare_batches(self._batch_sizes(order),
+                                             self.dedup_index)
         dir_hash: dict = {}
         for d in reversed(order):
-            try:
-                entries = sorted(d.iterdir())
-            except OSError:
-                entries = []
-            files = [p for p in entries
-                     if p.is_file() and not p.is_symlink()]
-            subdirs = [p for p in entries if p.is_dir() and not p.is_symlink()]
+            with obs_trace.span("pack.walk"):
+                try:
+                    entries = sorted(d.iterdir())
+                except OSError:
+                    entries = []
+                files = [p for p in entries
+                         if p.is_file() and not p.is_symlink()]
+                subdirs = [p for p in entries
+                           if p.is_dir() and not p.is_symlink()]
             children = [h for h in self._pack_files(files) if h is not None]
-            children.extend(dir_hash[s] for s in subdirs if s in dir_hash)
-            try:
-                st = d.stat()
-                meta = TreeMetadata(size=0, mtime_ns=st.st_mtime_ns,
-                                    ctime_ns=st.st_ctime_ns)
-            except OSError:  # directory vanished mid-walk: keep its children
-                meta = TreeMetadata()
-            name = "" if d == root else d.name
-            dir_hash[d] = self._tree_with_split(TreeKind.DIR, name, meta,
-                                                children)
-            self.stats.dirs += 1
-            self._maybe_emit_partial()
+            with obs_trace.span("pack.dir_tree"):
+                children.extend(dir_hash[s] for s in subdirs if s in dir_hash)
+                try:
+                    st = d.stat()
+                    meta = TreeMetadata(size=0, mtime_ns=st.st_mtime_ns,
+                                        ctime_ns=st.st_ctime_ns)
+                except OSError:
+                    # directory vanished mid-walk: keep its children
+                    meta = TreeMetadata()
+                name = "" if d == root else d.name
+                dir_hash[d] = self._tree_with_split(TreeKind.DIR, name, meta,
+                                                    children)
+                self.stats.dirs += 1
+                self._maybe_emit_partial()
         self._flush_device_sync()
-        self.writer.flush()
+        # the wait for every seal and write in flight, and for what the
+        # writer thread does after each (``on_packfile``: the index, the
+        # seal-time table): none of it is the writer's ``stall``
+        with obs_trace.span("pack.flush"):
+            self.writer.flush()
         return dir_hash[root]

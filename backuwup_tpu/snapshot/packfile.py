@@ -28,7 +28,6 @@ raises ``DirtyPackfileError`` if data would be lost.
 from __future__ import annotations
 
 import os
-import threading
 import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
@@ -157,8 +156,6 @@ class PackfileWriter:
         self._write_pool: Optional[ThreadPoolExecutor] = None
         self._batch: List = []  # futures of _Pending, submission order
         self._writes: deque = deque()  # in-flight assemble+write futures
-        self._stats_lock = threading.Lock()
-        self.stage_seconds = {"seal": 0.0, "write": 0.0, "stall": 0.0}
         if self.seal_workers:
             self._seal_pool = ThreadPoolExecutor(
                 max_workers=self.seal_workers,
@@ -193,10 +190,7 @@ class PackfileWriter:
         header = PackfileHeaderBlob(
             hash=blob_hash, kind=kind, compression=comp_kind,
             length=len(record), offset=0)  # offset assigned at write time
-        dt = time.monotonic() - t0
-        with self._stats_lock:
-            self.stage_seconds["seal"] += dt
-        _STAGE_SECONDS.observe(dt, stage="seal")
+        _STAGE_SECONDS.observe(time.monotonic() - t0, stage="seal")
         return _Pending(header, record, len(data))
 
     def add_blob(self, blob: Blob) -> None:
@@ -246,10 +240,7 @@ class PackfileWriter:
         t0 = time.monotonic()
         while len(self._writes) >= max(1, defaults.PACK_SEAL_QUEUE_PACKFILES):
             self._writes.popleft().result()
-        dt = time.monotonic() - t0
-        with self._stats_lock:
-            self.stage_seconds["stall"] += dt
-        _STAGE_SECONDS.observe(dt, stage="stall")
+        _STAGE_SECONDS.observe(time.monotonic() - t0, stage="stall")
         self._writes.append(self._write_pool.submit(
             self._assemble_batch, batch))
 
@@ -345,11 +336,10 @@ class PackfileWriter:
         durable.commit_replace(tmp, path)
         faults.crashpoint(_CP_SEAL_POST)
         size = path.stat().st_size
-        dt = time.monotonic() - t0
-        with self._stats_lock:
-            self.bytes_written += size
-            self.stage_seconds["write"] += dt
-        _STAGE_SECONDS.observe(dt, stage="write")
+        # one thread writes packfiles (the writer thread, or the packer's
+        # own with no seal workers): nothing else touches the count
+        self.bytes_written += size
+        _STAGE_SECONDS.observe(time.monotonic() - t0, stage="write")
         hashes = [h.hash for h in headers]
         assert size <= self._cap, "cap enforced before write"
         if self.on_packfile is not None:
