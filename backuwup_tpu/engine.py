@@ -26,7 +26,6 @@ Re-designs ``client/src/backup/mod.rs`` + ``backup_orchestrator.rs`` +
 from __future__ import annotations
 
 import asyncio
-import os
 import shutil
 import threading
 import time
@@ -71,7 +70,7 @@ from .obs import profile as obs_profile
 from .obs import trace as obs_trace
 from .ops.backend import ChunkerBackend, select_backend
 from .snapshot.blob_index import BlobIndex, ChallengeTable
-from .snapshot.packer import DirPacker
+from .snapshot.packer import DirPacker, TreeScan, scan_tree
 from .snapshot.packfile import PackfileReader, PackfileWriter, packfile_path
 from .store import (EVENT_BACKUP, EVENT_GC, EVENT_REPAIR,
                     EVENT_RESTORE_REQUEST, Store)
@@ -286,6 +285,8 @@ class Engine:
                                          self.device_dedup.axis)
         self.orchestrator = Orchestrator()
         self.last_pack_stats = None
+        # estimate_size's scan of the tree, until its backup takes it
+        self._tree_scan: Optional[TreeScan] = None
         # backup and restore are mutually exclusive and non-reentrant
         # (restore_orchestrator.rs:45-56); a second start must fail loudly,
         # not corrupt the pack dir with a concurrent packer
@@ -344,14 +345,12 @@ class Engine:
     # --- size estimate (backup/mod.rs:207-238) -----------------------------
 
     def estimate_size(self, root: Path) -> int:
+        """The bytes a backup of ``root`` will need stored.  The scan of
+        the tree that tells it is the packer's own first pass: kept for
+        the backup that asked (``_tree_scan``), which hands it on."""
         last = self.store.last_backup_size()
-        total = 0
-        for dirpath, _dirnames, filenames in os.walk(root):
-            for f in filenames:
-                try:
-                    total += (Path(dirpath) / f).stat().st_size
-                except OSError:
-                    pass
+        self._tree_scan = scan_tree(root)
+        total = self._tree_scan.total_bytes
         if last is not None:
             # incremental estimate: only the size delta needs new storage
             return max(total - last, min(total, 50 * 1000 * 1000))
@@ -1110,8 +1109,10 @@ class Engine:
         backup_tid = obs_trace.current_trace_id()
         # the size estimate walks the whole tree: keep it off the event
         # loop (backup/mod.rs:207-238 runs it blocking; we cannot)
+        self._tree_scan = None
         estimate = await self._blocking(
             _in_span, "backup.estimate", backup_tid, self.estimate_size, root)
+        scan, self._tree_scan = self._tree_scan, None
         marks.append(time.monotonic())
         orch.set_buffer(self._buffer_bytes())  # leftovers from past runs
         self._log(f"backup started, estimated {estimate} bytes")
@@ -1138,7 +1139,7 @@ class Engine:
             with obs_trace.bind(backup_tid), obs_trace.span("engine.pack"):
                 try:
                     with obs_trace.jax_profiler("backup_pack"):
-                        snapshot_holder["hash"] = packer.pack(root)
+                        snapshot_holder["hash"] = packer.pack(root, scan)
                     snapshot_holder["stats"] = packer.stats
                 finally:
                     with obs_trace.span("pack.flush"):

@@ -212,6 +212,17 @@ _BATCH_FILES = _metrics.counter(
     "batch, padded buckets, or the long-stream scan",
     labelnames=("route",))
 
+# What the packer asked the file system about a backup's tree
+# (``snapshot/packer.py`` ``_list_dir``, the only place it asks): the
+# ``os.scandir`` calls and the ``lstat`` calls of both passes, and the
+# directories and regular files the first pass (``scan_tree``) found.
+# Two calls a file and two a directory say no other walk is left.
+TREE_SCAN_COUNTS = ("dirs", "files", "scandir_calls", "lstat_calls")
+_TREE_SCAN = _metrics.counter(
+    "bkw_tree_scan_total",
+    "Directories and regular files a backup's tree scan found, and the "
+    "scandir and lstat calls its two passes made", labelnames=("what",))
+
 # Bytes the resident streaming route (ops/resident.py) moved for a
 # streamed file: ``uploaded`` is everything it put on the device
 # (window blocks, chunk rows), about the file's size when every byte
@@ -271,10 +282,12 @@ WALL_PHASES = ("estimate", "pack", "index_flush", "drain", "commit")
 # The pack thread's wall (span ``engine.pack``), the same way: its
 # top-level spans by step, each opened where the work is and none inside
 # another of these, so a step's seconds are exclusive and the steps sum
-# to ``engine.pack`` less its self time.  ``walk`` is the directory
-# discovery, then a directory's listing a time, and the ``lstat`` walk
-# that tells the backend the batches to come (``batch.compile`` inside
-# it in a process's first backup); ``device_sync`` the host-classified
+# to ``engine.pack`` less its self time.  ``walk`` is a directory's
+# listing a time (one ``os.scandir``, one ``lstat`` a file), ahead of
+# them the tree's scan where the caller handed none (the engine hands
+# the estimate's), and the backend told the batches to come from that
+# scan's lengths (``batch.compile`` inside it in a process's first
+# backup); ``device_sync`` the host-classified
 # hashes pushed into the HBM table between batches (``index.classify``
 # inside); ``flush`` the wait for every seal and write in flight and
 # the pools' shutdown.  :func:`report` makes its ``pack`` section of
@@ -456,6 +469,15 @@ def batch_chunks(verdict: str, n: int) -> None:
         _BATCH_CHUNKS.inc(n, verdict=verdict)
 
 
+def tree_scan(**counts: int) -> None:
+    """Add to the tree scan's counts (``TREE_SCAN_COUNTS``)."""
+    for what, n in counts.items():
+        if what not in TREE_SCAN_COUNTS:
+            raise ValueError(f"unknown tree scan count {what!r}")
+        if n:
+            _TREE_SCAN.inc(n, what=what)
+
+
 def batch_files(route: str, n: int) -> None:
     """``n`` files of one pack batch sent down ``route``."""
     if route not in BATCH_ROUTES:
@@ -552,6 +574,8 @@ def baseline() -> Dict[str, Dict[str, float]]:
                            for v in BATCH_VERDICTS}
     out["batch_files"] = {r: _BATCH_FILES.value(route=r)
                           for r in BATCH_ROUTES}
+    out["tree_scan"] = {w: _TREE_SCAN.value(what=w)
+                        for w in TREE_SCAN_COUNTS}
     out["send"] = {f"{k}_bytes": _SEND_BYTES.value(kind=k)
                    for k in SEND_BYTE_KINDS}
     out["send"]["dispatches"] = _SEND_DISPATCHES.value()
@@ -615,6 +639,7 @@ def report(base: Optional[dict] = None,
     batch["files"] = {k: int(v) for k, v in _delta("batch_files").items()}
     pack = {"total_s": round(span_s.get("engine.pack", 0.0), 6),
             "steps": _by_group(span_s, PACK_STEPS),
+            "scan": {k: int(v) for k, v in _delta("tree_scan").items()},
             "stall_s": round(_delta("pack_stage_s")["stall"], 6),
             "seal_table_s": round(span_s.get("pack.seal_table", 0.0), 6)}
     compile_s = {fun: round(dt, 6)

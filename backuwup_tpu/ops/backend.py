@@ -127,7 +127,7 @@ class ChunkerBackend:
                         dedup) -> None:
         """The lengths of the files of every pack batch a backup is about
         to hand to :meth:`manifest_many_classified`, before the first
-        (an iterable the packer fills from a walk of its own: read it
+        (an iterable the packer cuts from its scan of the tree: read it
         only to use it).  A backend that compiles programs per shape
         compiles them side by side here (:class:`TpuBackend`); the
         others have nothing to do."""

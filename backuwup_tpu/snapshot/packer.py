@@ -22,9 +22,10 @@ from __future__ import annotations
 import logging
 import os
 import time
-from dataclasses import dataclass, field
+from array import array
+from dataclasses import dataclass
 from pathlib import Path
-from typing import Callable, List, Optional
+from typing import Callable, Iterator, List, Optional, Tuple
 
 from .. import defaults
 from ..obs import metrics as obs_metrics
@@ -56,6 +57,109 @@ class PackStats:
     # only: a streamed file's per-chunk emit (host index, seal queue,
     # writer, the pause behind the send buffer) is left out
     chunk_hash_s: float = 0.0
+
+
+def _list_dir(path: str) -> Tuple[list, List[str], list, int]:
+    """One ``os.scandir`` of ``path`` and one ``lstat`` a regular file,
+    the only questions the packer asks the file system about a tree:
+    ``(files, subdirs, links, failed)`` in name order (the order
+    ``sorted(Path.iterdir())`` gives within one parent).  ``files`` are
+    ``(entry, lstat result)`` of the regular files, ``subdirs`` the paths
+    of the real directories, ``links`` the symlinks' entries, ``failed``
+    the files that were gone or unreadable by their ``lstat``; anything
+    else (FIFOs, sockets, devices) is skipped.  An ``os.DirEntry`` knows
+    its kind from the directory's own record, so the ``lstat`` is the one
+    call a file.  A directory that cannot be listed reads as empty."""
+    files: list = []
+    subdirs: List[str] = []
+    links: list = []
+    failed = 0
+    try:
+        with os.scandir(path) as it:
+            entries = sorted(it, key=lambda e: e.name)
+    except OSError:
+        entries = []
+    for entry in entries:
+        try:
+            if entry.is_file(follow_symlinks=False):
+                try:
+                    files.append((entry, entry.stat(follow_symlinks=False)))
+                except OSError:
+                    failed += 1
+            elif entry.is_dir(follow_symlinks=False):
+                subdirs.append(entry.path)
+            elif entry.is_symlink():
+                links.append(entry)
+        except OSError:
+            continue
+    obs_profile.tree_scan(scandir_calls=1, lstat_calls=len(files) + failed)
+    return files, subdirs, links, failed
+
+
+@dataclass
+class TreeScan:
+    """What a backup knows of its tree before it packs it
+    (:func:`scan_tree`): integers and directory paths, no ``stat``
+    result and no file's path, so O(directories) + 8 bytes a file."""
+    #: the directories breadth-first from the root, sorted by name
+    #: within a parent; packed in reverse, children before parents
+    dirs: List[str]
+    #: the size estimate's sum (``Engine.estimate_size``)
+    total_bytes: int
+    #: a directory of ``dirs`` each: its regular files' lengths in name
+    #: order
+    file_sizes: List[array]
+
+    def batches(self, batch_bytes: int) -> Iterator[List[int]]:
+        """The file lengths of the pack batches to come, a list a batch
+        (a directory's files up to ``batch_bytes``, as
+        ``DirPacker._pack_files`` cuts them; a larger file is streamed),
+        for ``ChunkerBackend.prepare_batches``.  A file that changes
+        before it is read costs a compile in its batch, nothing else."""
+        for lengths in self.file_sizes:
+            sizes: List[int] = []
+            pending = 0
+            for n in lengths:
+                if n > batch_bytes:
+                    continue
+                sizes.append(n)
+                pending += n
+                if pending >= batch_bytes:
+                    yield sizes
+                    sizes, pending = [], 0
+            if sizes:
+                yield sizes
+
+
+def scan_tree(root: Path) -> TreeScan:
+    """The first of the two looks a backup takes at its tree: a
+    breadth-first :func:`_list_dir` walk from ``root``, once a backup
+    (``Engine.estimate_size`` makes it in the ``backup.estimate`` phase
+    and hands it to :meth:`DirPacker.pack`, which else makes its own).
+    The second is :meth:`DirPacker.pack`'s own listing of a directory as
+    it packs it, so that a file's times are taken next to its read.
+
+    The estimate counts what ``os.walk`` with a ``stat()`` a file
+    counted: every regular file, and for a symlink what it points to
+    (one ``stat`` a symlink), unless that is a directory."""
+    dirs = [os.fspath(Path(root))]
+    file_sizes: List[array] = []
+    total = files_seen = 0
+    for d in dirs:
+        files, subdirs, links, _failed = _list_dir(d)
+        sizes = array("q", (st.st_size for _entry, st in files))
+        file_sizes.append(sizes)
+        dirs.extend(subdirs)
+        total += sum(sizes)
+        files_seen += len(sizes)
+        for link in links:
+            try:
+                if not link.is_dir():
+                    total += link.stat().st_size
+            except OSError:
+                pass
+    obs_profile.tree_scan(dirs=len(dirs), files=files_seen)
+    return TreeScan(dirs=dirs, total_bytes=total, file_sizes=file_sizes)
 
 
 class DirPacker:
@@ -179,9 +283,11 @@ class DirPacker:
 
     # --- file chunking (the TPU-batched hot path) --------------------------
 
-    def _pack_files(self, files: List[Path]) -> List[Optional[bytes]]:
-        """Chunk+hash a batch of files; returns each file's tree hash
-        (None for files that vanished or failed to read)."""
+    def _pack_files(self, files: List[Tuple[Path, os.stat_result]]
+                    ) -> List[Optional[bytes]]:
+        """Chunk+hash a directory's files, each with the ``lstat`` its
+        listing took; returns each file's tree hash (None for files that
+        vanished or failed to read)."""
         hashes: List[Optional[bytes]] = [None] * len(files)
         batch_idx: List[int] = []
         batch_data: List[bytes] = []
@@ -255,10 +361,10 @@ class DirPacker:
                             data[ref.offset:ref.offset + ref.length],
                             dup_hint=next(hints, None))
                     hashes[i] = self._tree_with_split(
-                        TreeKind.FILE, files[i].name, meta,
+                        TreeKind.FILE, files[i][0].name, meta,
                         [ref.hash for ref in manifest])
                     self.stats.files += 1
-                    self.progress(file=str(files[i]), bytes=len(data))
+                    self.progress(file=str(files[i][0]), bytes=len(data))
             self._flush_device_sync()
             self._maybe_emit_partial()
             batch_idx.clear()
@@ -266,15 +372,13 @@ class DirPacker:
             batch_meta.clear()
 
         pending = 0
-        for i, path in enumerate(files):
-            try:
-                st = path.lstat()
-                if st.st_size > self.batch_bytes:
-                    # oversized file: stream it so memory stays bounded
+        for i, (path, st) in enumerate(files):
+            if st.st_size > self.batch_bytes:
+                # oversized file: stream it so memory stays bounded
+                try:
                     hashes[i] = self._pack_file_streaming(path, st)
-                    continue
-            except OSError:
-                self.stats.failed_files += 1
+                except OSError:
+                    self.stats.failed_files += 1
                 continue
             to_read.append((i, path, st))
             pending += st.st_size
@@ -366,81 +470,41 @@ class DirPacker:
 
     # --- directory walk ----------------------------------------------------
 
-    def _batch_sizes(self, dirs: List[Path]):
-        """The file lengths of the pack batches this backup will make,
-        a list a batch (a directory's files up to ``batch_bytes``, as
-        :meth:`_pack_files` cuts them; a larger file is streamed), for
-        ``ChunkerBackend.prepare_batches``: a backend that compiles a
-        program a shape compiles side by side what they need, any other
-        never starts this walk.  One ``lstat`` a file; a file that
-        changes before it is read costs a compile in its batch, nothing
-        else."""
-        for d in dirs:
-            sizes: List[int] = []
-            pending = 0
-            try:
-                entries = sorted(d.iterdir())
-            except OSError:
-                continue
-            for p in entries:
-                try:
-                    if p.is_symlink() or not p.is_file():
-                        continue
-                    n = p.lstat().st_size
-                except OSError:
-                    continue
-                if n > self.batch_bytes:
-                    continue
-                sizes.append(n)
-                pending += n
-                if pending >= self.batch_bytes:
-                    yield sizes
-                    sizes, pending = [], 0
-            if sizes:
-                yield sizes
-
-    def pack(self, root: Path) -> bytes:
-        """Pack ``root`` recursively; returns the snapshot id (root hash)."""
+    def pack(self, root: Path, scan: Optional[TreeScan] = None) -> bytes:
+        """Pack ``root`` recursively; returns the snapshot id (root hash).
+        ``scan``: :func:`scan_tree` of this ``root`` where the caller
+        has made it (the engine's estimate)."""
         root = Path(root)
         if not root.is_dir():
             raise NotADirectoryError(str(root))
-        # discover directories breadth-first, then process deepest-first so
-        # children always hash before parents (dir_packer.rs:89-132)
-        order: List[Path] = [root]
-        with obs_trace.span("pack.walk"):
-            for d in order:
-                try:
-                    subdirs = sorted(p for p in d.iterdir()
-                                     if p.is_dir() and not p.is_symlink())
-                except OSError:
-                    subdirs = []
-                order.extend(subdirs)
+        if scan is None:
+            with obs_trace.span("pack.walk"):
+                scan = scan_tree(root)
+        elif scan.dirs[0] != os.fspath(root):
+            raise ValueError(f"scan of {scan.dirs[0]}, not of {root}")
         if self.dedup_index is not None:
             with obs_trace.span("pack.prepare"):
-                self.backend.prepare_batches(self._batch_sizes(order),
+                self.backend.prepare_batches(scan.batches(self.batch_bytes),
                                              self.dedup_index)
+        # the directories were found breadth-first: packed deepest-first,
+        # children always hash before parents (dir_packer.rs:89-132)
         dir_hash: dict = {}
-        for d in reversed(order):
+        for d in reversed(scan.dirs):
             with obs_trace.span("pack.walk"):
-                try:
-                    entries = sorted(d.iterdir())
-                except OSError:
-                    entries = []
-                files = [p for p in entries
-                         if p.is_file() and not p.is_symlink()]
-                subdirs = [p for p in entries
-                           if p.is_dir() and not p.is_symlink()]
+                listed, subdirs, _links, failed = _list_dir(d)
+                self.stats.failed_files += failed
+                files = [(Path(entry.path), st) for entry, st in listed]
             children = [h for h in self._pack_files(files) if h is not None]
             with obs_trace.span("pack.dir_tree"):
                 children.extend(dir_hash[s] for s in subdirs if s in dir_hash)
                 try:
-                    st = d.stat()
+                    st = os.stat(d)
                     meta = TreeMetadata(size=0, mtime_ns=st.st_mtime_ns,
                                         ctime_ns=st.st_ctime_ns)
                 except OSError:
                     # directory vanished mid-walk: keep its children
                     meta = TreeMetadata()
-                name = "" if d == root else d.name
+                name = "" if d == scan.dirs[0] else os.path.basename(d)
                 dir_hash[d] = self._tree_with_split(TreeKind.DIR, name, meta,
                                                     children)
                 self.stats.dirs += 1
@@ -451,4 +515,4 @@ class DirPacker:
         # seal-time table): none of it is the writer's ``stall``
         with obs_trace.span("pack.flush"):
             self.writer.flush()
-        return dir_hash[root]
+        return dir_hash[scan.dirs[0]]
