@@ -173,7 +173,7 @@ STREAM_GROUPS = {
 }
 
 # The same for one pack batch of whole files (the batched route:
-# ``Packer._pack_files`` -> ``manifest_many_classified`` ->
+# ``DirPacker._flush_batch`` -> ``manifest_many_classified`` ->
 # ``DevicePipeline.manifest_batch_classified``): reading the files,
 # building host batches and decoding what came down, waiting for the
 # device (the mesh program's dispatch and collect, the tiny files'
@@ -211,6 +211,32 @@ _BATCH_FILES = _metrics.counter(
     "Files of the batched route by prepass route: one tiny-file digest "
     "batch, padded buckets, or the long-stream scan",
     labelnames=("route",))
+
+# The pack batches of a backup (``snapshot/packer.py`` ``_flush_batch``:
+# one read, one ``manifest_many_classified``, one emit each), and what
+# they held: the directories with a file in the batch (one cut by
+# ``batch_bytes`` counts in both its batches) and the files read.
+PACK_BATCH_ITEMS = {"dirs": "dirs", "files": "batched_files"}  # -> report
+_PACK_BATCHES = _metrics.counter(
+    "bkw_pack_batches_total",
+    "Pack batches the packer handed to the chunker backend")
+_PACK_BATCH_ITEMS = _metrics.counter(
+    "bkw_pack_batch_items_total",
+    "Directories and files the pack batches held", labelnames=("what",))
+
+# The rows of the HBM index's host-fed query batches
+# (``ops/dedup_index.py`` ``_pad_queries``, where ``probe``, ``insert``
+# and ``_insert_once`` get their shapes): the hashes sent, and the rows
+# of the power-of-two bucket they were padded to (at most twice as many
+# from 8 up), which is what keeps ``dedup_insert`` / ``dedup_probe``
+# from compiling for every new count
+# (``bkw_jit_compile_seconds{fun="dedup_insert"}``).
+INDEX_QUERY_ROWS = ("actual", "padded")
+_INDEX_QUERY_ROWS = _metrics.counter(
+    "bkw_index_query_rows_total",
+    "Rows of the query batches the host sent the HBM dedup index: the "
+    "hashes themselves, and the rows of the bucket they were padded to",
+    labelnames=("what",))
 
 # What the packer asked the file system about a backup's tree
 # (``snapshot/packer.py`` ``_list_dir``, the only place it asks): the
@@ -478,6 +504,19 @@ def tree_scan(**counts: int) -> None:
             _TREE_SCAN.inc(n, what=what)
 
 
+def pack_batch(dirs: int, files: int) -> None:
+    """One pack batch of ``files`` files out of ``dirs`` directories."""
+    _PACK_BATCHES.inc()
+    _PACK_BATCH_ITEMS.inc(dirs, what="dirs")
+    _PACK_BATCH_ITEMS.inc(files, what="files")
+
+
+def index_query_rows(actual: int, padded: int) -> None:
+    """One query batch of ``actual`` hashes in ``padded`` rows."""
+    _INDEX_QUERY_ROWS.inc(actual, what="actual")
+    _INDEX_QUERY_ROWS.inc(padded, what="padded")
+
+
 def batch_files(route: str, n: int) -> None:
     """``n`` files of one pack batch sent down ``route``."""
     if route not in BATCH_ROUTES:
@@ -576,6 +615,11 @@ def baseline() -> Dict[str, Dict[str, float]]:
                           for r in BATCH_ROUTES}
     out["tree_scan"] = {w: _TREE_SCAN.value(what=w)
                         for w in TREE_SCAN_COUNTS}
+    out["pack_batches"] = {key: _PACK_BATCH_ITEMS.value(what=w)
+                           for w, key in PACK_BATCH_ITEMS.items()}
+    out["pack_batches"]["batches"] = _PACK_BATCHES.value()
+    out["index_query_rows"] = {w: _INDEX_QUERY_ROWS.value(what=w)
+                               for w in INDEX_QUERY_ROWS}
     out["send"] = {f"{k}_bytes": _SEND_BYTES.value(kind=k)
                    for k in SEND_BYTE_KINDS}
     out["send"]["dispatches"] = _SEND_DISPATCHES.value()
@@ -637,6 +681,9 @@ def report(base: Optional[dict] = None,
     batch: dict = _by_group(span_s, BATCH_GROUPS)
     batch["chunks"] = {k: int(v) for k, v in _delta("batch_chunks").items()}
     batch["files"] = {k: int(v) for k, v in _delta("batch_files").items()}
+    # ``batches``, ``dirs`` and ``batched_files`` (empty files too, which
+    # ``files`` sends down no route)
+    batch.update({k: int(v) for k, v in _delta("pack_batches").items()})
     pack = {"total_s": round(span_s.get("engine.pack", 0.0), 6),
             "steps": _by_group(span_s, PACK_STEPS),
             "scan": {k: int(v) for k, v in _delta("tree_scan").items()},
@@ -671,6 +718,8 @@ def report(base: Optional[dict] = None,
         "stream": stream,
         "batch": batch,
         "pack": pack,
+        "index": {"query_rows": {k: int(v) for k, v
+                                 in _delta("index_query_rows").items()}},
         "send": {k: int(v) for k, v in _delta("send").items()},
         "compile_s": compile_s,
         "compile_total_s": round(sum(compile_s.values()), 6),

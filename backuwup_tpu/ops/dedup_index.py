@@ -38,6 +38,8 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from .. import defaults
+from ..obs import profile as obs_profile
+from .blake3_tpu import _batch_bucket
 
 KEY_WORDS = 4  # 128-bit stored fingerprint of the 256-bit blake3 hash
 
@@ -207,9 +209,16 @@ class ShardedDedupIndex:
 
 
 def _pad_queries(queries: np.ndarray, d: int):
+    """``queries`` as the ``(d, rows, KEY_WORDS)`` slab the probe and
+    insert programs take, and their count.  Each device's share is
+    padded to a power-of-two bucket, as a digest batch's rows are, so a
+    backup compiles the programs for a handful of lengths and not for
+    every count of hashes it meets.  Padding rows are all-zero keys,
+    which probe nothing and occupy no slot."""
     queries = np.asarray(queries, dtype=np.uint32).reshape(-1, KEY_WORDS)
     n = queries.shape[0]
-    padded = max(d, -(-n // d) * d)
+    padded = d * _batch_bucket(-(-n // d))
+    obs_profile.index_query_rows(actual=n, padded=padded)
     q = np.zeros((padded, KEY_WORDS), dtype=np.uint32)
     q[:n] = queries
     return q.reshape(d, -1, KEY_WORDS), n

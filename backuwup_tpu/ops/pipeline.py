@@ -920,9 +920,14 @@ class DevicePipeline:
         if tiny:
             with obs_trace.span("batch.tiny_digest"):
                 digs = blake3_many_tpu([streams[i] for i in tiny])
-            tiny_bytes = sum(sizes[i] for i in tiny)
-            obs_profile.dispatch("digest", actual_bytes=tiny_bytes,
-                                 padded_bytes=tiny_bytes)
+            # a launch a leaf class, rows padded to a power of two: what
+            # ``bucketed_batches`` uploads
+            classes = Counter(_leaf_bucket(sizes[i]) for i in tiny)
+            obs_profile.dispatch(
+                "digest", count=len(classes),
+                actual_bytes=sum(sizes[i] for i in tiny),
+                padded_bytes=sum(_batch_bucket(n) * L * CHUNK_LEN
+                                 for L, n in classes.items()))
             for i, d in zip(tiny, digs):
                 out[i] = ([(0, sizes[i])],
                           np.frombuffer(d, dtype=np.uint8).reshape(1, 32))

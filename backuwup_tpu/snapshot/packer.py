@@ -6,8 +6,8 @@ Re-designs ``client/src/backup/filesystem/dir_packer.rs``:
   so every child tree hash exists before its parent is built.
 * Files are chunked + fingerprinted through a :class:`ChunkerBackend`
   (CPU oracle or the TPU kernels) — the batched analog of the reference's
-  per-file FastCDC/blake3 hot loop (``:246-311``).  All files of one
-  directory form one device batch.
+  per-file FastCDC/blake3 hot loop (``:246-311``).  The files of
+  consecutive directories form one device batch (:meth:`DirPacker.pack`).
 * Tree nodes (``Tree`` wire blobs) carry name, metadata, and child hashes;
   nodes with more than TREE_MAX_CHILDREN children split into a
   ``next_sibling`` chain (``dir_packer.rs:35,313-363``), built back-to-front
@@ -19,6 +19,8 @@ Re-designs ``client/src/backup/filesystem/dir_packer.rs``:
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import logging
 import os
 import time
@@ -33,6 +35,7 @@ from ..obs import profile as obs_profile
 from ..obs import trace as obs_trace
 from ..ops.backend import ChunkerBackend
 from ..ops.blake3_cpu import blake3_hash
+from ..ops.pipeline import _POOL_STREAM_STEP
 from ..wire import Blob, BlobKind, Tree, TreeKind, TreeMetadata
 from .blob_index import BlobIndex
 from .packfile import PackfileWriter
@@ -110,15 +113,21 @@ class TreeScan:
     #: order
     file_sizes: List[array]
 
-    def batches(self, batch_bytes: int) -> Iterator[List[int]]:
-        """The file lengths of the pack batches to come, a list a batch
-        (a directory's files up to ``batch_bytes``, as
-        ``DirPacker._pack_files`` cuts them; a larger file is streamed),
+    def batches(self, batch_bytes: int,
+                dispatch_bytes: int = _POOL_STREAM_STEP
+                ) -> Iterator[List[int]]:
+        """The file lengths of the pack batches to come, a list a batch,
+        cut as :meth:`DirPacker.pack` cuts them (its docstring has the
+        rule; a file over ``batch_bytes`` is streamed and in no batch),
         for ``ChunkerBackend.prepare_batches``.  A file that changes
         before it is read costs a compile in its batch, nothing else."""
-        for lengths in self.file_sizes:
-            sizes: List[int] = []
-            pending = 0
+        sizes: List[int] = []
+        pending = 0
+        for lengths in reversed(self.file_sizes):
+            if sizes and _closes_batch(pending, lengths, batch_bytes,
+                                       dispatch_bytes):
+                yield sizes
+                sizes, pending = [], 0
             for n in lengths:
                 if n > batch_bytes:
                     continue
@@ -127,8 +136,21 @@ class TreeScan:
                 if pending >= batch_bytes:
                     yield sizes
                     sizes, pending = [], 0
-            if sizes:
-                yield sizes
+        if sizes:
+            yield sizes
+
+
+def _closes_batch(pending: int, lengths, batch_bytes: int,
+                  dispatch_bytes: int) -> bool:
+    """Whether the open pack batch (``pending`` bytes of files) closes
+    before a directory whose files have these ``lengths``: where the
+    directory's batched files would take it past a device dispatch's
+    worth, and before a directory that streams a file (its blobs are
+    packed as they are met, so what was queued before it has to be
+    packed first)."""
+    batched = sum(n for n in lengths if n <= batch_bytes)
+    return pending + batched > dispatch_bytes \
+        or any(n > batch_bytes for n in lengths)
 
 
 def scan_tree(root: Path) -> TreeScan:
@@ -162,11 +184,26 @@ def scan_tree(root: Path) -> TreeScan:
     return TreeScan(dirs=dirs, total_bytes=total, file_sizes=file_sizes)
 
 
+@dataclass
+class _OpenDir:
+    """A directory between its listing and its tree node."""
+    path: str
+    #: its name in its parent's node ("" for the root)
+    name: str
+    #: its regular files in name order, each with its ``lstat``
+    files: List[Tuple[Path, os.stat_result]]
+    subdirs: List[str]
+    #: a file of ``files`` each: its tree hash (None while it is queued,
+    #: and for a file that vanished or failed to read)
+    hashes: List[Optional[bytes]]
+
+
 class DirPacker:
     def __init__(self, backend: ChunkerBackend, writer: PackfileWriter,
                  index: BlobIndex,
                  progress: Optional[Callable] = None,
                  batch_bytes: int = 256 * defaults.MiB,
+                 dispatch_bytes: int = _POOL_STREAM_STEP,
                  should_pause: Optional[Callable] = None,
                  dedup_batch: Optional[Callable] = None,
                  dedup_index=None,
@@ -176,6 +213,13 @@ class DirPacker:
         self.index = index
         self.progress = progress or (lambda **kw: None)
         self.batch_bytes = batch_bytes
+        self.dispatch_bytes = dispatch_bytes
+        # the open pack batch: (directory, index of one of its files, or
+        # None for the directory's end) in the order to pack them
+        self._queue: List[tuple] = []
+        self._queued_files = 0
+        self._queued_bytes = 0
+        self._dir_hash: dict = {}
         self.should_pause = should_pause or (lambda: None)
         # manifest hook: called (hash, size) for EVERY blob the snapshot
         # references — duplicates included — so the caller can record the
@@ -283,36 +327,71 @@ class DirPacker:
 
     # --- file chunking (the TPU-batched hot path) --------------------------
 
-    def _pack_files(self, files: List[Tuple[Path, os.stat_result]]
-                    ) -> List[Optional[bytes]]:
-        """Chunk+hash a directory's files, each with the ``lstat`` its
-        listing took; returns each file's tree hash (None for files that
-        vanished or failed to read)."""
-        hashes: List[Optional[bytes]] = [None] * len(files)
-        batch_idx: List[int] = []
+    def _queue_dir(self, odir: _OpenDir) -> None:
+        """Queue a listed directory on the open pack batch: its files,
+        and behind them its own end.  :meth:`pack` has the rule by which
+        a batch closes."""
+        if self._queued_files and _closes_batch(
+                self._queued_bytes, [st.st_size for _path, st in odir.files],
+                self.batch_bytes, self.dispatch_bytes):
+            self._flush_batch()
+        self._queue_files(odir)
+        self._queue.append((odir, None))
+        if not self._queued_files:
+            # no file is waiting for the device: the node is built now
+            self._flush_batch()
+
+    def _queue_files(self, odir: _OpenDir) -> None:
+        for i, (path, st) in enumerate(odir.files):
+            if st.st_size > self.batch_bytes:
+                # oversized file: stream it so memory stays bounded
+                try:
+                    odir.hashes[i] = self._pack_file_streaming(path, st)
+                except OSError:
+                    self.stats.failed_files += 1
+                continue
+            self._queue.append((odir, i))
+            self._queued_files += 1
+            self._queued_bytes += st.st_size
+            if self._queued_bytes >= self.batch_bytes:
+                self._flush_batch()
+
+    def _flush_batch(self) -> None:
+        """Pack what is queued, in the order it was queued: the files
+        read, chunked and hashed as one device batch, then each file's
+        blobs and tree node and, behind a directory's last file, the
+        directory's own node."""
+        queue, self._queue = self._queue, []
+        reads = obs_trace.span("batch.read") if self._queued_files \
+            else contextlib.nullcontext()
+        self._queued_files = self._queued_bytes = 0
+        # (directory, index of the file in it or None for its end, the
+        # file's bytes, its metadata); a file that cannot be read left out
+        events: List[tuple] = []
         batch_data: List[bytes] = []
-        batch_meta: List[TreeMetadata] = []
-
-        to_read: List[tuple] = []
-
-        def flush_batch():
-            if to_read:
-                with obs_trace.span("batch.read"):
-                    for i, path, st in to_read:
-                        try:
-                            data = path.read_bytes()
-                        except OSError:
-                            self.stats.failed_files += 1
-                            continue
-                        self.stats.bytes_read += len(data)
-                        batch_idx.append(i)
-                        batch_data.append(data)
-                        batch_meta.append(TreeMetadata(
-                            size=len(data), mtime_ns=st.st_mtime_ns,
-                            ctime_ns=st.st_ctime_ns))
-                to_read.clear()
-            if not batch_idx:
-                return
+        with reads:
+            for odir, i in queue:
+                if i is None:
+                    events.append((odir, None, None, None))
+                    continue
+                path, st = odir.files[i]
+                try:
+                    data = path.read_bytes()
+                except OSError:
+                    self.stats.failed_files += 1
+                    continue
+                self.stats.bytes_read += len(data)
+                batch_data.append(data)
+                events.append((odir, i, data, TreeMetadata(
+                    size=len(data), mtime_ns=st.st_mtime_ns,
+                    ctime_ns=st.st_ctime_ns)))
+        manifests: list = []
+        hints = iter(())
+        if batch_data:
+            obs_profile.pack_batch(
+                dirs=len({id(odir) for odir, i, _d, _m in events
+                          if i is not None}),
+                files=len(batch_data))
             t0 = time.monotonic()
             hint_list = None
             if self.dedup_index is not None:
@@ -342,7 +421,6 @@ class DirPacker:
                 # the device table or the host blob index answers it
                 obs_profile.dispatch("index", actual_bytes=32 * total_refs,
                                      padded_bytes=32 * total_refs)
-            hints = iter(())
             if hint_list is not None:
                 hints = iter(hint_list)
             elif self.dedup_batch is not None:
@@ -351,42 +429,59 @@ class DirPacker:
                 self._flush_device_sync()
                 hints = iter(self.dedup_batch(
                     [ref.hash for m in manifests for ref in m]))
+        self._emit(events, manifests, hints)
+        if manifests:
+            # the batch's nodes (files' and directories') reach the
+            # device table behind it, whatever their count: the index
+            # pads a batch of hashes to a bucket (``_pad_queries``)
+            self._flush_device_sync()
+            self._maybe_emit_partial()
+
+    def _emit(self, events: List[tuple], manifests: list,
+              hints: Iterator) -> None:
+        """Pack a batch's events in order: a file's new chunks and its
+        node (``manifests``: one a file, ``hints``: the device's verdict
+        a chunk), a directory's node at its end."""
+        of_file = iter(manifests)
+        for ends, run in itertools.groupby(events, key=lambda e: e[1] is None):
+            if ends:
+                for odir, _i, _data, _meta in run:
+                    self._close_dir(odir)
+                continue
             with obs_trace.span("batch.emit"):
-                for i, data, meta, manifest in zip(batch_idx, batch_data,
-                                                   batch_meta, manifests):
+                for odir, i, data, meta in run:
+                    path = odir.files[i][0]
+                    manifest = next(of_file)
                     for ref in manifest:
                         self.stats.chunks += 1
                         self._add_blob(
                             ref.hash, BlobKind.FILE_CHUNK,
                             data[ref.offset:ref.offset + ref.length],
                             dup_hint=next(hints, None))
-                    hashes[i] = self._tree_with_split(
-                        TreeKind.FILE, files[i][0].name, meta,
+                    odir.hashes[i] = self._tree_with_split(
+                        TreeKind.FILE, path.name, meta,
                         [ref.hash for ref in manifest])
                     self.stats.files += 1
-                    self.progress(file=str(files[i][0]), bytes=len(data))
-            self._flush_device_sync()
-            self._maybe_emit_partial()
-            batch_idx.clear()
-            batch_data.clear()
-            batch_meta.clear()
+                    self.progress(file=str(path), bytes=len(data))
 
-        pending = 0
-        for i, (path, st) in enumerate(files):
-            if st.st_size > self.batch_bytes:
-                # oversized file: stream it so memory stays bounded
-                try:
-                    hashes[i] = self._pack_file_streaming(path, st)
-                except OSError:
-                    self.stats.failed_files += 1
-                continue
-            to_read.append((i, path, st))
-            pending += st.st_size
-            if pending >= self.batch_bytes:
-                flush_batch()
-                pending = 0
-        flush_batch()
-        return hashes
+    def _close_dir(self, odir: _OpenDir) -> None:
+        """A directory's own node, once each of its files and
+        subdirectories has its hash."""
+        with obs_trace.span("pack.dir_tree"):
+            children = [h for h in odir.hashes if h is not None]
+            children.extend(self._dir_hash[s] for s in odir.subdirs
+                            if s in self._dir_hash)
+            try:
+                st = os.stat(odir.path)
+                meta = TreeMetadata(size=0, mtime_ns=st.st_mtime_ns,
+                                    ctime_ns=st.st_ctime_ns)
+            except OSError:
+                # directory vanished mid-walk: keep its children
+                meta = TreeMetadata()
+            self._dir_hash[odir.path] = self._tree_with_split(
+                TreeKind.DIR, odir.name, meta, children)
+            self.stats.dirs += 1
+            self._maybe_emit_partial()
 
     @obs_trace.traced("stream.file")
     def _pack_file_streaming(self, path: Path, st: os.stat_result) -> bytes:
@@ -473,7 +568,22 @@ class DirPacker:
     def pack(self, root: Path, scan: Optional[TreeScan] = None) -> bytes:
         """Pack ``root`` recursively; returns the snapshot id (root hash).
         ``scan``: :func:`scan_tree` of this ``root`` where the caller
-        has made it (the engine's estimate)."""
+        has made it (the engine's estimate).
+
+        A pack batch (one ``batch.read``, one ``manifest_many_classified``,
+        one emit) spans directories: the files of consecutive directories
+        join the open batch, each directory's end queued behind its last
+        file, and what is queued is packed in that order, so the blobs
+        and the snapshot are what a batch a directory gave.  The batch
+        closes at ``batch_bytes`` (inside a directory too: the bound on
+        what the packer holds), and at a directory boundary where the
+        next directory would take it past ``dispatch_bytes``, a device
+        dispatch's worth (``ops/pipeline.py``'s step for a stream's
+        padded length): a directory of that size or more is a batch of
+        its own and its blobs reach the writer as soon as it is read,
+        and a tree of thousands of small directories goes to the device
+        in batches of about that size, not a handful of files at a
+        time.  :meth:`TreeScan.batches` cuts the same."""
         root = Path(root)
         if not root.is_dir():
             raise NotADirectoryError(str(root))
@@ -484,35 +594,25 @@ class DirPacker:
             raise ValueError(f"scan of {scan.dirs[0]}, not of {root}")
         if self.dedup_index is not None:
             with obs_trace.span("pack.prepare"):
-                self.backend.prepare_batches(scan.batches(self.batch_bytes),
-                                             self.dedup_index)
+                self.backend.prepare_batches(
+                    scan.batches(self.batch_bytes, self.dispatch_bytes),
+                    self.dedup_index)
         # the directories were found breadth-first: packed deepest-first,
         # children always hash before parents (dir_packer.rs:89-132)
-        dir_hash: dict = {}
+        self._dir_hash = {}
         for d in reversed(scan.dirs):
             with obs_trace.span("pack.walk"):
                 listed, subdirs, _links, failed = _list_dir(d)
                 self.stats.failed_files += failed
                 files = [(Path(entry.path), st) for entry, st in listed]
-            children = [h for h in self._pack_files(files) if h is not None]
-            with obs_trace.span("pack.dir_tree"):
-                children.extend(dir_hash[s] for s in subdirs if s in dir_hash)
-                try:
-                    st = os.stat(d)
-                    meta = TreeMetadata(size=0, mtime_ns=st.st_mtime_ns,
-                                        ctime_ns=st.st_ctime_ns)
-                except OSError:
-                    # directory vanished mid-walk: keep its children
-                    meta = TreeMetadata()
-                name = "" if d == scan.dirs[0] else os.path.basename(d)
-                dir_hash[d] = self._tree_with_split(TreeKind.DIR, name, meta,
-                                                    children)
-                self.stats.dirs += 1
-                self._maybe_emit_partial()
+            name = "" if d == scan.dirs[0] else os.path.basename(d)
+            self._queue_dir(_OpenDir(d, name, files, subdirs,
+                                     [None] * len(files)))
+        self._flush_batch()
         self._flush_device_sync()
         # the wait for every seal and write in flight, and for what the
         # writer thread does after each (``on_packfile``: the index, the
         # seal-time table): none of it is the writer's ``stall``
         with obs_trace.span("pack.flush"):
             self.writer.flush()
-        return dir_hash[scan.dirs[0]]
+        return self._dir_hash[scan.dirs[0]]

@@ -14,6 +14,7 @@ import threading
 import time
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 from backuwup_tpu import defaults
@@ -28,6 +29,7 @@ from backuwup_tpu.ops.gear import CDCParams
 from backuwup_tpu.snapshot.blob_index import BlobIndex
 from backuwup_tpu.snapshot.packer import DirPacker
 from backuwup_tpu.snapshot.packfile import PackfileWriter
+from benchmark.generators import source_tree
 
 pytestmark = pytest.mark.dataflow
 
@@ -52,7 +54,20 @@ class HostAnswers:
         return [self.index.is_duplicate(h) for h in hashes]
 
 
+# ISSUE 41's tree at a small size: 150 files in 16 directories under
+# three top-level ones (the root holds none), pack batches that span them
+SOURCE_TREE = {"files": 150, "directories": 16, "top_level": 3,
+               "max_depth": 4, "dir_files_median": 7, "dir_files_sigma": 1.2,
+               "dir_files_min": 1, "dir_files_max": 600,
+               "size_median_bytes": 6144, "size_sigma": 1.45,
+               "size_min_bytes": 64, "size_max_bytes": 4194304,
+               "size_mean_bytes": 17408}
+
+
 def _tree(root: Path, route: str) -> None:
+    if route == "source_tree":
+        source_tree.build(root, SOURCE_TREE, np.random.default_rng([40, 0]))
+        return
     rng = random.Random(38)
     (root / "docs").mkdir(parents=True)
     if route == "streaming":  # one file over the packer's batch_bytes
@@ -169,12 +184,12 @@ def _delay_the_device_sync(monkeypatch) -> None:
     monkeypatch.setattr(DirPacker, "_flush_device_sync", slow)
 
 
-@pytest.mark.parametrize("route", ["batched", "streaming"])
+@pytest.mark.parametrize("route", ["batched", "streaming", "source_tree"])
 def test_the_phases_close_the_wall_and_the_steps_the_pack_thread(
         tmp_path, monkeypatch, route):
     # the two closures are clocks against clocks.  Between the pack
     # thread's steps lies list building only (a directory's files come
-    # from ``pack.walk`` with their ``lstat``; ``_pack_files``' loop over
+    # from ``pack.walk`` with their ``lstat``; the batch's queue of
     # them asks the file system nothing), 0.1-0.3 % of ``engine.pack``
     # on an idle machine, so the steps close at 2 %; on a machine whose
     # cores are all taken a thread that lets go of the interpreter lock
@@ -200,13 +215,19 @@ def test_the_phases_close_the_wall_and_the_steps_the_pack_thread(
     assert pack["total_s"] <= wall["phases"]["pack"]
     # the tree was looked at twice: the estimate's scan, the pack's listing
     scan = pack["scan"]
-    assert scan["files"] > 0 and scan["dirs"] == 2
+    assert scan["files"] > 0 and scan["dirs"] == (
+        SOURCE_TREE["directories"] + 1 if route == "source_tree" else 2)
     assert scan["lstat_calls"] == 2 * scan["files"]
     assert scan["scandir_calls"] == 2 * scan["dirs"]
     # each route's own steps, and none of the other's
     mine, other = (("stream",), ("read", "manifest", "emit")) \
         if route == "streaming" else (("read", "manifest", "emit"),
                                       ("stream",))
+    if route == "source_tree":
+        # one batch for the 16 directories, and its counters in the report
+        assert rep["batch"]["batches"] == 1
+        assert rep["batch"]["dirs"] == SOURCE_TREE["directories"]
+        assert rep["batch"]["batched_files"] == SOURCE_TREE["files"]
     assert all(pack["steps"][s] > 0 for s in mine + ("walk", "dir_tree",
                                                      "device_sync", "flush"))
     assert all(pack["steps"][s] == 0 for s in other)

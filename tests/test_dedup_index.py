@@ -6,6 +6,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from backuwup_tpu.obs import metrics as obs_metrics
 from backuwup_tpu.ops.blake3_cpu import blake3_hash
 from backuwup_tpu.ops.dedup_index import (
     KEY_WORDS,
@@ -170,3 +171,129 @@ def test_queries_from_cvs_matches_host_path():
     q_host = hashes_to_queries(digests)
     assert np.array_equal(q_dev, q_host)
     assert (q_dev[4] == 0).all() and (q_dev[11] == 0).all()
+
+
+# --- batch lengths come in buckets (ISSUE 41) --------------------------------
+# ``_pad_queries`` pads each device's share of a batch to a power of two
+# of at least 8 rows.  The answers and the table are those of a plain
+# host set of the same keys whatever the length, and a length inside a
+# bucket already met compiles nothing.
+
+LENGTHS = [1, 7, 8, 9, 1000, 1025]
+MESH_SIZES = [1, 8]
+
+
+def _mesh_of(size):
+    return jax.sharding.Mesh(np.array(jax.devices()[:size]), ("data",))
+
+
+def _rows(what):
+    return obs_metrics.registry().get(
+        "bkw_index_query_rows_total").value(what=what)
+
+
+def _bucket(n, d):
+    b = 8
+    while b < -(-n // d):
+        b *= 2
+    return d * b
+
+
+def _mixed_batch(n, known):
+    """``n`` distinct hashes, about a third of them from ``known``."""
+    fresh = iter(_hashes(n, seed=f"fresh{n}"))
+    old = iter(known)
+    return [next(old) if i % 3 == 0 and i // 3 < len(known) else next(fresh)
+            for i in range(n)]
+
+
+def _dump_set(idx):
+    keys, values = idx.dump()
+    return {k.astype("<u4").tobytes(): int(v) for k, v in zip(keys, values)}
+
+
+@pytest.mark.parametrize("n", LENGTHS)
+@pytest.mark.parametrize("size", MESH_SIZES)
+def test_a_batch_of_any_length_answers_as_a_host_set(size, n):
+    idx = ShardedDedupIndex.create(_mesh_of(size), capacity=4096)
+    known = _hashes(300, seed="known")
+    idx.insert(hashes_to_queries(known), np.full(300, 7, dtype=np.uint32))
+    host = {h[:16]: 7 for h in known}
+    batch = _mixed_batch(n, known)
+    q = hashes_to_queries(batch)
+    want = np.array([host.get(h[:16], -1) + 1 for h in batch],
+                    dtype=np.uint32)
+    sent, padded = _rows("actual"), _rows("padded")
+    assert np.array_equal(idx.probe(q), want)
+    # the same found-vector from the insert, and nothing lost; a second
+    # attempt of the same rows finds every one and stores nothing
+    found, lost = idx._insert_once(q, np.full(n, 9, dtype=np.uint32))
+    assert found.shape == lost.shape == (n,)
+    assert np.array_equal(found, want) and not lost.any()
+    assert np.array_equal(idx.insert(q, np.full(n, 5, dtype=np.uint32)),
+                          np.where(want > 0, want, 10))
+    for h in batch:
+        host.setdefault(h[:16], 9)
+    # a padding row never occupies a slot: exactly the live keys
+    assert _dump_set(idx) == host
+    assert np.array_equal(idx.probe(q),
+                          np.array([host[h[:16]] + 1 for h in batch]))
+    # counter (b): four batches of n rows, each padded to its bucket
+    assert _rows("actual") - sent == 4 * n
+    assert _rows("padded") - padded == 4 * _bucket(n, size)
+
+
+@pytest.mark.parametrize("n", LENGTHS)
+@pytest.mark.parametrize("size", MESH_SIZES)
+def test_classify_insert_of_any_length_answers_as_the_host_index(
+        size, n, tmp_path):
+    from backuwup_tpu.crypto import KeyManager
+    from backuwup_tpu.snapshot.blob_index import BlobIndex
+    from backuwup_tpu.snapshot.device_dedup import MeshDedupIndex
+    host = BlobIndex(KeyManager.from_secret(b"\x07" * 32), tmp_path / "i")
+    known = _hashes(300, seed="known")
+    for h in known:
+        host.mark_queued(h)
+    dev = MeshDedupIndex(_mesh_of(size), host, capacity=4096)
+    batch = _mixed_batch(n, known)
+    # one repeat inside the batch where it has room: "duplicate"
+    asked = batch + batch[:1] if n > 1 else batch
+    flags = dev.classify_insert(asked)
+    assert flags == [host.is_duplicate(h) for h in batch] \
+        + ([True] if n > 1 else [])
+    assert all(dev.classify_insert(batch))
+    assert set(_dump_set(dev.sharded)) \
+        == {h[:16] for h in known} | {h[:16] for h in batch}
+
+
+def _compiles():
+    """Backend compiles of the two programs so far, by the hook a
+    ``TpuBackend`` installs (``bkw_jit_compile_seconds``'s count)."""
+    fam = obs_metrics.registry().get("bkw_jit_compile_seconds")
+    return [sum(s["count"] for s in fam._snapshot_series()
+                if s["labels"]["fun"] == fun)
+            for fun in ("dedup_insert", "dedup_probe")]
+
+
+@pytest.mark.parametrize("size", MESH_SIZES)
+def test_a_length_inside_a_bucket_already_met_compiles_nothing(size):
+    from backuwup_tpu.ops.backend import _install_jax_hooks
+    _install_jax_hooks()
+    # a capacity no other test uses: programs of this test's own
+    idx = ShardedDedupIndex.create(_mesh_of(size),
+                                   capacity=65536 + 64 * size)
+    start = _compiles()
+
+    def ask(n):
+        q = hashes_to_queries(_hashes(n, seed=f"len{n}"))
+        idx.insert(q, np.ones(n, dtype=np.uint32))
+        assert (idx.probe(q) == 2).all()
+        return [now - was for now, was in zip(_compiles(), start)]
+
+    assert ask(1000) == [1, 1]
+    assert ask(1025) == [2, 2]
+    lo, mid, hi = (_bucket(1000, size) // 2 + 1, _bucket(1000, size),
+                   _bucket(1025, size))
+    for n in (lo, lo + 199, mid - 1, mid, mid + 2, mid + 475, hi - 1, hi):
+        assert ask(n) == [2, 2], n
+    assert ask(hi + 1) == [3, 3]

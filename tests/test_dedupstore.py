@@ -249,6 +249,40 @@ def test_budget_is_hard_cap_with_demotion(mesh, host_index, tmp_path):
     assert not any(ti.classify_insert(_hashes(200, seed=42)))
 
 
+@pytest.mark.parametrize("n", [1, 7, 8, 9, 1000, 1025])
+@pytest.mark.parametrize("size", [1, 8])
+def test_a_batch_of_any_length_classifies_as_the_host_index(
+        size, n, host_index, tmp_path):
+    """The hot table takes a batch in a power-of-two bucket of rows
+    (``ops/dedup_index._pad_queries``): the verdicts are the host
+    index's and the table holds the batch's keys and no padding row,
+    whatever the length."""
+    ti = TieredDedupIndex(Mesh(np.array(jax.devices()[:size]), ("data",)),
+                          host_index, cold_dir=tmp_path / "cold")
+    known = _hashes(300, seed=60)
+    assert not any(ti.classify_insert(known))
+    for h in known:
+        host_index.mark_queued(h)
+    fresh = _hashes(n, seed=61 + n)
+    batch = [known[i] if i % 3 == 0 and i < len(known) else fresh[i]
+             for i in range(n)]
+    rows0 = (_metric("bkw_index_query_rows_total", what="actual"),
+             _metric("bkw_index_query_rows_total", what="padded"))
+    assert ti.classify_insert(batch) \
+        == [host_index.is_duplicate(h) for h in batch]
+    assert all(ti.classify_insert(batch))
+    keys, _values = ti.sharded.dump()
+    assert {k.astype("<u4").tobytes() for k in keys} \
+        == {h[:16] for h in known + batch}
+    bucket = 8
+    while bucket < -(-n // size):
+        bucket *= 2
+    assert _metric("bkw_index_query_rows_total", what="actual") \
+        - rows0[0] == 2 * n
+    assert _metric("bkw_index_query_rows_total", what="padded") \
+        - rows0[1] == 2 * size * bucket
+
+
 def test_promotion_clock_repins_hot_cold_keys(mesh, host_index, tmp_path):
     budget = 8 * 64 * 20
     ti = TieredDedupIndex(mesh, host_index, cold_dir=tmp_path / "cold",

@@ -73,11 +73,15 @@ def test_dispatch_counts_manifest_many_exact(rng):
         [len(s) for s in streams]
 
 
-def test_dispatch_counts_packer_hand_count(tmp_path, rng):
-    """Hand count for a DirPacker tree: the packer batches per
-    directory (one flush per dir with files, everything far below
-    batch_bytes), so with d0=3 files, d1=2 files, root=1 file:
-    scan=select=gather=6, digest=3 (one per batch), index=3."""
+@pytest.mark.parametrize("dispatch_bytes, batches", [(0, 3), (1 << 20, 1)],
+                         ids=["a_directory_a_batch", "one_batch"])
+def test_dispatch_counts_packer_hand_count(tmp_path, rng, dispatch_bytes,
+                                           batches):
+    """Hand count for a DirPacker tree, everything far below
+    batch_bytes, with d0=3 files, d1=2 files, root=1 file:
+    scan=select=gather=6, and digest and index one per pack batch: a
+    batch a directory with files where none may span directories, one
+    where the three are under a dispatch's worth together."""
     src = tmp_path / "src"
     (src / "d0").mkdir(parents=True)
     (src / "d1").mkdir()
@@ -92,7 +96,8 @@ def test_dispatch_counts_packer_hand_count(tmp_path, rng):
         KEYS, tmp_path / "pack",
         on_packfile=lambda pid, path, hashes, size:
             index.finalize_packfile(pid, hashes))
-    packer = DirPacker(CpuBackend(SMALL), writer, index)
+    packer = DirPacker(CpuBackend(SMALL), writer, index,
+                       dispatch_bytes=dispatch_bytes)
 
     base = profile.baseline()
     snapshot = packer.pack(src)
@@ -101,7 +106,10 @@ def test_dispatch_counts_packer_hand_count(tmp_path, rng):
     assert len(snapshot) == 32
     assert packer.stats.files == 6
     assert rep["dispatches"] == {
-        "scan": 6, "select": 6, "gather": 6, "digest": 3, "index": 3}
+        "scan": 6, "select": 6, "gather": 6, "digest": batches,
+        "index": batches}
+    assert rep["batch"]["batches"] == batches
+    assert rep["batch"]["dirs"] == 3 and rep["batch"]["batched_files"] == 6
     total = sum(layout.values())
     assert rep["bytes"]["scan"] == total
     assert rep["bytes"]["digest"] == total
@@ -183,10 +191,10 @@ def test_backup_e2e_perf_plane_acceptance(tmp_path, isolated):
 
     # 1) dispatch counts: the harness corpus is 6 small files split
     # d0/d1, so the packer hand count is scan=select=gather=6,
-    # digest=2 (one per directory batch), index=2
+    # digest=1, index=1 (the two directories are one pack batch)
     rep = profile.report(base)
     assert rep["dispatches"] == {
-        "scan": 6, "select": 6, "gather": 6, "digest": 2, "index": 2}
+        "scan": 6, "select": 6, "gather": 6, "digest": 1, "index": 1}
     assert all(rep["bytes"][s] > 0 for s in profile.STAGES)
 
     # 2) the backup journaled its pipeline report, matching the deltas

@@ -28,7 +28,7 @@ from backuwup_tpu.obs import profile as obs_profile
 from backuwup_tpu.ops.backend import CpuBackend, NativeBackend
 from backuwup_tpu.ops.gear import CDCParams
 from backuwup_tpu.snapshot.blob_index import BlobIndex
-from backuwup_tpu.snapshot.packer import DirPacker, scan_tree
+from backuwup_tpu.snapshot.packer import DirPacker, _OpenDir, scan_tree
 from backuwup_tpu.snapshot.packfile import PackfileWriter
 from backuwup_tpu.wire import TreeKind, TreeMetadata
 
@@ -66,8 +66,11 @@ def oracle_dirs(root: Path) -> list:
 
 
 def oracle_batch_sizes(dirs: list, batch_bytes: int):
-    """``DirPacker._batch_sizes`` as it was."""
-    for d in dirs:
+    """``DirPacker._batch_sizes`` as it was: a batch a directory, here
+    in the order the directories are packed (since ISSUE 41 a batch
+    spans directories up to ``dispatch_bytes``: 0 in ``_packer``, so a
+    directory a batch still)."""
+    for d in reversed(dirs):
         sizes = []
         pending = 0
         try:
@@ -92,9 +95,18 @@ def oracle_batch_sizes(dirs: list, batch_bytes: int):
             yield sizes
 
 
+def _pack_files(packer: DirPacker, pairs: list) -> list:
+    """A directory's files as one batch of their own, as ``_pack_files``
+    packed them: their tree hashes."""
+    odir = _OpenDir("", "", pairs, [], [None] * len(pairs))
+    packer._queue_files(odir)
+    packer._flush_batch()
+    return odir.hashes
+
+
 def oracle_pack(packer: DirPacker, root: Path) -> bytes:
     """``DirPacker.pack`` as it was, down to ``_pack_files``' ``lstat``
-    loop, which it hands on as the pairs ``_pack_files`` takes now."""
+    loop, which it hands on as the pairs the batch takes now."""
     order = oracle_dirs(root)
     dir_hash = {}
     for d in reversed(order):
@@ -110,7 +122,7 @@ def oracle_pack(packer: DirPacker, root: Path) -> bytes:
                 pairs.append((p, p.lstat()))
             except OSError:
                 packer.stats.failed_files += 1
-        children = [h for h in packer._pack_files(pairs) if h is not None]
+        children = [h for h in _pack_files(packer, pairs) if h is not None]
         children.extend(dir_hash[s] for s in subdirs if s in dir_hash)
         try:
             st = d.stat()
@@ -217,7 +229,7 @@ def _packer(base: Path):
             index.finalize_packfile(pid, hashes))
     blobs = []
     packer = DirPacker(Recording(), writer, index, batch_bytes=BATCH,
-                       dedup_index=HostAnswers(index),
+                       dispatch_bytes=0, dedup_index=HostAnswers(index),
                        on_blob=lambda h, n: blobs.append((h, n)))
     return packer, blobs
 
@@ -321,13 +333,13 @@ def test_a_file_that_vanishes_after_its_listing_is_counted_failed(
     src = tmp_path / "src"
     src.mkdir()
     _write(src, {"keep": 4000, "lost": 3 * BATCH if big else 4000})
-    inner = DirPacker._pack_files
+    inner = DirPacker._queue_dir
 
-    def vanish_first(self, files):
+    def vanish_first(self, odir):
         (src / "lost").unlink(missing_ok=True)
-        return inner(self, files)
+        return inner(self, odir)
 
-    monkeypatch.setattr(DirPacker, "_pack_files", vanish_first)
+    monkeypatch.setattr(DirPacker, "_queue_dir", vanish_first)
     packer, blobs = _packer(tmp_path / "new")
     root = packer.pack(src)
     assert packer.stats.failed_files == 1 and packer.stats.files == 1
@@ -352,12 +364,16 @@ def test_the_scan_holds_integers_and_directories_only(tmp_path):
     assert scan.dirs == [str(tmp_path), str(tmp_path / "d")]
     assert [a.typecode for a in scan.file_sizes] == ["q", "q"]
     assert scan.total_bytes == sum(map(sum, scan.file_sizes))
-    # a larger file is left out of the batches (it is streamed), a
-    # directory's batch is cut where it reaches batch_bytes
+    # a larger file is left out of the batches (it is streamed) and the
+    # batch open before its directory is closed; a batch is cut where it
+    # reaches batch_bytes; ``d`` is packed before the root
     assert list(scan.batches(BATCH)) == [
-        [500], [BATCH // 2, BATCH // 2], [999]]
+        [BATCH // 2, BATCH // 2], [999], [500]]
+    # nothing streams and both directories are under a dispatch's worth
     assert list(scan.batches(1 << 30)) == [
-        [3 * BATCH + 17, 500], [BATCH + 1, BATCH // 2, BATCH // 2, 999]]
+        [BATCH + 1, BATCH // 2, BATCH // 2, 999, 3 * BATCH + 17, 500]]
+    assert list(scan.batches(1 << 30, dispatch_bytes=0)) == [
+        [BATCH + 1, BATCH // 2, BATCH // 2, 999], [3 * BATCH + 17, 500]]
 
 
 # --- the calls, counted ----------------------------------------------------
