@@ -114,6 +114,39 @@ def _leaf_scan_xla_flat(words_flat: jnp.ndarray, nb: jnp.ndarray,
     return jnp.stack(cv, axis=1), jnp.stack(cv_pre, axis=1)
 
 
+def _leaf_rows(flat: jnp.ndarray, off: jnp.ndarray) -> jnp.ndarray:
+    """The pool's gather: ``(lanes, 1024) u8``, lane ``j`` holding
+    ``flat[off[j] : off[j] + 1024]``.
+
+    A lane's 1 KiB at an arbitrary byte offset lies in rows ``q`` and
+    ``q + 1`` of the stream seen as whole 1 KiB rows, ``s = off % 1024``
+    bytes in.  Whole rows are what the v5e gathers fast, so: two row
+    gathers, then the shift by ``s`` as ten conditional shifts by a power
+    of two along the row, the row pair narrowing by what each stage can
+    no longer need.  Asked for as ``lanes`` byte slices at byte offsets
+    (``vmap(dynamic_slice)``) the v5e runs a loop of
+    ``lanes`` steps, 1.8 us each: 0.300 s at the long file's 164,864
+    lanes where this form takes 0.029 s (chip run, PR 42; the other
+    forms tried are in ``scripts/probe_pool_gather.py``).
+
+    Slack: the row view is cut at a whole row, so ``flat`` must reach at
+    least ``CHUNK_LEN`` bytes past the last chunk's end for the cut to
+    keep every row that holds a chunk's byte.  The index of row ``q + 1``
+    is held inside the view: where that changes it, ``q`` is the view's
+    last row, the lane's bytes end in it, and the caller's mask drops
+    what the stand-in row put behind them.
+    """
+    n_rows = flat.shape[0] // CHUNK_LEN
+    rows = flat[:n_rows * CHUNK_LEN].reshape(n_rows, CHUNK_LEN)
+    q = off // CHUNK_LEN
+    s = (off % CHUNK_LEN)[:, None]
+    x = jnp.concatenate(
+        [rows[q], rows[jnp.minimum(q + 1, n_rows - 1)]], axis=1)
+    for bit in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
+        x = jnp.where((s & bit) != 0, x[:, bit:], x[:, :x.shape[1] - bit])
+    return x[:, :CHUNK_LEN]
+
+
 @functools.lru_cache(maxsize=32)
 def tier_spans(max_leaves: int, n_tiers: int = 3) -> Tuple[int, ...]:
     """Geometric leaf-count tier grid ending at ``max_leaves``.
@@ -146,7 +179,8 @@ def pool_digest(flat: jnp.ndarray, offs: jnp.ndarray, lens: jnp.ndarray, *,
     """Digest ``C`` chunks carved from one resident byte pool.
 
     ``flat``: (N,) u8 with >= CHUNK_LEN slack bytes after the last chunk
-    (fixed-span gathers must never clamp); ``offs``/``lens``: (C,) i32
+    (the gather reads the stream as whole 1 KiB rows, cut at a whole row:
+    :func:`_leaf_rows`); ``offs``/``lens``: (C,) i32
     absolute byte offsets / lengths (len <= 0 marks an unused slot).
     ``tiers``: ((leaf_span, chunk_capacity), ...) ascending by span; the
     last span must be >= the largest possible leaf count.
@@ -180,10 +214,7 @@ def pool_digest(flat: jnp.ndarray, offs: jnp.ndarray, lens: jnp.ndarray, *,
     # --- one gather + one word-prep + ONE leaf scan ------------------------
     off = jnp.where(active, offs[oc] + k * CHUNK_LEN, 0)
 
-    def one(o):
-        return jax.lax.dynamic_slice(flat, (o,), (CHUNK_LEN,))
-
-    data = jax.vmap(one)(off)  # (leaf_cap, 1024)
+    data = _leaf_rows(flat, off)  # (leaf_cap, 1024)
     data = jnp.where(
         jnp.arange(CHUNK_LEN, dtype=jnp.int32)[None, :] < nbytes[:, None],
         data, jnp.uint8(0))

@@ -20,13 +20,23 @@ the relayout ahead of the scan kernel is now held to planning no gather.
 Nor the RS product: ``MUL_TABLE[mat, shard]`` compiled and fitted for
 seven PRs and was a gather of 82-97 ms a 3 MiB packfile on the chip, the
 largest program of two cells' traces (PR 37), so ``rs_gf_matmul`` is held
-to planning none either.
+to planning none either.  Nor the leaf pool's gather: ``leaf_cap`` byte
+slices at byte offsets (``vmap(dynamic_slice)``) compiled for every PR
+since the pool came, to a ``while`` of ``leaf_cap`` steps that took
+1.8-3.5 us each on the chip, 0.52 s of the 0.76 s the device worked in a
+``ref-1m.incr`` backup (PR 42), so ``pool_digest`` is held to planning no
+loop over its lanes.  What that probe also taught: a gather is not slow
+for being over ``u8``, it is slow for taking less than a row; two
+gathers of whole 1 KiB ``u8`` rows are 7.5 ms at 164,864 lanes, and they
+are what ``pool_digest`` now plans.
 
 Only one process may load libtpu, and it keeps it until it exits, so
 the topology is described inside a module-scoped fixture — never at
 import, in a ``skipif`` or in ``parametrize`` — and everything built
 from it is built in fixtures or tests of this one file.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -101,6 +111,13 @@ def _temp_bytes(lowered) -> int:
     return lowered.compile().memory_analysis().temp_size_in_bytes
 
 
+def _planned_bytes(compiled) -> int:
+    """Arguments, outputs and temporaries of a compiled program."""
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+
+
 def _lower_scan(variant, rows, width, one_chip):
     fn = {"v1": scan_fused._fused_candidate_words_v1,
           "v2": scan_fused._fused_candidate_words_u32}[variant]
@@ -130,10 +147,7 @@ def test_scan_relayout_plans_no_gather_and_fits(one_chip, rows, width):
     compiled = _lower_scan("v2", rows, width, one_chip).compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and " gather(" not in hlo
-    mem = compiled.memory_analysis()
-    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-               + mem.temp_size_in_bytes)
-    assert planned < 4 * GiB
+    assert _planned_bytes(compiled) < 4 * GiB
 
 
 def test_leaf_digest_kernel_compiles_at_dispatch_width(one_chip):
@@ -158,6 +172,48 @@ def test_pool_digest_compiles_at_dispatch_width(one_chip):
         leaf_cap=leaf_capacity(rows * width, chunks),
         tiers=tier_plan(PARAMS, rows * width, rows), pallas=True)
     assert _temp_bytes(lowered) < 2 * GiB
+
+
+def _loop_bounds(hlo: str) -> set:
+    """The constants that the conditions of the text's ``while`` loops
+    compare their counters with: a loop of ``n`` steps shows ``n``."""
+    bounds = set()
+    for name in set(re.findall(r"condition=%([\w.\-]+)", hlo)):
+        cond = re.search(r"^%" + re.escape(name) + r" \(.*?^\}", hlo,
+                         re.M | re.S).group(0)
+        bounds.update(int(c) for c in re.findall(r"constant\((\d+)\)", cond))
+    return bounds
+
+
+@pytest.mark.parametrize("rows,width,halo", [(1, 160 << 20, 0),
+                                             (*CELL, _HALO)],
+                         ids=["stream-160m", "cell"])
+def test_pool_gather_plans_no_lane_loop_and_fits(one_chip, rows, width, halo):
+    """``pool_digest`` at the two shapes ``ref-1m.incr`` runs: the long
+    file's 160 MiB stream (``leaf_cap`` 164,864) and the ``(1, 64 MiB)``
+    row inside the manifest program (66,048).  No ``while`` makes
+    ``leaf_cap`` steps (the parent's gather did: 1.8-3.5 us a step on the
+    chip), every gather over ``u8`` takes whole 1 KiB rows (a narrower
+    one is the byte-at-a-time kind), and arguments, outputs and
+    temporaries together stay under 2 GiB."""
+    chunks = rows * _caps(width)[2]
+    leaf_cap = leaf_capacity(rows * width, chunks)
+    assert leaf_cap == {160 << 20: 164_864, 64 << 20: 66_048}[width]
+    compiled = pool_digest.lower(
+        jax.ShapeDtypeStruct((rows * (halo + width) + 1024,), jnp.uint8,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((chunks,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((chunks,), jnp.int32, sharding=one_chip),
+        leaf_cap=leaf_cap, tiers=tier_plan(PARAMS, rows * width, rows),
+        pallas=True).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo  # the leaf scan is in the program
+    assert leaf_cap not in _loop_bounds(hlo)
+    u8_gathers = [line for line in hlo.splitlines()
+                  if re.search(r"= u8\[[^=]* gather\(", line)]
+    assert u8_gathers and all(
+        "slice_sizes={1,1024}" in line for line in u8_gathers)
+    assert _planned_bytes(compiled) < 2 * GiB
 
 
 @pytest.mark.parametrize("n_dev", [1, 4])

@@ -10,7 +10,9 @@ implementation is the correctness bar for both designs.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from backuwup_tpu.ops import cdc_cpu
 from backuwup_tpu.ops.blake3_cpu import Blake3Numpy, blake3_hash
@@ -25,6 +27,7 @@ from backuwup_tpu.ops.digest_pool import (
 from backuwup_tpu.ops.gear import CDCParams
 from backuwup_tpu.ops.manifest_device import (
     scan_digest_batch_pool,
+    scan_digest_batch_pool_mesh,
     tier_plan,
 )
 from backuwup_tpu.ops.pipeline import DevicePipeline
@@ -52,10 +55,14 @@ def _run_pool(flat, offs, lens, C, tiers=None, leaf_cap=None, **kw):
     return np.asarray(acc), int(np.asarray(ovf)[0])
 
 
-@pytest.mark.parametrize("pallas_kw", [
+# the pool with the XLA leaf scan, and with the Pallas leaf kernel interpreted
+FORMS = pytest.mark.parametrize("pallas_kw", [
     {"pallas": False},
     {"pallas": True, "interpret": True},
 ], ids=["xla", "pallas-interpret"])
+
+
+@FORMS
 def test_pool_digest_matches_oracle(pallas_kw):
     rng = np.random.default_rng(5)
     flat = rng.integers(0, 256, 512 * 1024, dtype=np.uint8)
@@ -90,6 +97,135 @@ def test_pool_digest_overlapping_and_shuffled_spans():
     got = _digests_of(acc)
     for i, (o, l) in enumerate(spans):
         assert got[i] == blake3_hash(flat[o:o + l].tobytes())
+
+
+# --- the gather (PR 42): rows q and q + 1 of the stream's 1 KiB rows, and
+# the shift by s = offset % 1024, through the whole pool -----------------
+
+def _assert_oracle(flat, spans, C, **kw):
+    acc, ovf = _run_pool(flat, [o for o, _ in spans], [l for _, l in spans],
+                         C=C, **kw)
+    assert ovf == 0
+    got = _digests_of(acc)
+    for i, (o, l) in enumerate(spans):
+        if l > 0:
+            assert got[i] == blake3_hash(flat[o:o + l].tobytes()), (o, l)
+        else:
+            assert not acc[i].any()
+    return acc
+
+
+@FORMS
+@pytest.mark.parametrize("s", [0, 1, 3, 4, 1021, 1023])
+def test_pool_gather_misalignment(s, pallas_kw):
+    """Chunks whose first byte lies ``s`` bytes into a 1 KiB row: one
+    lane, a lane and a byte, many lanes, and a second chunk that starts
+    where the first ends (another misalignment)."""
+    rng = np.random.default_rng(100 + s)
+    flat = rng.integers(0, 256, 96 * 1024, dtype=np.uint8)
+    spans = [(s, 1024), (5 * 1024 + s, 1025), (8 * 1024 + s, 20_000),
+             (8 * 1024 + s + 20_000, 4097), (40 * 1024 + s, 1)]
+    _assert_oracle(flat, spans, C=8, **pallas_kw)
+
+
+@FORMS
+@pytest.mark.parametrize("n", [1, 1023, 1024, 1025, 3 << 20],
+                         ids=["1", "1023", "1024", "1025", "3MiB"])
+def test_pool_gather_chunk_lengths(n, pallas_kw):
+    """One chunk of ``n`` bytes at a byte offset that is no multiple of
+    anything, beside a short neighbour on each side."""
+    rng = np.random.default_rng(n % 9973)
+    flat = rng.integers(0, 256, n + 4099, dtype=np.uint8)
+    spans = [(0, 2051), (2051, n), (2051 + n, 2048)]
+    _assert_oracle(flat, spans, C=4,
+                   tiers=((8, 4), (3072, 4)), **pallas_kw)
+
+
+@FORMS
+def test_pool_gather_chunk_ends_on_last_byte_before_slack(pallas_kw):
+    """The stream's last byte belongs to a chunk, whatever row it falls
+    in: behind it lies nothing but the ``CHUNK_LEN`` of slack, and the
+    view of whole rows is cut inside that slack."""
+    rng = np.random.default_rng(77)
+    for n in (16 * 1024, 16 * 1024 + 31, 16 * 1024 + 1023):
+        flat = rng.integers(0, 256, n, dtype=np.uint8)
+        spans = [(0, 5000), (n - 3001, 3001), (n - 1, 1), (n - 1024, 1024)]
+        _assert_oracle(flat, spans, C=4, **pallas_kw)
+
+
+@FORMS
+def test_pool_gather_unused_slot_between_used(pallas_kw):
+    rng = np.random.default_rng(78)
+    flat = rng.integers(0, 256, 64 * 1024, dtype=np.uint8)
+    spans = [(7, 5000), (9999, 0), (5007, 7000), (123, -1), (12_007, 1023)]
+    acc = _assert_oracle(flat, spans, C=8, **pallas_kw)
+    assert not acc[len(spans):].any()
+
+
+@pytest.mark.parametrize("short", [1, 2, 7])
+def test_pool_gather_leaf_cap_short_still_flags(short):
+    """A pool with ``short`` lanes too few says so; the chunks that got
+    all their lanes still digest to the oracle's value."""
+    rng = np.random.default_rng(79)
+    flat = rng.integers(0, 256, 32 * 1024, dtype=np.uint8)
+    spans = [(3, 8192), (8195, 8192)]  # 16 lanes
+    acc, ovf = _run_pool(flat, [o for o, _ in spans], [l for _, l in spans],
+                         C=4, tiers=((8, 4), (16, 4)), leaf_cap=16 - short)
+    assert ovf == short
+    assert _digests_of(acc)[0] == blake3_hash(flat[3:3 + 8192].tobytes())
+
+
+def _stage_rows(rows, P):
+    """``(B, _HALO + P)`` batch buffer and lengths of byte-string rows."""
+    buf = np.zeros((len(rows), _HALO + P), dtype=np.uint8)
+    nv = np.zeros(len(rows), dtype=np.int32)
+    for r, d in enumerate(rows):
+        buf[r, _HALO:_HALO + len(d)] = np.frombuffer(d, dtype=np.uint8)
+        nv[r] = len(d)
+    return buf, nv
+
+
+def _assert_rows_match_oracle(rows, packed, acc, ovf, cut_cap):
+    """A batch program's cuts and digests against the CPU chunker and
+    ``blake3_cpu``, row by row."""
+    assert not np.asarray(ovf).any()
+    packed = np.asarray(packed)
+    dig8 = np.ascontiguousarray(np.asarray(acc).astype("<u4")).view(
+        np.uint8).reshape(len(rows), cut_cap, 32)
+    for r, data in enumerate(rows):
+        ref_chunks = cdc_cpu.chunk_stream(data, SMALL)
+        ref_digests = Blake3Numpy().digest_batch(
+            [data[o:o + l] for o, l in ref_chunks])
+        assert packed[r, 0] == 0
+        n_cuts = int(packed[r, 1])
+        ends = packed[r, 2:2 + n_cuts].astype(np.int64)
+        offs = np.concatenate([[0], ends[:-1] + 1])
+        assert list(zip(offs.tolist(),
+                        (ends - offs + 1).tolist())) == ref_chunks
+        assert [bytes(d) for d in dig8[r, :n_cuts]] == ref_digests
+
+
+def test_pool_gather_through_the_mesh_program():
+    """``scan_digest_batch_pool_mesh`` on the 8-device CPU mesh, a row a
+    shard: every row's bytes start 31 bytes into its own ``31 + P``, so
+    each chunk of each shard meets the gather at another misalignment."""
+    n_dev, P = 8, 32768
+    mesh = Mesh(np.array(jax.devices()[:n_dev]), ("data",))
+    rng = np.random.default_rng(80)
+    rows = [rng.integers(0, 256, n, dtype=np.uint8).tobytes()
+            for n in (P, P - 1, 20_001, 1, 0, 1025, 9_999, 31_744)]
+    buf, nv = _stage_rows(rows, P)
+    s_cap, l_cap, cut_cap = DevicePipeline(SMALL)._caps(P)
+    sharding = NamedSharding(mesh, PartitionSpec("data"))
+    packed, acc, ovf = scan_digest_batch_pool_mesh(
+        jax.device_put(buf, sharding), jax.device_put(nv, sharding),
+        mesh=mesh, axis="data", min_size=SMALL.min_size,
+        desired_size=SMALL.desired_size, max_size=SMALL.max_size,
+        mask_s=SMALL.mask_s, mask_l=SMALL.mask_l,
+        s_cap=s_cap, l_cap=l_cap, cut_cap=cut_cap, fused=False,
+        leaf_cap=leaf_capacity(P, cut_cap), tiers=tier_plan(SMALL, P, 1))
+    assert np.asarray(ovf).shape == (n_dev,)  # a flag a shard
+    _assert_rows_match_oracle(rows, packed, acc, ovf, cut_cap)
 
 
 def test_pool_digest_tier_cascade_and_overflow():
@@ -171,16 +307,10 @@ def test_pool_gate_runs():
 def test_scan_digest_batch_pool_matches_oracle():
     P = 65536
     rng = np.random.default_rng(13)
-    sizes = [P, 30_000, 0, 1, 5000]
     rows = [rng.integers(0, 256, n, dtype=np.uint8).tobytes()
-            for n in sizes]
-    buf = np.zeros((len(rows), _HALO + P), dtype=np.uint8)
-    nv = np.zeros(len(rows), dtype=np.int32)
-    for r, d in enumerate(rows):
-        buf[r, _HALO:_HALO + len(d)] = np.frombuffer(d, dtype=np.uint8)
-        nv[r] = len(d)
-    pipe = DevicePipeline(SMALL)
-    s_cap, l_cap, cut_cap = pipe._caps(P)
+            for n in (P, 30_000, 0, 1, 5000)]
+    buf, nv = _stage_rows(rows, P)
+    s_cap, l_cap, cut_cap = DevicePipeline(SMALL)._caps(P)
     packed, acc, ovf = scan_digest_batch_pool(
         jnp.asarray(buf), jnp.asarray(nv), min_size=SMALL.min_size,
         desired_size=SMALL.desired_size, max_size=SMALL.max_size,
@@ -188,19 +318,4 @@ def test_scan_digest_batch_pool_matches_oracle():
         s_cap=s_cap, l_cap=l_cap, cut_cap=cut_cap, fused=False,
         leaf_cap=leaf_capacity(len(rows) * P, len(rows) * cut_cap),
         tiers=tier_plan(SMALL, len(rows) * P, len(rows)))
-    packed = np.asarray(packed)
-    acc = np.asarray(acc)
-    assert not np.asarray(ovf).any()
-    dig8 = np.ascontiguousarray(acc.astype("<u4")).view(np.uint8).reshape(
-        len(rows), cut_cap, 32)
-    for r, data in enumerate(rows):
-        ref_chunks = cdc_cpu.chunk_stream(data, SMALL)
-        ref_digests = Blake3Numpy().digest_batch(
-            [data[o:o + l] for o, l in ref_chunks])
-        assert packed[r, 0] == 0
-        n_cuts = int(packed[r, 1])
-        ends = packed[r, 2:2 + n_cuts].astype(np.int64)
-        offs = np.concatenate([[0], ends[:-1] + 1])
-        assert list(zip(offs.tolist(),
-                        (ends - offs + 1).tolist())) == ref_chunks
-        assert [bytes(d) for d in dig8[r, :n_cuts]] == ref_digests
+    _assert_rows_match_oracle(rows, packed, acc, ovf, cut_cap)
