@@ -252,15 +252,31 @@ _TREE_SCAN = _metrics.counter(
 # Bytes the resident streaming route (ops/resident.py) moved for a
 # streamed file: ``uploaded`` is everything it put on the device
 # (window blocks, chunk rows), about the file's size when every byte
-# goes up once; ``host_assembled`` is what it had to copy together on
-# the host (the chunk that straddles carry and window, a slice rescanned
-# by the oracle), a few hundred KiB a segment.
-STREAM_BYTE_KINDS = ("uploaded", "host_assembled")
+# goes up once; ``carried`` is what stayed there between two windows,
+# the open chunk a window ends in, moved to the front of the next
+# resident buffer by a device slice (at most ``max_size`` a window);
+# ``host_assembled`` is what it had to copy together on the host (the
+# chunk that straddles carry and window, a slice rescanned by the
+# oracle), at most one chunk a window.  Beside them the windows made
+# resident, and what the route's digest tiles were dispatched over by
+# leaf class (``leaves``: a tile's row length in 1 KiB leaves): the
+# chunks' own bytes, and the tiles' full height and row length.
+STREAM_BYTE_KINDS = ("uploaded", "host_assembled", "carried")
 _STREAM_BYTES = _metrics.counter(
     "bkw_stream_bytes_total",
-    "Bytes the resident streaming route uploaded to the device, and "
-    "bytes it assembled on the host, per streamed file",
+    "Bytes the resident streaming route uploaded to the device, moved "
+    "to the next resident buffer on the device, and assembled on the "
+    "host, per streamed file",
     labelnames=("kind",))
+_STREAM_SEGMENTS = _metrics.counter(
+    "bkw_stream_segments_total",
+    "Windows of streamed files the resident streaming route made "
+    "resident on the device")
+_STREAM_DIGEST_BYTES = _metrics.counter(
+    "bkw_stream_digest_bytes_total",
+    "Bytes of the resident streaming route's digest tiles by leaf "
+    "class: the chunks' own, and the padded tiles'",
+    labelnames=("leaves", "what"))
 
 # What the send stage moves for the packfiles it codes: ``packfile`` is
 # the bytes of every packfile whose stripe it coded and audited,
@@ -350,6 +366,7 @@ REPORT_SPANS = tuple(dict.fromkeys((
     "packer.manifest_many",
     "stream.file",
     *STREAM_GROUPS,
+    "stream.boundary_chunk",  # inside stream.slice: not a group's term
     *BATCH_GROUPS,  # pipeline.mesh_dispatch / mesh_collect among them
     "index.classify",
     "send.dial",
@@ -480,11 +497,24 @@ def tier_cold_commit(kind: str) -> None:
 
 
 def stream_bytes(kind: str, n: int) -> None:
-    """Count ``n`` bytes of a streamed file uploaded or host-assembled."""
+    """Count ``n`` bytes of a streamed file uploaded, carried on the
+    device or host-assembled."""
     if kind not in STREAM_BYTE_KINDS:
         raise ValueError(f"unknown stream byte kind {kind!r}")
     if n:
         _STREAM_BYTES.inc(n, kind=kind)
+
+
+def stream_segment() -> None:
+    """One window of a streamed file made resident."""
+    _STREAM_SEGMENTS.inc()
+
+
+def stream_digest_tile(leaves: int, actual_bytes: int,
+                       padded_bytes: int) -> None:
+    """One digest tile of the streaming route's class ``leaves``."""
+    _STREAM_DIGEST_BYTES.inc(actual_bytes, leaves=str(leaves), what="actual")
+    _STREAM_DIGEST_BYTES.inc(padded_bytes, leaves=str(leaves), what="padded")
 
 
 def batch_chunks(verdict: str, n: int) -> None:
@@ -609,6 +639,10 @@ def baseline() -> Dict[str, Dict[str, float]]:
     out["tier"] = tier
     out["stream_bytes"] = {k: _STREAM_BYTES.value(kind=k)
                            for k in STREAM_BYTE_KINDS}
+    out["stream_segments"] = {"segments": _STREAM_SEGMENTS.value()}
+    out["stream_digest"] = {
+        (s["labels"]["leaves"], s["labels"]["what"]): s["value"]
+        for s in _STREAM_DIGEST_BYTES._snapshot_series()}
     out["batch_chunks"] = {v: _BATCH_CHUNKS.value(verdict=v)
                            for v in BATCH_VERDICTS}
     out["batch_files"] = {r: _BATCH_FILES.value(route=r)
@@ -678,6 +712,14 @@ def report(base: Optional[dict] = None,
     stream: dict = _by_group(span_s, STREAM_GROUPS)
     for kind, n in _delta("stream_bytes").items():
         stream[f"{kind}_bytes"] = int(n)
+    stream["segments"] = int(_delta("stream_segments")["segments"])
+    # {leaves: {"bytes", "padded_bytes"}} of the classes that ran a tile
+    classes: Dict[str, Dict[str, int]] = {}
+    for (leaves, what), n in _delta("stream_digest").items():
+        if n > 0:
+            key = "bytes" if what == "actual" else "padded_bytes"
+            classes.setdefault(leaves, {})[key] = int(n)
+    stream["digest_classes"] = classes
     batch: dict = _by_group(span_s, BATCH_GROUPS)
     batch["chunks"] = {k: int(v) for k, v in _delta("batch_chunks").items()}
     batch["files"] = {k: int(v) for k, v in _delta("batch_files").items()}

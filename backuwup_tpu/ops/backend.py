@@ -339,7 +339,8 @@ class TpuBackend(ChunkerBackend):
 
         Spans, one per step per segment: ``stream.read``,
         ``stream.upload``, ``cdc.scan``, ``cdc.decode``,
-        ``stream.select_cuts``, ``stream.slice`` (views), ``blake3.stage``
+        ``stream.select_cuts``, ``stream.slice`` (views; inside it
+        ``stream.boundary_chunk``, the host's one copy), ``blake3.stage``
         (chunk rows), ``blake3.digest``, ``stream.emit``.
         """
         from .resident import ResidentStream
@@ -494,8 +495,9 @@ def _host_view(carry: memoryview, window: memoryview, off: int,
         return window[off - c:off - c + ln]
     if off + ln <= c:
         return carry[off:off + ln]
-    obs_profile.stream_bytes("host_assembled", ln)
-    return memoryview(b"".join((carry[off:], window[:off + ln - c])))
+    with obs_trace.span("stream.boundary_chunk"):
+        obs_profile.stream_bytes("host_assembled", ln)
+        return memoryview(b"".join((carry[off:], window[:off + ln - c])))
 
 
 _jax_hooks_installed = False

@@ -166,7 +166,8 @@ def _resident_slice(buf: jnp.ndarray, start: jnp.ndarray,
 
 class ResidentStream:
     """The device half of one streamed file: the resident buffer, where
-    the carry lies in it, and the two byte counters the report reads."""
+    the carry lies in it, and the counters the report reads (bytes
+    uploaded and carried, windows, digest tiles by class)."""
 
     def __init__(self, params: CDCParams, scanner: TpuCdcScanner,
                  segment_bytes: int):
@@ -190,6 +191,7 @@ class ResidentStream:
             self.buf = _next_resident(
                 self.buf, np.int32(geo.front + self.window), np.int32(carry),
                 front=geo.front)
+            obs_profile.stream_bytes("carried", carry)
         host = np.frombuffer(window, dtype=np.uint8)
         blocks, pos = [], 0
         for size in geo.blocks(len(host)):
@@ -211,6 +213,7 @@ class ResidentStream:
             block.delete()
         self.window, self.carry = len(host), carry
         obs_profile.stream_bytes("uploaded", pos - geo.front)
+        obs_profile.stream_segment()
 
     # --- scan --------------------------------------------------------------
 
@@ -312,6 +315,7 @@ class ResidentStream:
                                  padded_bytes=padded)
             obs_profile.dispatch("digest", actual_bytes=actual,
                                  padded_bytes=padded)
+            obs_profile.stream_digest_tile(L, actual, padded)
         raw = np.asarray(acc).astype("<u4").tobytes()
         return [raw[32 * r:32 * r + 32] for r in row_of.tolist()]
 

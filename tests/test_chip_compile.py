@@ -264,15 +264,21 @@ def test_dedup_program_compiles_at_default_capacity(meshes, n_dev, insert):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
-def test_resident_stream_route_compiles_at_the_packers_segment(one_chip):
+@pytest.mark.parametrize("params,classes", [
+    pytest.param(CDCParams(16384, 65536, 196608, 18, 14),
+                 ((64, 128), (256, 128)), id="64k-chunks"),
+    pytest.param(CDCParams(), ((1024, 128), (2048, 32), (3072, 32)),
+                 id="shipped-1m-chunks")])
+def test_resident_stream_route_compiles_at_the_packers_segment(
+        one_chip, params, classes):
     """The streamed file's route (ops/resident.py) at the packer's 256 MiB
-    segment and the benchmark's 64 KiB chunks: the buffer's programs
+    segment, at the benchmark's 64 KiB chunks and at the shipped 1 MiB
+    ones (rows of up to 3 MiB, a 3 MiB carry): the buffer's programs
     alias it in place, the scan slice is the 128 MiB program the route
-    always ran, and the gather+digest tile of the largest class keeps its
+    always ran, and every class's gather+digest tile keeps its
     temporaries under a gibibyte beside the resident segment."""
-    params = CDCParams(16384, 65536, 196608, 18, 14)
     geo = resident.Geometry.of(params, TpuCdcScanner(params), 256 << 20)
-    assert geo.classes == ((64, 128), (256, 128)) and geo.n_slices == 2
+    assert geo.classes == classes and geo.n_slices == 2
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -292,11 +298,11 @@ def test_resident_stream_route_compiles_at_the_packers_segment(one_chip):
         shape((_HALO + geo.scan_slice,), jnp.uint8), i32,
         shape((), jnp.uint32), shape((), jnp.uint32), k_cap=geo.k_cap)
     assert _temp_bytes(scan) < 1.25 * GiB
-    L, B = geo.classes[-1]
-    tile = _gather_digest.lower(
-        buf, shape((2, geo.rows), jnp.int32), i32,
-        shape((geo.rows, 8), jnp.uint32), B=B, L=L)
-    assert _temp_bytes(tile) < 1 * GiB
+    for L, B in geo.classes:
+        tile = _gather_digest.lower(
+            buf, shape((2, geo.rows), jnp.int32), i32,
+            shape((geo.rows, 8), jnp.uint32), B=B, L=L)
+        assert _temp_bytes(tile) < 1 * GiB
 
 
 def test_resident_stripe_route_compiles_at_a_sealed_packfiles_bucket(one_chip):

@@ -106,7 +106,9 @@ def test_stream_route_enters_each_span_once_per_segment(make, rng):
         assert delta[name] == rounds, (name, delta)
     rep = obs_profile.report(base)
     assert set(rep["stream"]) == {"host_prep", "device_wait", "emit",
-                                  "uploaded_bytes", "host_assembled_bytes"}
+                                  "uploaded_bytes", "host_assembled_bytes",
+                                  "carried_bytes", "segments",
+                                  "digest_classes"}
     assert rep["stream"]["host_prep"] > 0 and rep["stream"]["emit"] > 0
     if make is TpuBackend:
         # every window is uploaded and scanned once (the empty read at
@@ -122,15 +124,24 @@ def test_stream_route_enters_each_span_once_per_segment(make, rng):
         for name in ("stream.upload", "cdc.scan", "blake3.digest",
                      "stream.emit"):
             assert rep["stage_seconds"][name] > 0
-        # one span per step per segment, none per chunk
+        # one span per step per segment, none per chunk; the host's one
+        # copy a window (the chunk astride carry and window, or the
+        # open chunk carried on) is a span of its own inside the slice
         assert len(refs) > 20 * rounds
+        assert 0 < delta["stream.boundary_chunk"] <= 2 * (rounds - 1)
         assert sum(delta.values()) == 3 * rounds + 4 * (rounds - 1) \
-            + 2 * digests
+            + 2 * digests + delta["stream.boundary_chunk"]
+        assert rep["stream"]["segments"] == rounds - 1
+        tiles = rep["stream"]["digest_classes"]
+        assert sum(c["bytes"] for c in tiles.values()) == len(data)
+        assert all(c["padded_bytes"] >= c["bytes"] for c in tiles.values())
     else:
         assert all(delta[n] == 0 for n in DEVICE_ROUTE + ("blake3.stage",
                                                           "blake3.digest"))
         assert rep["stream"]["device_wait"] == 0
         assert rep["stream"]["uploaded_bytes"] == 0
+        assert rep["stream"]["segments"] == 0
+        assert rep["stream"]["digest_classes"] == {}
 
 
 @pytest.mark.parametrize("make", [CpuBackend, TpuBackend],
