@@ -23,6 +23,8 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from ..ops.blake3_cpu import blake3_hash
+
 _DIR = Path(__file__).resolve().parent
 _SOURCES = ("cdc_blake3.c", "Makefile")
 
@@ -123,6 +125,18 @@ def blake3_native(data: bytes) -> bytes:
     out = np.zeros(32, dtype=np.uint8)
     lib.bkw_blake3(_u8(arr) if len(arr) else _u8(out), len(arr), _u8(out))
     return out.tobytes()
+
+
+def host_digest(data, oracle=blake3_hash) -> bytes:
+    """BLAKE3 of one input on the host, for what is hashed where it is
+    built (a tree node as the packer emits it, a transfer's whole file):
+    the C library where it loads (6 us at 100 bytes, 0.3 ms at 147 KB,
+    GIL released), else ``oracle`` — the scalar reference by default
+    (0.14 ms and 180 ms for the same two), which suits metadata-sized
+    inputs; a caller of whole files passes the numpy batch engine."""
+    if available():
+        return blake3_native(data)
+    return oracle(data)
 
 
 def _cap(n: int, min_size: int) -> int:

@@ -51,9 +51,7 @@ def _file_digest(data) -> bytes:
     which manages about 1 MiB/s and, at one digest per side of every file
     above ``TRANSFER_CHUNK_BYTES``, was the whole wall clock of a served
     backup (PERF.md, PR 22)."""
-    if native.available():
-        return native.blake3_native(data)
-    return blake3_many([data])[0]
+    return native.host_digest(data, oracle=lambda d: blake3_many([d])[0])
 
 
 _P2P_BYTES = obs_metrics.counter(
