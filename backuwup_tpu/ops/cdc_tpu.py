@@ -16,9 +16,12 @@ decomposition designed for XLA/TPU:
   are exact in bf16 and the MXU accumulates in f32, so the product is the
   exact integer table value.
 * Candidate cut-points (``h & mask == 0``) leave the device as a two-level
-  sparse structure: bits are packed 32:1 into u32 words on the VPU, then a
-  fixed-capacity ``jnp.nonzero`` compacts the (overwhelmingly zero) words,
-  so only a few KiB cross host<->HBM per segment.
+  sparse structure: bits are packed 32:1 into u32 words on the VPU, then
+  :func:`_compact_words` hands over the (overwhelmingly zero) words that are
+  not zero at a fixed capacity, so only a few KiB cross host<->HBM per
+  segment.  It is not ``jnp.nonzero(size=...)``: that is a scatter of one
+  update a word, which the TPU applies one after the other; the compaction
+  scatters one update a 128-word block and gathers whole rows.
 * Final cut selection (min/desired/max + two-mask normalization) runs on the
   host over the sparse candidates — the same code path as the CPU oracle
   (:func:`backuwup_tpu.ops.cdc_cpu.select_cuts`), so TPU and CPU chunking
@@ -127,6 +130,64 @@ def _candidate_words(h, n_valid, mask_s, mask_l):
     return _pack_bits(cand_l), _pack_bits(cand_s)
 
 
+def _compact_words(words_l, words_s, k_cap: int):
+    """The nonzero loose words of a slice, in order, at a fixed capacity.
+
+    Returns ``(widx, wl, ws, nz_words)``: the indices of the nonzero words
+    of ``words_l`` ascending (``-1`` from the true count on), the loose and
+    strict words at those indices, and the true count, whatever ``k_cap``.
+
+    ``jnp.nonzero(mask, size=k_cap)`` is a scatter-add of one update a
+    *word*, and the v5e runs a scatter one update after the other: 36.6 ms
+    a 128 MiB slice's 4,194,304 words at any density (PERF.md, PR 47).
+    Here the only scatter has one update a 128-word *block*: the blocks'
+    counts and their exclusive cumsum give each non-empty block its first
+    output slot, its id is scattered there and spread over the block's
+    slots by a running maximum, every slot fetches its block as a row (the
+    chip gathers whole rows fast) and picks its word by rank along the 128
+    lanes (a product on the MXU).  Every shape follows from ``k_cap`` and
+    the word count, and no level has a capacity of its own below ``k_cap``.
+    """
+    n = words_l.shape[0]
+    blk = 128
+    while n % blk:
+        blk //= 2
+    nblk = n // blk
+    wl2 = words_l.reshape(nblk, blk)
+    cnt = jnp.sum(wl2 != 0, axis=1, dtype=jnp.int32)
+    nz_words = jnp.sum(cnt)
+    off = jnp.cumsum(cnt) - cnt
+    # slots at or past k_cap fall off the end; 0 marks "no block starts here"
+    first = jnp.zeros(k_cap, jnp.int32).at[
+        jnp.where(cnt > 0, off, k_cap)].max(
+            jnp.arange(1, nblk + 1, dtype=jnp.int32), mode="drop")
+    slot = jnp.arange(k_cap, dtype=jnp.int32)
+    bid = jnp.maximum(jax.lax.cummax(first) - 1, 0)
+    rank = slot - jax.lax.cummax(jnp.where(first > 0, slot, 0))
+    rows_l = wl2[bid]
+    rows_nz = rows_l != 0
+    # the count of nonzero lanes up to each lane, as a product with a
+    # triangle of ones on the MXU: 0/1 are exact in bf16 and the sums, at
+    # most 128, in f32.  (A cumsum along the lanes is 0.2-3.4 ms slower a
+    # slice on the chip and takes its compiler 20-29 s at 8,192 rows;
+    # doubling shifted adds 1.2 ms slower at 131,072: PERF.md, PR 47.)
+    lane = jnp.arange(blk, dtype=jnp.int32)
+    upto = jnp.dot(rows_nz.astype(jnp.bfloat16),
+                   (lane[:, None] <= lane[None, :]).astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+    pick = rows_nz & (upto == (rank + 1)[:, None])
+    filled = slot < nz_words
+    widx = jnp.where(filled, bid * blk + jnp.argmax(pick, axis=1), -1)
+
+    def picked(rows, words):
+        got = jnp.sum(jnp.where(pick, rows, jnp.uint32(0)), axis=1,
+                      dtype=jnp.uint32)
+        return jnp.where(filled, got, words[0])
+
+    return (widx, picked(rows_l, words_l),
+            picked(words_s.reshape(nblk, blk)[bid], words_s), nz_words)
+
+
 @functools.partial(jax.jit, static_argnames=("k_cap",))
 def _scan_segment(ext, n_valid, mask_s, mask_l, *, k_cap: int):
     """Hash one padded segment, return sparse candidate words.
@@ -139,11 +200,7 @@ def _scan_segment(ext, n_valid, mask_s, mask_l, *, k_cap: int):
         h = _hash_ext_fast(ext)
         words_l, words_s = _candidate_words(h, n_valid, mask_s, mask_l)
     with jax.named_scope("cdc_word_compact"):
-        nz = words_l != 0
-        (widx,) = jnp.nonzero(nz, size=k_cap, fill_value=-1)
-        nz_words = jnp.sum(nz.astype(jnp.int32))
-        safe = jnp.clip(widx, 0, words_l.shape[0] - 1)
-        return widx, words_l[safe], words_s[safe], nz_words
+        return _compact_words(words_l, words_s, k_cap)
 
 
 def _decode_words(widx, wl, ws, count, base_offset: int):

@@ -15,6 +15,10 @@ file for the streaming route), then:
 3. restores into an empty directory and compares every byte;
 4. backs the unchanged tree up again: every chunk must classify duplicate.
 
+Before the served path it scans one 128 MiB slice of seeded bytes at each
+of the benchmark's two candidate densities (1 MiB and 64 KiB chunks) and
+holds ``_scan_segment``'s sparse outputs to the numpy oracle's (untimed).
+
 It asserts that the Pallas kernels were selected, that the HBM tier
 answered fingerprints, and that no row was re-run on the host.  Every
 phase prints one JSON line; a phase that fails ends the run non-zero.  The
@@ -290,6 +294,44 @@ async def served_path(args, work: Path, meter: CompileMeter) -> None:
         await server.stop()
 
 
+def scan_slices(args) -> None:
+    """One ``_scan_segment`` slice at the scanner's segment size and each
+    density the benchmark's cells run: indices, loose and strict words and
+    the count equal to the numpy oracle's over the same bytes."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from backuwup_tpu.ops.cdc_cpu import gear_hashes
+    from backuwup_tpu.ops.cdc_tpu import _HALO, TpuCdcScanner, _scan_segment
+    from backuwup_tpu.ops.gear import CDCParams
+
+    n = (8 if args.rehearse else 128) * MiB
+    ext = np.random.default_rng(args.seed).integers(
+        0, 256, _HALO + n, dtype=np.uint8)
+    n_valid = n - 77  # ends inside a word
+    h = gear_hashes(ext[_HALO:].tobytes(), ext[:_HALO].tobytes())
+    h[n_valid:] = 0xFFFFFFFF  # a candidate of neither mask
+    bit = np.arange(32, dtype=np.uint32)
+    for params in (CDCParams(), CDCParams.from_desired(64 * KiB)):
+        k_cap = TpuCdcScanner(params)._k_cap(n)
+        widx, wl, ws, count = _scan_segment(
+            jnp.asarray(ext), jnp.int32(n_valid), jnp.uint32(params.mask_s),
+            jnp.uint32(params.mask_l), k_cap=k_cap)
+        cand_l = (h & np.uint32(params.mask_l)) == 0
+        cand_s = cand_l & ((h & np.uint32(params.mask_s)) == 0)
+        words_l, words_s = (
+            (c.reshape(-1, 32).astype(np.uint32) << bit).sum(
+                axis=1, dtype=np.uint32) for c in (cand_l, cand_s))
+        want = np.flatnonzero(words_l)
+        assert 0 < len(want) == int(count) <= k_cap, (len(want), int(count))
+        widx = np.asarray(widx)
+        assert (widx[:len(want)] == want).all() and (widx[len(want):] == -1).all()
+        assert (np.asarray(wl)[:len(want)] == words_l[want]).all()
+        assert (np.asarray(ws)[:len(want)] == words_s[want]).all()
+        emit(phase="scan_slice", mask_l_bits=params.mask_l_bits, bytes=n,
+             k_cap=k_cap, nz_words=len(want), equal_to_oracle=True)
+
+
 # --- four chips: the mesh manifest and the sharded index ---------------------------
 
 def mesh_path(args, work: Path, meter: CompileMeter) -> None:
@@ -419,6 +461,7 @@ def main(argv=None) -> int:
         if args.chips == 4:
             mesh_path(args, work, meter)
         else:
+            scan_slices(args)
             asyncio.run(served_path(args, work, meter))
     finally:
         shutil.rmtree(work, ignore_errors=True)

@@ -64,6 +64,114 @@ def test_segment_overflow_falls_back_to_oracle(monkeypatch):
     assert scanner.chunk_stream(data) == cdc_cpu.chunk_stream(data, SMALL)
 
 
+# --- the scan's word compaction (PR 47) -------------------------------------
+
+# (word count, k_cap): the smallest segment bucket, a 1 MiB and a 4 MiB one
+_COMPACT_SHAPES = {2048: 512, 32768: 512, 131072: 2048}
+_COMPACT_MASKS = ["all-zero", "first-word", "last-word", "full-block",
+                  "every-word", "exactly-k_cap", "k_cap-plus-1",
+                  "density-2^-13", "density-2^-9"]
+
+
+def _mask_indices(kind, n, k_cap, rng):
+    if kind.startswith("density"):
+        bits = int(kind.rsplit("-", 1)[1])
+        return np.flatnonzero(rng.random(n) < 2.0 ** -bits)
+    return {"all-zero": lambda: np.empty(0, np.int64),
+            "first-word": lambda: np.array([0]),
+            "last-word": lambda: np.array([n - 1]),
+            "full-block": lambda: np.arange(256, 384),
+            "every-word": lambda: np.arange(n),
+            "exactly-k_cap": lambda: rng.choice(n, k_cap, replace=False),
+            "k_cap-plus-1": lambda: rng.choice(n, k_cap + 1, replace=False),
+            }[kind]()
+
+
+def _contract(words_l, words_s, k_cap):
+    """``_scan_segment``'s outputs in numpy: what the direct
+    ``jnp.nonzero(size=k_cap, fill_value=-1)`` returned, to the bit."""
+    nz = np.flatnonzero(words_l)
+    widx = np.full(k_cap, -1, np.int32)
+    widx[:min(len(nz), k_cap)] = nz[:k_cap]
+    safe = np.clip(widx, 0, len(words_l) - 1)
+    return widx, words_l[safe], words_s[safe], len(nz)
+
+
+@pytest.mark.parametrize("kind", _COMPACT_MASKS)
+@pytest.mark.parametrize("n", sorted(_COMPACT_SHAPES))
+def test_compact_words_matches_flatnonzero(n, kind):
+    import jax
+
+    from backuwup_tpu.ops.cdc_tpu import _compact_words
+
+    k_cap = _COMPACT_SHAPES[n]
+    rng = np.random.default_rng(n + _COMPACT_MASKS.index(kind))
+    idx = _mask_indices(kind, n, k_cap, rng)
+    words_l = np.zeros(n, np.uint32)
+    words_l[idx] = rng.integers(1, 1 << 32, len(idx), dtype=np.uint64)
+    words_s = words_l & rng.integers(0, 1 << 32, n, dtype=np.uint64).astype(
+        np.uint32)
+    got = jax.jit(_compact_words, static_argnums=2)(words_l, words_s, k_cap)
+    widx, wl, ws, count = _contract(words_l, words_s, k_cap)
+    # the callers' one overflow signal is the TRUE count, past k_cap too
+    assert int(got[3]) == count == len(idx)
+    np.testing.assert_array_equal(np.asarray(got[0]), widx)
+    np.testing.assert_array_equal(np.asarray(got[1]), wl)
+    np.testing.assert_array_equal(np.asarray(got[2]), ws)
+    assert got[0].dtype == np.int32 and got[1].dtype == np.uint32
+
+
+@pytest.mark.parametrize("n", sorted(_COMPACT_SHAPES))
+def test_scan_segment_n_valid_inside_a_word(n):
+    """A segment that ends inside a word: the bits past ``n_valid`` are no
+    candidates, the words before it are the oracle's."""
+    import jax.numpy as jnp
+
+    from backuwup_tpu.ops.cdc_tpu import _HALO, _scan_segment
+
+    params = CDCParams.from_desired(1024)  # a candidate every 256 bytes
+    k_cap = n // 8  # twice the candidates of the valid half
+    ext = np.frombuffer(_data(_HALO + 32 * n, seed=n), dtype=np.uint8)
+    n_valid = 32 * (n // 2) + 13
+    got = _scan_segment(jnp.asarray(ext), jnp.int32(n_valid),
+                        jnp.uint32(params.mask_s), jnp.uint32(params.mask_l),
+                        k_cap=k_cap)
+    h = cdc_cpu.gear_hashes(ext[_HALO:].tobytes(), ext[:_HALO].tobytes())
+    valid = np.arange(32 * n) < n_valid
+    cand_l = ((h & np.uint32(params.mask_l)) == 0) & valid
+    cand_s = cand_l & ((h & np.uint32(params.mask_s)) == 0)
+
+    def pack(bits):
+        return (bits.reshape(-1, 32).astype(np.uint32)
+                << np.arange(32, dtype=np.uint32)).sum(axis=1,
+                                                       dtype=np.uint32)
+
+    widx, wl, ws, count = _contract(pack(cand_l), pack(cand_s), k_cap)
+    assert 0 < count == int(got[3]) <= k_cap
+    np.testing.assert_array_equal(np.asarray(got[0]), widx)
+    np.testing.assert_array_equal(np.asarray(got[1]), wl)
+    np.testing.assert_array_equal(np.asarray(got[2]), ws)
+    assert widx.max() <= (n_valid - 1) // 32
+
+
+@pytest.mark.parametrize("params", [
+    pytest.param(CDCParams.from_desired(64 * 1024), id="mask_l_bits-14"),
+    pytest.param(CDCParams(), id="mask_l_bits-18")])
+def test_candidate_positions_match_oracle_at_the_cells_densities(params):
+    """The benchmark cells' two densities over a multi-segment input (the
+    last segment short): the device's candidates are ``gear_hashes``'s.
+    The compaction has no capacity of its own below ``k_cap``, so the
+    oracle rescan is ``test_segment_overflow_falls_back_to_oracle``'s."""
+    data = _data(5 * (1 << 19) + 12345, seed=params.mask_l_bits)
+    scanner = TpuCdcScanner(params, segment_size=1 << 20)
+    pos_s, pos_l = scanner.candidate_positions(data)
+    want_s, want_l = cdc_cpu.candidate_positions(data, params)
+    assert len(want_l) > 4
+    # the first 31 hashes of a stream have a short window on the device
+    np.testing.assert_array_equal(pos_l[pos_l >= 31], want_l[want_l >= 31])
+    np.testing.assert_array_equal(pos_s[pos_s >= 31], want_s[want_s >= 31])
+
+
 def test_scan_select_forced_cut_fallback_and_parallel_paths(rng):
     """The pointer-doubling selection and its sequential fallback must both
     be bit-identical to the oracle: zero runs force non-candidate cuts

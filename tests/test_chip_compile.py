@@ -264,21 +264,36 @@ def test_dedup_program_compiles_at_default_capacity(meshes, n_dev, insert):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
-@pytest.mark.parametrize("params,classes", [
+def _scatter_updates(compiled) -> list:
+    """How many updates each scatter of a compiled program applies (the
+    v5e applies them one after the other, 8.7 ns each: PR 47)."""
+    text = compiled.as_text()
+    counts = []
+    for m in re.finditer(r" scatter\(%[\w.-]+, %[\w.-]+, (%[\w.-]+)\)", text):
+        dims = re.search(re.escape(m.group(1)) + r" = \w+\[([\d,]*)\]", text)
+        counts.append(int(np.prod([int(d) for d in dims.group(1).split(",")
+                                   if d])))
+    return counts
+
+
+@pytest.mark.parametrize("params,classes,k_cap", [
     pytest.param(CDCParams(16384, 65536, 196608, 18, 14),
-                 ((64, 128), (256, 128)), id="64k-chunks"),
-    pytest.param(CDCParams(), ((1024, 128), (2048, 32), (3072, 32)),
+                 ((64, 128), (256, 128)), 131072, id="64k-chunks"),
+    pytest.param(CDCParams(), ((1024, 128), (2048, 32), (3072, 32)), 8192,
                  id="shipped-1m-chunks")])
 def test_resident_stream_route_compiles_at_the_packers_segment(
-        one_chip, params, classes):
+        one_chip, params, classes, k_cap):
     """The streamed file's route (ops/resident.py) at the packer's 256 MiB
     segment, at the benchmark's 64 KiB chunks and at the shipped 1 MiB
     ones (rows of up to 3 MiB, a 3 MiB carry): the buffer's programs
     alias it in place, the scan slice is the 128 MiB program the route
-    always ran, and every class's gather+digest tile keeps its
-    temporaries under a gibibyte beside the resident segment."""
+    always ran, at the sparse capacity of either density, with no scatter
+    of an update a candidate word (PR 47: one a 128-word block), and every
+    class's gather+digest tile keeps its temporaries under a gibibyte
+    beside the resident segment."""
     geo = resident.Geometry.of(params, TpuCdcScanner(params), 256 << 20)
     assert geo.classes == classes and geo.n_slices == 2
+    assert geo.k_cap == k_cap
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -296,8 +311,11 @@ def test_resident_stream_route_compiles_at_the_packers_segment(
         buf, i32, size=_HALO + geo.scan_slice).compile()
     scan = _scan_segment.lower(
         shape((_HALO + geo.scan_slice,), jnp.uint8), i32,
-        shape((), jnp.uint32), shape((), jnp.uint32), k_cap=geo.k_cap)
-    assert _temp_bytes(scan) < 1.25 * GiB
+        shape((), jnp.uint32), shape((), jnp.uint32),
+        k_cap=geo.k_cap).compile()
+    assert scan.memory_analysis().temp_size_in_bytes < 1.25 * GiB
+    updates = _scatter_updates(scan)
+    assert updates and max(updates) <= geo.scan_slice // 32 // 128, updates
     for L, B in geo.classes:
         tile = _gather_digest.lower(
             buf, shape((2, geo.rows), jnp.int32), i32,
