@@ -104,10 +104,6 @@ PACK_EMIT_MAX_LAG_S = 2.0
 # callback wakes the loop the moment a packfile commits; this timeout
 # only bounds how long a (theoretical) lost wakeup could park it.
 SEND_WAKEUP_BACKSTOP_S = 0.5
-# Host->device staging ring depth for manifest_segments_stream
-# (ops/pipeline.py): batch N+1's bytes upload asynchronously while
-# batch N runs scan->digest on device.
-PIPELINE_STAGE_DEPTH = 2
 
 # --- resumable WAN transfer plane (net/p2p.py send_file, docs/transfer.md) ---
 # Payloads larger than this go out as FILE_PART frames with per-part acks

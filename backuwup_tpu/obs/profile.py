@@ -14,18 +14,20 @@ moral equivalent):
 =========  =================================================================
 stage      what counts as one dispatch
 =========  =================================================================
-scan       device: one fused ``scan_select_batch``/``scan_digest_batch``
-           launch per batch.  CPU/native fallback: one ``chunk()`` pass
+scan       device: one ``scan_select_batch`` launch per batch, alone (the
+           host-tiled path) or inside the batch's one manifest program
+           (``scan_digest_batch_pool``).  CPU/native fallback: one ``chunk()`` pass
            per stream (native runs the whole pipeline in one C call per
            stream and counts once under every stage).
 select     rides the scan program on every path (fused boundary
            selection), so it counts 1:1 with scan.
-gather     device: one ``gather_chunks``/``_gather_digest`` tile launch.
+gather     device: one ``gather_chunks``/``_gather_digest`` tile launch,
+           or the leaf pool's gather inside the manifest program.
            CPU fallback: one host piece-slicing pass per stream that
            produced at least one chunk.
 digest     device: one batched digest launch (``_gather_digest`` tile,
-           fused scan+digest batch, or ``blake3_many_tpu`` tiny-stream
-           batch).  CPU fallback: one batched ``digest_many`` call per
+           the manifest program's leaf pool, a long stream's
+           ``pool_digest``, or ``blake3_many_tpu`` tiny-stream batch).  CPU fallback: one batched ``digest_many`` call per
            ``manifest_many``/stream segment with at least one piece.
 index      one batched dedup classification per pack batch (device
            ``dedup_batch`` table classify or the host blob-index pass),
@@ -174,7 +176,7 @@ STREAM_GROUPS = {
 
 # The same for one pack batch of whole files (the batched route:
 # ``DirPacker._flush_batch`` -> ``manifest_many_classified`` ->
-# ``DevicePipeline.manifest_batch_classified``): reading the files,
+# ``DevicePipeline.manifest_batch``): reading the files,
 # building host batches and decoding what came down, waiting for the
 # device (the mesh program's dispatch and collect, the tiny files'
 # digest batch, a long file's segmented scan and digest, the index
@@ -361,7 +363,7 @@ PACK_STEPS = {
 }
 
 # Span names whose bkw_span_seconds sums a pipeline report attributes as
-# per-stage wall time: the batched route's dispatch/collect pairs, the
+# per-stage wall time: the host-tiled path's dispatch/collect pairs, the
 # packer entry point that drives them and the batch's own parts, the
 # streamed file and its parts, the index classify, the send stage's
 # steps (``send.stripe`` holds ``send.rs_encode``, ``send.challenge_tables``
@@ -372,9 +374,6 @@ REPORT_SPANS = tuple(dict.fromkeys((
     "pipeline.cut_collect",
     "pipeline.digest_dispatch",
     "pipeline.digest_collect",
-    "pipeline.scan_digest_dispatch",
-    "pipeline.scan_digest_collect",
-    "pipeline.h2d_stage",
     "packer.manifest_many",
     "stream.file",
     *STREAM_GROUPS,

@@ -432,7 +432,7 @@ class TpuBackend(ChunkerBackend):
                                               missing, rand)
 
     def manifest_many(self, streams):
-        results = self.pipeline.manifest_batch(streams)
+        results, _flags = self.pipeline.manifest_batch(streams)
         out = []
         for chunks, digests in results:
             out.append([
@@ -466,7 +466,7 @@ class TpuBackend(ChunkerBackend):
         pipe = self.pipeline
         if not self._rides_mesh_of(dedup):
             return super().manifest_many_classified(streams, dedup)
-        results, rowflags = pipe.manifest_batch_classified(streams, dedup)
+        results, rowflags = pipe.manifest_batch(streams, dedup)
         out = []
         hashes: List[bytes] = []
         raw: List[Optional[bool]] = []

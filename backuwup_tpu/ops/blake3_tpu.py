@@ -448,19 +448,15 @@ def pallas_digest_available() -> bool:
     On the TPU a kernel that does not lower, or does not match, raises —
     with the compiler's own message — instead of handing the work to a
     slower path behind a green run.  Off the TPU (the CPU test
-    configuration) the kernel is simply not selected.
+    configuration) False: the XLA leaf scan is the only form there.
     """
-    import os
-
-    if os.environ.get("BKW_PALLAS_DIGEST", "1") == "0":
-        return False
     if jax.devices()[0].platform != "tpu":
         return False
     rng = np.random.default_rng(3)
     # B*L = 12288 lanes = 3 grid steps (> _LEAF_LANES): the probe must
     # exercise the multi-grid-step index map on the live runtime — a
     # g>1-specific mis-lowering would otherwise pass a g=1 probe and
-    # silently corrupt digests in production class tiles.
+    # silently corrupt digests in production tiles.
     B = 1536
     buf = rng.integers(0, 256, (B, 8 * CHUNK_LEN), dtype=np.uint8)
     lens = np.resize(

@@ -1,14 +1,13 @@
 """Flat leaf-pool BLAKE3: digest every chunk of a batch in ONE program.
 
-The class-tile digest stage (``manifest_device.scan_digest_batch``) pays
-per-class costs ~12 times per batch: a full-length ``nonzero`` compaction,
-a padded gather at the class span, an XLA word-prep pass, a separate
-Pallas grid, and a scatter — and PERF.md's stage table shows that on
-hardware this dispatch + word-prep overhead, not leaf compute, dominates
-the digest section (~60-135 ms of a ~100-170 ms segment).  The reference
-has no equivalent stage at all — it hashes chunks one at a time on the
-CPU (``dir_packer.rs:285-311``); this module is how the same work maps
-onto a TPU without the reference's serial structure.
+A digest stage that lays chunks out as padded tiles a length class pays
+per-class costs ~12 times per batch: a full-length ``nonzero``
+compaction, a padded gather at the class span, an XLA word-prep pass, a
+separate Pallas grid, and a scatter — dispatch and word-prep overhead,
+not leaf compute.  The reference has no equivalent stage at all — it
+hashes chunks one at a time on the CPU (``dir_packer.rs:285-311``); this
+module is how the same work maps onto a TPU without the reference's
+serial structure.
 
 Design: decompose EVERY chunk into its 1 KiB BLAKE3 leaves and run one
 flat pool of leaves through a single scan:
@@ -20,10 +19,10 @@ flat pool of leaves through a single scan:
    ids at each chunk's first lane + a running max — no per-class
    compaction, no searchsorted.
 2. **One leaf scan.**  The pool gathers once (1 KiB per lane), word-preps
-   once, and runs ONE Pallas grid (or the XLA fallback) over all lanes.
-   Padding waste is the final partial leaf of each chunk — near-zero,
-   where the class tiles padded every chunk to its class span (~1.2-1.5x
-   measured).  The leaf scan is ~94% of single-chunk BLAKE3 compute
+   once, and runs ONE Pallas grid (the XLA leaf scan off the TPU) over
+   all lanes.  Padding waste is the final partial leaf of each chunk —
+   near-zero, where class tiles pad every chunk to its class span
+   (~1.2-1.5x measured).  The leaf scan is ~94% of single-chunk BLAKE3 compute
    (16 blocks/leaf vs 1 merge per leaf pair), so this stage holds
    essentially all the FLOPs.
 3. **Tiny tiered tree.**  Leaf chaining values (32 B/leaf — 32x smaller
@@ -31,13 +30,12 @@ flat pool of leaves through a single scan:
    tiers and pair-merged by :func:`blake3_tpu.tree_reduce_groups`; tier
    padding costs ~1/16 of leaf work at worst, so coarse tiers are fine
    where payload-level class tiles were not.  Tier capacities cascade
-   upward exactly like the class cascade (excess hands to the next tier;
-   only terminus overflow aborts to the host-tiled path, bit-exact
-   either way).
+   upward (excess hands to the next tier; only terminus overflow aborts
+   to the host-tiled path, bit-exact either way).
 
 Digests are bit-identical to :mod:`backuwup_tpu.ops.blake3_cpu` (the
-spec oracle) — property-tested in interpret mode and gated at runtime by
-``DevicePipeline``'s parity ladder before production use.
+spec oracle) — property-tested in interpret mode and checked on the live
+runtime where a ``DevicePipeline`` is made (:func:`_pool_digest_probe`).
 
 Mesh usage (``manifest_device.scan_digest_batch_pool_mesh``): each shard
 runs its own pool over its row slice with PER-SHARD ``leaf_cap``/``tiers``
@@ -84,8 +82,9 @@ def _leaf_scan_xla_flat(words_flat: jnp.ndarray, nb: jnp.ndarray,
                         lbl: jnp.ndarray, counter: jnp.ndarray):
     """Flat-lane XLA leaf scan: (lanes, 16, 16) u32 -> (lanes, 8) cv +
     (lanes, 8) penultimate cv (state before the last block's compression,
-    for the single-leaf ROOT recompute).  Fallback when the Pallas kernel
-    is unavailable; masking mirrors ``digest_padded``'s leaf loop.
+    for the single-leaf ROOT recompute).  The pool's leaf scan off the TPU
+    and the Pallas kernel's reference; masking mirrors ``digest_padded``'s
+    leaf loop.
     """
     lanes = words_flat.shape[0]
     zeros = jnp.zeros(lanes, dtype=jnp.uint32)
@@ -166,7 +165,7 @@ def tier_spans(max_leaves: int, n_tiers: int = 3) -> Tuple[int, ...]:
 def leaf_capacity(total_padded_bytes: int, max_chunks: int) -> int:
     """Structural upper bound on pool lanes: every payload byte plus at
     most one partial leaf per chunk.  No distribution calibration — the
-    pool, unlike the class tiles, cannot overflow on adversarial data."""
+    pool's lanes, unlike its tiers, cannot overflow on adversarial data."""
     cap = total_padded_bytes // CHUNK_LEN + max_chunks
     return -(-cap // 512) * 512
 
@@ -289,10 +288,14 @@ def pool_digest(flat: jnp.ndarray, offs: jnp.ndarray, lens: jnp.ndarray, *,
     return acc, ovf
 
 
+@functools.lru_cache(maxsize=2)
 def _pool_digest_probe(pallas: bool) -> None:
     """Run the compiled leaf-pool path against the HOST spec oracle on
-    the live runtime; raises on a lowering failure (the compiler's own
-    error) or on the first digest that differs."""
+    the live runtime, once a form (where a ``DevicePipeline`` is made);
+    raises on a lowering failure (the compiler's own error) or on the
+    first digest that differs, on any platform: the pool is the batched
+    route's only digest, and a fault there must not turn into a slower
+    green run."""
     from .blake3_cpu import blake3_hash
     rng = np.random.default_rng(7)
     flat = rng.integers(0, 256, 256 * 1024, dtype=np.uint8)
@@ -323,30 +326,6 @@ def _pool_digest_probe(pallas: bool) -> None:
                 f"BLAKE3 oracle on a {l}-byte chunk")
 
 
-@functools.lru_cache(maxsize=4)
-def pool_digest_available(pallas: bool) -> bool:
-    """True when the compiled leaf-pool path matches the HOST spec oracle
-    on the live runtime.
-
-    On a TPU a probe that does not lower or does not match raises: a
-    fault there must not turn into a slower green run.  Off the TPU (the
-    CPU test configuration) the XLA form of the same program is probed
-    and a failure only deselects it — the class tiles take over.
-    """
-    import os
-
-    if os.environ.get("BKW_POOL_DIGEST", "1") == "0":
-        return False
-    if jax.devices()[0].platform == "tpu":
-        _pool_digest_probe(pallas)
-        return True
-    try:
-        _pool_digest_probe(pallas)
-        return True
-    except Exception:  # pragma: no cover - CPU test configuration only
-        return False
-
-
 @functools.lru_cache(maxsize=64)
 def tier_caps(spans: Tuple[int, ...], fracs_by_leaves, expect_total: float,
               n_extra: int) -> Tuple[Tuple[int, int], ...]:
@@ -354,7 +333,7 @@ def tier_caps(spans: Tuple[int, ...], fracs_by_leaves, expect_total: float,
 
     ``fracs_by_leaves``: tuple of (max_leaves_of_bin, fraction) pairs —
     hashable so the plan caches per (params, shape).  Expectation +
-     0.75 sigma like the class cascade; the terminus carries the real
+    0.75 sigma (binomial); the terminus carries the real
     slack plus ``n_extra`` (short per-row tails land in tier 0).
     """
     out = []

@@ -1,30 +1,28 @@
 """Zero-round-trip manifest: scan -> select -> gather -> digest on device.
 
-The host-tiled driver downloads each segment's cut list before it can
+A host-tiled driver downloads each segment's cut list before it can
 stage digest tiles, so every batch pays two serialized host round trips
-while the device sits idle (before PR 1 this, not device compute, was the
-pipeline's wall clock; not re-measured on the v5e).  The reference has
-the same structure collapsed
-onto one CPU (``dir_packer.rs:246-311``): chunk, then hash, then index —
-all in one address space.  The TPU answer is to keep the *data plane*
-entirely in HBM:
+while the device sits idle.  The reference has the same structure
+collapsed onto one CPU (``dir_packer.rs:246-311``): chunk, then hash,
+then index — all in one address space.  The TPU answer is to keep the
+*data plane* entirely in HBM:
 
 1. :func:`backuwup_tpu.ops.cdc_tpu.scan_select_batch` produces packed
    per-row cut lists on device (Mosaic strip scan + on-device selection).
-2. Chunk meta (offset, length, class) is DERIVED on device from the cut
-   lists — no host assembly.
-3. Chunks are compacted into a small set of power-of-two length classes
-   (fixed-capacity ``nonzero``), gathered HBM->HBM at their class's
-   padded span, digested with the batched BLAKE3, and the root chaining
-   values scattered into one dense ``(B*cut_cap, 8)`` accumulator.
+2. Chunk meta (offset, length) is DERIVED on device from the cut lists —
+   no host assembly.
+3. Every chunk's 1 KiB leaves run through one flat leaf pool and 2-3
+   small tree tiers (:func:`backuwup_tpu.ops.digest_pool.pool_digest`),
+   and the root chaining values land in one dense ``(B*cut_cap, 8)``
+   accumulator.
 4. The caller downloads ``(cuts, digests, overflow)`` once — for a whole
    run of batches — and assembles manifests host-side.
 
-Class capacities are sized from a one-time oracle calibration of the
-chunk-length distribution (:func:`class_plan`); a class overflow (data
-far from the calibrated distribution, e.g. adversarial all-max chunks)
-sets a flag and the affected batch falls back to the host-tiled path,
-preserving bit-exact output.
+Tier capacities are sized from the analytic chunk-length distribution
+(:func:`tier_plan`); a tier overflow (data far from that distribution,
+e.g. adversarial all-max chunks) sets a flag a shard and the affected
+shard's rows fall back to the host-tiled path, preserving bit-exact
+output.
 """
 
 from __future__ import annotations
@@ -37,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .cdc_tpu import _HALO, scan_select_batch
-from .blake3_tpu import digest_padded
 from .gear import CDCParams
 
 CHUNK_LEN = 1024
@@ -53,7 +50,7 @@ def _length_histogram(params: CDCParams) -> Tuple[float, Tuple[float, ...]]:
     ``(1-p_s)^a (1-p_l)^b`` where ``a``/``b`` count positions seen by the
     strict/loose windows and ``p = 2^-mask_bits``; the forced cut at
     ``max_size`` truncates the tail.  Exact for random corpora; real
-    corpora that deviate far enough to overflow the 1.7x-slack capacities
+    corpora that deviate far enough to overflow the tiers' capacities
     fall back to the host-tiled path (still bit-exact), so this estimate
     only steers throughput, never correctness.
     """
@@ -98,36 +95,6 @@ def class_leaf_sizes(params: CDCParams) -> Tuple[int, ...]:
     return tuple(out)
 
 
-@functools.lru_cache(maxsize=64)
-def class_caps(params: CDCParams, total_bytes: int,
-               n_rows: int) -> Tuple[int, ...]:
-    """Per-class chunk-slot capacities for one batch shape.
-
-    Expectation + 0.75 sigma (binomial) per class — deliberately tight,
-    because digest compute scales with cap x class span and the cascade
-    hands per-class excess to the next span class; only total-count
-    fluctuation reaches the terminus (which carries the real slack).
-    Class 0 additionally holds every row's short tail.  A cascade
-    overflow is detected on device and the batch re-runs on the
-    host-tiled path (bit-exact either way).
-    """
-    mean_len, fracs = _length_histogram(params)
-    expect_total = total_bytes / max(mean_len, 1.0)
-    caps = []
-    for i, frac in enumerate(fracs):
-        mu = expect_total * frac
-        sigma = (expect_total * frac * (1.0 - frac)) ** 0.5
-        want = mu + 0.75 * sigma + 1 + (n_rows if i == 0 else 0)
-        if i == len(fracs) - 1:
-            want += 8 + 0.02 * expect_total  # cascade terminus slack
-        elif mu < 1.5 and i > 0:
-            # near-empty class: skip its digest tile entirely, the
-            # cascade hands its rare chunks one span class up
-            want = 0
-        caps.append(-(-int(want) // 4) * 4)
-    return tuple(caps)
-
-
 def _chunk_meta(packed: jnp.ndarray, row_len: int):
     """Packed cut rows -> flat per-chunk (abs offset, length, valid).
 
@@ -154,84 +121,17 @@ def _chunk_meta(packed: jnp.ndarray, row_len: int):
             valid.reshape(-1))
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "min_size", "desired_size", "max_size", "mask_s", "mask_l",
-    "s_cap", "l_cap", "cut_cap", "fused", "classes", "caps",
-    "pallas_digest"))
-def scan_digest_batch(buf_d: jnp.ndarray, nv_b: jnp.ndarray, *,
-                      min_size: int, desired_size: int, max_size: int,
-                      mask_s: int, mask_l: int, s_cap: int, l_cap: int,
-                      cut_cap: int, fused: bool,
-                      classes: Tuple[int, ...], caps: Tuple[int, ...],
-                      pallas_digest: bool = False):
-    """One resident ``(B, _HALO+P)`` batch -> (packed cuts, digests, ovf).
-
-    Everything stays on device: ``packed`` is ``scan_select_batch``'s
-    ``(B, 2+cut_cap)`` cut rows, ``digests`` is ``(B*cut_cap, 8)`` u32
-    root chaining values addressed by ``row*cut_cap + chunk``, ``ovf`` is
-    ``(1,)`` i32 — the number of chunks the cascade could not place
-    (nonzero means the caller must fall back; see cascade note below).
-    """
-    B = buf_d.shape[0]
-    row_len = buf_d.shape[1]
-    packed = scan_select_batch(
-        buf_d, nv_b, min_size=min_size, desired_size=desired_size,
-        max_size=max_size, mask_s=mask_s, mask_l=mask_l,
-        s_cap=s_cap, l_cap=l_cap, cut_cap=cut_cap, fused=fused)
-    abs_offs, flat_lens, flat_valid = _chunk_meta(packed, row_len)
-    total = B * cut_cap
-
-    leaves = (flat_lens + (CHUNK_LEN - 1)) // CHUNK_LEN
-    # class id = index of smallest class >= leaves (valid chunks only)
-    cls = jnp.zeros(total, dtype=jnp.int32)
-    for i, c in enumerate(classes[:-1]):
-        cls = cls + (leaves > c).astype(jnp.int32)
-
-    flat = buf_d.reshape(-1)
-    # slack so fixed-span gathers never clamp (dynamic_slice clips
-    # out-of-range starts, which would shift data)
-    flat = jnp.pad(flat, (0, classes[-1] * CHUNK_LEN))
-    acc = jnp.zeros((total, 8), dtype=jnp.uint32)
-    # cascade spill: a class beyond its capacity hands its excess chunks
-    # to the next (larger-span) class, so per-class capacities stay at
-    # ~expectation and only total-count fluctuation can reach the top
-    carry = jnp.zeros(total, dtype=bool)
-    for i, (Lc, cap) in enumerate(zip(classes, caps)):
-        if cap == 0:  # skipped class: cascade everything upward
-            carry = carry | (flat_valid & (cls == i))
-            continue
-        mine = flat_valid & ((cls == i) | carry)
-        rank = jnp.cumsum(mine.astype(jnp.int32)) - 1
-        take = mine & (rank < cap)
-        carry = mine & ~take
-        (idx,) = jnp.nonzero(take, size=cap, fill_value=total)
-        safe = jnp.clip(idx, 0, total - 1)
-        got = idx < total
-        o = jnp.where(got, abs_offs[safe], 0)
-        ln = jnp.where(got, flat_lens[safe], 0)
-        span = Lc * CHUNK_LEN
-
-        def one(off):
-            return jax.lax.dynamic_slice(flat, (off,), (span,))
-
-        tile = jax.vmap(one)(o)
-        cv = digest_padded(tile, ln, L=Lc, pallas=pallas_digest)  # (cap, 8)
-        acc = acc.at[idx].set(cv, mode="drop")
-    ovf = jnp.sum(carry.astype(jnp.int32))[None]  # terminus overflow only
-    return packed, acc, ovf
-
-
 @functools.lru_cache(maxsize=64)
 def tier_plan(params: CDCParams, total_bytes: int,
               n_rows: int) -> Tuple[Tuple[int, int], ...]:
     """((leaf_span, chunk_cap), ...) tree tiers for the leaf-pool digest.
 
-    Chunk-count expectations come from the same analytic length
-    histogram as :func:`class_caps`, re-binned onto the 2-3 geometric
-    tier spans (tree work is ~1/16 of leaf work, so coarse spans cost
-    a few percent where the payload-level class tiles could not afford
-    them).  Class bins that straddle a tier edge only blur the capacity
-    estimate — overflow still cascades and, at the terminus, falls back
+    Chunk-count expectations come from the analytic length histogram
+    (:func:`_length_histogram`), re-binned onto the 2-3 geometric tier
+    spans (tree work is ~1/16 of leaf work, so coarse spans cost a few
+    percent where payload-level class tiles could not afford them).
+    Class bins that straddle a tier edge only blur the capacity estimate
+    — overflow still cascades and, at the terminus, falls back
     bit-exactly.
     """
     from .digest_pool import tier_caps, tier_spans
@@ -253,11 +153,15 @@ def scan_digest_batch_pool(buf_d: jnp.ndarray, nv_b: jnp.ndarray, *,
                            cut_cap: int, fused: bool, leaf_cap: int,
                            tiers: Tuple[Tuple[int, int], ...],
                            pallas_digest: bool = False):
-    """Leaf-pool twin of :func:`scan_digest_batch` — same contract, but
-    the digest stage is ONE flat leaf scan + 2-3 tiny tree tiles
-    (:func:`backuwup_tpu.ops.digest_pool.pool_digest`) instead of ~12
-    per-class gather+digest pipelines.  Selected by ``DevicePipeline``'s
-    runtime parity ladder; bit-identical output either way.
+    """One resident ``(B, _HALO+P)`` batch -> (packed cuts, digests, ovf).
+
+    Everything stays on device: ``packed`` is ``scan_select_batch``'s
+    ``(B, 2+cut_cap)`` cut rows, ``digests`` is ``(B*cut_cap, 8)`` u32
+    root chaining values addressed by ``row*cut_cap + chunk``, ``ovf`` is
+    ``(1,)`` i32 — the number of chunks the tier cascade could not place
+    (nonzero means the caller must fall back).  The digest stage is ONE
+    flat leaf scan + 2-3 tiny tree tiles
+    (:func:`backuwup_tpu.ops.digest_pool.pool_digest`).
     """
     from .digest_pool import pool_digest
 
@@ -336,8 +240,7 @@ def scan_digest_batch_pool_mesh(buf_d, nv_b, *, mesh, axis: str,
     ``(packed, acc, ovf[, queries])`` where ``ovf`` is the ``(D,)``
     per-shard overflow vector.  Bit-identical to the single-device path:
     a shard sees exactly the rows a ``B/D``-row single-device batch would,
-    and every kernel is row-independent (parity-ladder posture — a mesh
-    that mis-lowers loses speed, never correctness).  With ``lower``
+    and every kernel is row-independent.  With ``lower``
     the two arguments are shapes (``jax.ShapeDtypeStruct``) and the
     program is traced and lowered for them, not run
     (``jax.stages.Lowered``).

@@ -1,25 +1,30 @@
 """Device-resident dedup pipeline: scan+select -> gather chunks -> digest.
 
 Composes the TPU kernels into the full chunk+hash step that the engine's
-batched route runs and ``chip_smoke.py`` drives:
+batched route runs and ``chip_smoke.py`` drives.  One driver takes a pack
+batch to the chip (:meth:`DevicePipeline.manifest_segments_mesh`): each
+bucketed batch is ONE shard-mapped program over the pipeline's mesh (a
+mesh of one device on one chip) —
 
 1. fused gear-hash scan + on-device FastCDC cut selection of a resident
-   byte batch (:func:`..ops.cdc_tpu.scan_select_batch`) — ONE dispatch,
-   and the only mid-pipeline download is the tiny packed cut list,
-2. on-device gather of the variable-length chunks into a small fixed set
-   of padded ``(B, L*1024)`` tiles (``vmap`` of ``dynamic_slice`` — bytes
-   move HBM->HBM, never through the host),
-3. batched BLAKE3 digests (:mod:`.blake3_tpu`).
+   byte batch (:func:`..ops.cdc_tpu.scan_select_batch`),
+2. chunk meta derived on device and every chunk's 1 KiB leaves digested
+   out of HBM in one flat leaf pool (:mod:`.digest_pool`),
+3. with a device index, the digest accumulator handed to it on the mesh
+   —
 
-Tile shapes are restricted to B in {8, 32, 128} and pow2 leaf buckets so
-the whole pipeline compiles a small closed set of programs (first-run cost,
-then the persistent cache) — data-dependent shapes were the round-2
-throughput killer: every novel (B, L) combo paid a 20-40 s XLA compile.
+and the only downloads are the packed cut list, the digests and the
+found-flags.  Beside it: the host-tiled path
+(:meth:`DevicePipeline.manifest_segments`: cuts come down, ``(B, L*1024)``
+digest tiles of B in {8, 32, 128} rows and pow2 leaf buckets go up), the
+exact fall-back for a shard whose pool overflowed, whose
+``_gather_digest`` tiles are also the streaming route's digest
+(:mod:`.resident`).
 
-Dispatch and collect halves are separate methods so
-:meth:`DevicePipeline.manifest_segments` can software-pipeline several
-segments: segment i+1's scan runs on device while segment i's cuts download
-(async) and its digest tiles are assembled on host.
+Shapes are closed sets so the whole pipeline compiles a small closed set
+of programs (first-run cost, then the persistent cache) — data-dependent
+shapes were the round-2 throughput killer: every novel (B, L) combo paid
+a 20-40 s XLA compile.
 
 The reference executes the same logical pipeline one byte / one chunk at a
 time on the CPU (``dir_packer.rs:246-311``).
@@ -215,15 +220,18 @@ class DevicePipeline:
         # per-device peak bytes in flight across the mesh dispatch window
         self.mesh_hbm_high_water: dict = {}
         self._nv_cache: OrderedDict = OrderedDict()
+        from . import digest_pool
         from .blake3_tpu import pallas_digest_available
-        from .digest_pool import pool_digest_available
         from .scan_fused import fused_scan_available
+        # the platform sets the kernels' forms (a TPU: the Mosaic scan and
+        # leaf kernels, checked here or raising; the CPU configuration:
+        # their XLA forms), and the leaf pool is checked in that form
         self.fused = fused_scan_available()
         self.pallas_digest = pallas_digest_available()
-        # leaf-pool digest stage: one flat leaf scan + tiny tree tiles
-        # instead of ~12 per-class pipelines; parity-gated on the live
-        # runtime, class tiles remain the fallback
-        self.pool_digest = pool_digest_available(self.pallas_digest)
+        digest_pool._pool_digest_probe(self.pallas_digest)
+        # read, with the two above and scan_fused._V2_SELECTED, by
+        # benchmark/deployment.py's ``kernels`` (ROADMAP D16)
+        self.pool_digest = True
 
     # --- scan + select (device) -------------------------------------------
 
@@ -279,14 +287,11 @@ class DevicePipeline:
         return packed_d
 
     def scan_select_collect(self, packed_d: jnp.ndarray, buf_d: jnp.ndarray,
-                            nv: np.ndarray,
-                            strict_overflow: bool = False) -> List[List[tuple]]:
+                            nv: np.ndarray) -> List[List[tuple]]:
         """Packed device cuts -> per-row [(offset, length)...] chunk lists.
 
         Overflowed rows (sparse capacity exceeded — adversarial data) are
-        re-chunked with the CPU oracle to stay bit-identical, unless
-        ``strict_overflow`` (benchmarks must never silently time the
-        oracle)."""
+        re-chunked with the CPU oracle to stay bit-identical."""
         with obs_trace.span("pipeline.cut_collect"):
             packed = np.asarray(packed_d)
         nv = np.asarray(nv, dtype=np.int32)
@@ -294,8 +299,6 @@ class DevicePipeline:
         for r in range(packed.shape[0]):
             overflow, chunks = _decode_cut_row(packed[r])
             if overflow:
-                if strict_overflow:
-                    raise RuntimeError("candidate overflow in scan+select")
                 row_bytes = bytes(np.asarray(
                     buf_d[r, _HALO:_HALO + int(nv[r])]))
                 per_row.append(chunk_stream_cpu(row_bytes, self.params))
@@ -386,193 +389,27 @@ class DevicePipeline:
 
     # --- composed drivers --------------------------------------------------
 
-    def manifest_segments(self, segments,
-                          strict_overflow: bool = False):
-        """Software-pipelined driver over resident batches (generator).
-
-        ``segments`` is any iterable of ``(buf_d, nv)``; batches are pulled
-        (and thus staged to HBM) lazily, at most ~3 in flight, so callers
-        can stream arbitrarily many batches without holding them all
-        resident.  While batch i's packed cuts cross the (high-latency)
-        host link, batch i+1's scan runs on device; digests download
-        asynchronously one stage later.  Steady-state wall clock approaches
-        pure device compute instead of compute + 2 round trips per batch.
-        Yields each batch's per-row results in order.
-        """
-        it = iter(segments)
-        scans: deque = deque()
-        digs: deque = deque()
-
-        def pump_scan():
-            for buf_d, nv in it:
-                scans.append((buf_d, nv,
-                              self.scan_select_dispatch(buf_d, nv)))
-                return
-
-        pump_scan()
-        pump_scan()
-        while scans or digs:
-            if scans:
-                buf_d, nv, packed_d = scans.popleft()
-                per_row = self.scan_select_collect(
-                    packed_d, buf_d, nv, strict_overflow)
-                digs.append((per_row,
-                             self.digest_dispatch(buf_d, per_row)))
-                del buf_d  # batch bytes may be freed once tiles dispatched
-                pump_scan()
-            while digs and (len(digs) >= 2 or not scans):
-                per_row, pending = digs.popleft()
-                yield self.digest_collect(pending, per_row)
-
-    def manifest_segments_stream(self, host_segments,
-                                 strict_overflow: bool = False,
-                                 depth: Optional[int] = None):
-        """:meth:`manifest_segments` fed through a double-buffered
-        host->device staging ring (generator).
-
-        ``host_segments`` yields HOST ``(buf, nv)`` batches (numpy).  A
-        ring of ``depth`` (default ``defaults.PIPELINE_STAGE_DEPTH``, 2)
-        batches is kept staged ahead of consumption with
-        ``jax.device_put`` — an async H2D copy on real accelerators — so
-        batch N+1's bytes cross the host link while batch N runs
-        scan->digest on device.  The synchronous alternative
-        (``jnp.asarray`` inside the consuming loop) serializes every
-        upload against compute; that staging gap was PERF.md round-5
-        item 3.  Results are bit-identical to the non-staged driver.
-        """
-        from .. import defaults as _defaults
-        if depth is None:
-            depth = _defaults.PIPELINE_STAGE_DEPTH
-        depth = max(1, int(depth))
-        it = iter(host_segments)
-        ring: deque = deque()
-
-        def stage_one() -> bool:
-            for buf, nv in it:
-                with obs_trace.span("pipeline.h2d_stage"):
-                    ring.append((jax.device_put(buf), nv))
-                return True
-            return False
-
-        def staged():
-            while True:
-                while len(ring) < depth and stage_one():
-                    pass
-                if not ring:
-                    return
-                yield ring.popleft()
-
-        yield from self.manifest_segments(staged(), strict_overflow)
-
-    def manifest_segments_device(self, segments, strict_overflow: bool = False,
-                                 window: int = 4):
-        """Zero-round-trip pipelined driver (generator).
-
-        Unlike :meth:`manifest_segments` (which downloads each batch's cut
-        list before staging digest tiles — two host round trips per batch,
-        the measured wall-clock floor on high-latency links), every stage
-        here runs on device via
-        :func:`backuwup_tpu.ops.manifest_device.scan_digest_batch`; the
-        only downloads are the packed cuts + digest accumulator, whose
-        async copies overlap later batches' compute.  ``window`` bounds
-        batches in flight (HBM high-water).
-
-        Overflow handling preserves bit-exactness: a row whose sparse
-        candidate capacity overflowed re-chunks on the CPU oracle; a batch
-        whose class capacities overflowed re-runs on the host-tiled path.
-        """
-        from .digest_pool import leaf_capacity
-        from .manifest_device import (class_caps, class_leaf_sizes,
-                                      scan_digest_batch,
-                                      scan_digest_batch_pool, tier_plan)
-
-        p = self.params
-        classes = class_leaf_sizes(p)
-        it = iter(segments)
-        pending: deque = deque()
-
-        def dispatch():
-            for buf_d, nv in it:
-                B = int(buf_d.shape[0])
-                padded = int(buf_d.shape[1]) - _HALO
-                s_cap, l_cap, cut_cap = self._caps(padded)
-                with obs_trace.span("pipeline.scan_digest_dispatch"):
-                    if self.pool_digest:
-                        packed, acc, ovf = scan_digest_batch_pool(
-                            buf_d, self._nv_device(nv),
-                            min_size=p.min_size, desired_size=p.desired_size,
-                            max_size=p.max_size, mask_s=p.mask_s,
-                            mask_l=p.mask_l, s_cap=s_cap, l_cap=l_cap,
-                            cut_cap=cut_cap, fused=self.fused,
-                            leaf_cap=leaf_capacity(B * padded, B * cut_cap),
-                            tiers=tier_plan(p, B * padded, B),
-                            pallas_digest=self.pallas_digest)
-                    else:
-                        packed, acc, ovf = scan_digest_batch(
-                            buf_d, self._nv_device(nv),
-                            min_size=p.min_size, desired_size=p.desired_size,
-                            max_size=p.max_size, mask_s=p.mask_s,
-                            mask_l=p.mask_l, s_cap=s_cap, l_cap=l_cap,
-                            cut_cap=cut_cap, fused=self.fused,
-                            classes=classes,
-                            caps=class_caps(p, B * padded, B),
-                            pallas_digest=self.pallas_digest)
-                for a in (packed, acc, ovf):
-                    _async_to_host(a)
-                actual = int(np.asarray(nv, dtype=np.int64).sum())
-                padded_total = B * padded
-                for stage in ("scan", "select", "gather", "digest"):
-                    obs_profile.dispatch(stage, actual_bytes=actual,
-                                         padded_bytes=padded_total)
-                pending.append((buf_d, nv, cut_cap, packed, acc, ovf))
-                return True
-            return False
-
-        for _ in range(window):
-            dispatch()
-        while pending:
-            buf_d, nv, cut_cap, packed_d, acc_d, ovf_d = pending.popleft()
-            dispatch()
-            with obs_trace.span("pipeline.scan_digest_collect"):
-                packed = np.asarray(packed_d)
-                ovf = np.asarray(ovf_d)
-            if ovf.any():
-                if strict_overflow:
-                    raise RuntimeError("class capacity overflow in "
-                                       "device manifest")
-                # recalibrated path: host-tiled pipeline, still exact
-                yield from self.manifest_segments([(buf_d, nv)])
-                continue
-            acc = np.asarray(acc_d)
-            dig8 = np.ascontiguousarray(acc.astype("<u4")).view(
-                np.uint8).reshape(-1, cut_cap, 32)
-            out = []
-            nv = np.asarray(nv, dtype=np.int32)
-            for r in range(packed.shape[0]):
-                overflow, chunks = _decode_cut_row(packed[r])
-                if overflow:
-                    if strict_overflow:
-                        raise RuntimeError(
-                            "candidate overflow in scan+select")
-                    row = bytes(np.asarray(
-                        buf_d[r, _HALO:_HALO + int(nv[r])]))
-                    chunks = chunk_stream_cpu(row, self.params)
-                    digs = np.stack([np.frombuffer(
-                        _blake3_host(row[o:o + ln]), dtype=np.uint8)
-                        for o, ln in chunks]) if chunks else \
-                        np.zeros((0, 32), dtype=np.uint8)
-                    out.append((chunks, digs))
-                    continue
-                out.append((chunks, dig8[r, :len(chunks)].copy()))
-            yield out
+    def manifest_segments(self, segments):
+        """The host-tiled path over resident ``(buf_d, nv)`` batches
+        (generator): each batch's cut list comes down before its digest
+        tiles are laid out on the host and dispatched, two round trips a
+        batch.  The mesh driver's exact fall-back for a shard whose pool
+        overflowed; yields each batch's per-row results in order."""
+        for buf_d, nv in segments:
+            per_row = self.scan_select_collect(
+                self.scan_select_dispatch(buf_d, nv), buf_d, nv)
+            yield self.digest_collect(
+                self.digest_dispatch(buf_d, per_row), per_row)
 
     def _ensure_mesh(self):
-        """The mesh for the shard-mapped driver; defaults to one axis
-        over every local device (the engine's dedup mesh shape)."""
-        if self.mesh is None:
-            from jax.sharding import Mesh
-            self.mesh = Mesh(np.array(jax.devices()), (self.mesh_axis,))
-        return self.mesh
+        """The driver's mesh: the one given or attached, else one axis
+        over every local device (the engine's dedup mesh shape), taken
+        for the call and not kept: a device index met later still brings
+        its own (``TpuBackend._rides_mesh_of``)."""
+        if self.mesh is not None:
+            return self.mesh
+        from jax.sharding import Mesh
+        return Mesh(np.array(jax.devices()), (self.mesh_axis,))
 
     def _row_sharding(self):
         """Rows over the mesh axis: how a batch goes to the mesh driver."""
@@ -584,19 +421,20 @@ class DevicePipeline:
     def _mesh_program(self, buf_sh, nv_sh, emit_queries: bool,
                       lower: bool = False):
         """The shard-mapped manifest program over one sharded
-        ``(B, _HALO + padded)`` batch; the batch's shape and this
-        pipeline's selections name the program.  ``lower``: the
-        arguments are shapes, and the program is traced and lowered for
-        them, not run."""
+        ``(B, _HALO + padded)`` batch; the batch's shape, its sharding's
+        mesh and this pipeline's selections name the program.
+        ``lower``: the arguments are shapes, and the program is traced
+        and lowered for them, not run."""
         from .digest_pool import leaf_capacity
         from .manifest_device import scan_digest_batch_pool_mesh, tier_plan
 
         p = self.params
-        bs = int(buf_sh.shape[0]) // int(self.mesh.devices.size)
+        mesh = buf_sh.sharding.mesh
+        bs = int(buf_sh.shape[0]) // int(mesh.devices.size)
         padded = int(buf_sh.shape[1]) - _HALO
         s_cap, l_cap, cut_cap = self._caps(padded)
         return scan_digest_batch_pool_mesh(
-            buf_sh, nv_sh, mesh=self.mesh, axis=self.mesh_axis,
+            buf_sh, nv_sh, mesh=mesh, axis=self.mesh_axis,
             min_size=p.min_size, desired_size=p.desired_size,
             max_size=p.max_size, mask_s=p.mask_s, mask_l=p.mask_l,
             s_cap=s_cap, l_cap=l_cap, cut_cap=cut_cap, fused=self.fused,
@@ -607,9 +445,9 @@ class DevicePipeline:
 
     def manifest_segments_mesh(self, segments, strict_overflow: bool = False,
                                window: int = 4, dedup=None):
-        """Multi-device pipelined driver (generator): the zero-round-trip
-        manifest of :meth:`manifest_segments_device`, data-parallel over
-        the row axis with ``shard_map``.
+        """The pipelined batch driver (generator): a zero-round-trip
+        manifest, data-parallel over the row axis with ``shard_map`` (on
+        one chip, a mesh of one device).
 
         Each batch is padded to a row multiple of the mesh size with
         zero rows (``nv=0`` rows produce no cuts), resharded ``P(axis)``,
@@ -617,9 +455,14 @@ class DevicePipeline:
         :func:`backuwup_tpu.ops.manifest_device.scan_digest_batch_pool_mesh`
         — per-shard leaf pools, per-shard tier cascades, and per-shard
         overflow flags, so a pool overflow re-runs ONLY the affected
-        shard's rows on the host-tiled path.  ``window`` bounds batches in
+        shard's rows on the host-tiled path.  The only downloads are the
+        packed cuts + digest accumulator, whose async copies overlap
+        later batches' compute.  ``window`` bounds batches in
         flight; per-device bytes in flight are tracked against
         ``bkw_mesh_hbm_highwater_bytes`` and ``mesh_hbm_high_water``.
+        A row whose sparse candidate capacity overflowed re-chunks on
+        the CPU oracle; ``strict_overflow`` turns either fall-back into
+        an error (a parity test must not compare oracle with oracle).
 
         With ``dedup`` (a ``MeshDedupIndex``) each batch's digest
         accumulator is handed to the sharded dedup table ON DEVICE
@@ -629,21 +472,10 @@ class DevicePipeline:
         batch's insert) or ``None`` when the device could not classify
         the row (shard fallback, candidate overflow, lost lanes);
         ``MeshDedupIndex.resolve_hints`` turns the raw flags into final
-        dup hints.  Without ``dedup`` it yields plain rows, bit-identical
-        to the single-device driver.
+        dup hints.  Without ``dedup`` it yields plain rows.
         """
-        if not self.pool_digest:
-            # parity ladder: no mesh twin for the class-tile digest —
-            # fall back to the single-device driver (flags all None, the
-            # host authority classifies)
-            for rows in self.manifest_segments_device(
-                    segments, strict_overflow, window):
-                yield (rows, [None] * len(rows)) if dedup is not None \
-                    else rows
-            return
-
         sharding = self._row_sharding()
-        D = int(self.mesh.devices.size)
+        D = int(sharding.mesh.devices.size)
         it = iter(segments)
         pending: deque = deque()
         state = {"in_flight": 0}
@@ -812,15 +644,15 @@ class DevicePipeline:
                 groups.setdefault(_segment_bucket(n), []).append(i)
         return empty, tiny, long, groups
 
-    def _first_use_jobs(self, sizes, emit_queries: Optional[bool]) -> dict:
+    def _first_use_jobs(self, sizes, emit_queries: bool) -> dict:
         """{program key: a thunk that traces and lowers it} for every
         program one batch of streams of these ``sizes`` runs: a digest
         batch a leaf class of tiny files, two scans and a leaf pool a
         long stream, the manifest program a bucket shape
-        (``emit_queries`` names the mesh driver's; None: the caller's
-        driver is not the mesh).  Each lowers the jitted callable the
-        data goes through, with the same static arguments, dtypes and
-        shardings, so the batch's own call finds it compiled."""
+        (``emit_queries``: with the hand-off to a device index).  Each
+        lowers the jitted callable the data goes through, with the same
+        static arguments, dtypes and shardings, so the batch's own call
+        finds it compiled."""
         p = self.params
         spec = jax.ShapeDtypeStruct
         _empty, tiny, long, groups = self._route(sizes)
@@ -840,26 +672,23 @@ class DevicePipeline:
                         spec((), jnp.int32), spec((), jnp.uint32),
                         spec((), jnp.uint32),
                         k_cap=self.scanner._k_cap(padded))
-            if self.pool_digest:
-                step = -(-n // _POOL_STREAM_STEP) * _POOL_STREAM_STEP
-                jobs["pool", step] = lambda step=step: self._pool_program(
-                    spec((step + CHUNK_LEN,), jnp.uint8),
-                    *[spec((step // p.min_size + 1,), jnp.int32)] * 2,
-                    lower=True)
-        if emit_queries is not None and self.pool_digest:
-            sharding = self._row_sharding()
-            D = int(self.mesh.devices.size)
-            for padded, _part, B in self._batch_shapes(groups):
-                shape = (-(-B // D) * D, _HALO + padded)
-                jobs["mesh", shape, emit_queries] = \
-                    lambda shape=shape: self._mesh_program(
-                        spec(shape, jnp.uint8, sharding=sharding),
-                        spec(shape[:1], jnp.int32, sharding=sharding),
-                        emit_queries, lower=True)
+            step = -(-n // _POOL_STREAM_STEP) * _POOL_STREAM_STEP
+            jobs["pool", step] = lambda step=step: self._pool_program(
+                spec((step + CHUNK_LEN,), jnp.uint8),
+                *[spec((step // p.min_size + 1,), jnp.int32)] * 2,
+                lower=True)
+        sharding = self._row_sharding()
+        D = int(sharding.mesh.devices.size)
+        for padded, _part, B in self._batch_shapes(groups):
+            shape = (-(-B // D) * D, _HALO + padded)
+            jobs["mesh", shape, emit_queries] = \
+                lambda shape=shape: self._mesh_program(
+                    spec(shape, jnp.uint8, sharding=sharding),
+                    spec(shape[:1], jnp.int32, sharding=sharding),
+                    emit_queries, lower=True)
         return jobs
 
-    def compile_side_by_side(self, batches,
-                             emit_queries: Optional[bool]) -> None:
+    def compile_side_by_side(self, batches, emit_queries: bool) -> None:
         """Compile every program that batches of streams of these sizes
         (``batches``: a list of lists of lengths) need and this process
         has not compiled: traced and lowered here, one after the other
@@ -965,48 +794,32 @@ class DevicePipeline:
             batch_rows.append(part)
             yield buf, nv
 
-    def manifest_batch(self, streams) -> List[Tuple[List[tuple], np.ndarray]]:
+    def manifest_batch(self, streams, dedup=None):
         """Chunk + fingerprint a batch of independent streams, resident.
 
         Each stream's bytes are staged into HBM exactly once: streams are
-        bucketed by padded length, scanned+selected with one fused dispatch
-        per bucket, and chunk buffers are gathered HBM->HBM out of the same
-        resident batch before the batched BLAKE3.  Returns a
-        ``(chunks, digests)`` pair per stream, bit-identical to the CPU
-        oracle pipeline.
-        """
-        out: List[Optional[Tuple[List[tuple], np.ndarray]]] = [None] * len(streams)
-        groups = self._manifest_prepass(streams, out)
-        # stage resident batches lazily through the pipelined driver
-        # behind the 2-deep H2D staging ring: at most ~3 batches (each
-        # bounded by the dispatch budget) live in HBM at once, and batch
-        # N+1's upload overlaps batch N's scan->digest
-        batch_rows: deque = deque()
-        gen = self._bucketed_batches(streams, groups, batch_rows)
-        for results in self.manifest_segments_stream(gen):
-            part = batch_rows.popleft()
-            for r, i in enumerate(part):
-                out[i] = results[r]
-        return out
-
-    def manifest_batch_classified(self, streams, dedup):
-        """:meth:`manifest_batch` through the mesh driver with the
-        on-device dedup handoff: returns ``(out, flags)`` where
-        ``flags[i]`` is stream i's per-chunk device found-vector or
-        ``None`` when the device could not classify it (empty/tiny/long
-        streams, shard fallbacks, lost lanes — the host authority
-        resolves those via ``MeshDedupIndex.resolve_hints``).
+        bucketed by padded length and each bucketed batch runs the mesh
+        driver's one program (scan, select, leaf-pool digest, and with
+        ``dedup`` the on-device hand-off to the index); at most
+        ``window`` batches, each bounded by the dispatch budget, are in
+        flight.  Returns ``(out, flags)``: a ``(chunks, digests)`` pair a
+        stream, bit-identical to the CPU oracle pipeline, and stream i's
+        per-chunk device found-vector, or ``None`` where the device did
+        not classify it (no ``dedup``; empty/tiny/long streams, shard
+        fallbacks, lost lanes — the host authority resolves those via
+        ``MeshDedupIndex.resolve_hints``).
         """
         out: List[Optional[Tuple[List[tuple], np.ndarray]]] = [None] * len(streams)
         flags: List[Optional[np.ndarray]] = [None] * len(streams)
         groups = self._manifest_prepass(streams, out)
         batch_rows: deque = deque()
         gen = self._bucketed_batches(streams, groups, batch_rows)
-        for rows, rowflags in self.manifest_segments_mesh(gen, dedup=dedup):
-            part = batch_rows.popleft()
-            for r, i in enumerate(part):
+        for got in self.manifest_segments_mesh(gen, dedup=dedup):
+            rows, rowflags = got if dedup is not None else (got, None)
+            for r, i in enumerate(batch_rows.popleft()):
                 out[i] = rows[r]
-                flags[i] = rowflags[r]
+                if rowflags is not None:
+                    flags[i] = rowflags[r]
         return out, flags
 
     def _chunk_bucket(self, n_bytes: int) -> int:
@@ -1066,17 +879,16 @@ class DevicePipeline:
             np.uint8).reshape(len(chunks), 32)
 
     def digest_chunks(self, stream: jnp.ndarray, chunks: List[tuple]) -> np.ndarray:
-        """Gather + digest chunk spans of a resident stream; (N, 32) u8.
-
-        Chunks group into (B, L) size tiles so device work scales with
-        actual bytes, not worst-case chunk size.
+        """Gather + digest chunk spans of a resident stream; (N, 32) u8:
+        through the leaf pool, and where its tier cascade overflowed
+        through (B, L) size tiles, so device work scales with actual
+        bytes, not worst-case chunk size.
         """
         if not chunks:
             return np.zeros((0, 32), dtype=np.uint8)
-        if self.pool_digest:
-            pooled = self._digest_chunks_pool(stream, chunks)
-            if pooled is not None:
-                return pooled
+        pooled = self._digest_chunks_pool(stream, chunks)
+        if pooled is not None:
+            return pooled
         # slack so the fixed-span gathers never clamp (dynamic_slice clips
         # out-of-range starts, which would shift data)
         stream = jnp.pad(stream, (0, self.l_bucket * CHUNK_LEN))

@@ -8,8 +8,8 @@ words (1/4 byte per stream byte), so HBM sees the stream as bytes (the
 row, its aligned copy, the strip matrix written and read) and the words.
 Device time on one TPU v5e (PR 35's probe; PERF.md section 5 has the
 table): a ``(1, 31 + 64 MiB)`` row 3.0 ms, of it the aligned copy 1.1,
-the strip transpose 0.5 and the v2 kernel 1.4 (v1's kernel 1.6); 32 rows
-of 1 MiB 1.0 ms; 128 rows of 1 MiB 3.9 ms.
+the strip transpose 0.5 and the kernel 1.4; 32 rows of 1 MiB 1.0 ms;
+128 rows of 1 MiB 3.9 ms.
 
 Layout — the **strip decomposition**: the P-byte stream is split into
 128 contiguous strips of S = P/128 bytes; strip ``l`` occupies lane
@@ -57,7 +57,7 @@ def _fmix32_u32(x):
 
 
 def _words_shape(B: int, S: int, *inputs):
-    """Output type of the scan kernels: the candidate words vary over
+    """Output type of the scan kernel: the candidate words vary over
     whatever manual mesh axes the inputs vary over, which
     ``jax.shard_map`` (``check_vma=True``) wants stated on the
     ``pallas_call``; outside ``shard_map`` the set is empty."""
@@ -71,7 +71,7 @@ def _words(ref):
 
 
 def _strip_matrix(ext_b: jnp.ndarray):
-    """``(B, 31+P) u8`` rows as the strip matrix the kernels read:
+    """``(B, 31+P) u8`` rows as the strip matrix the kernel reads:
     ``body[b, r, l] = stream[b, l*S + r]`` (``(B, S, 128) u8``, one
     transpose) and ``halo0`` (``(B, 32, 128) u8``), the 32 bytes ahead of
     each strip: strip ``l-1``'s tail, and for strip 0 the spec's zero byte
@@ -86,9 +86,9 @@ def _strip_matrix(ext_b: jnp.ndarray):
 
 
 def _make_scan_kernel_u32(mask_s: int, mask_l: int, S: int, R: int):
-    """v2 kernel: four stream bytes a u32 word, from the load on.
+    """The scan kernel: four stream bytes a u32 word, from the load on.
 
-    Fed v1's ``u8`` strip matrix.  On the TPU four consecutive sublane
+    Fed the ``u8`` strip matrix.  On the TPU four consecutive sublane
     rows of one lane of a ``u8`` array share a 32-bit word (the
     ``(32, 128)`` byte tile is ``(8, 128)`` words), so a ``(4R, 128)``
     ``u8`` block in VMEM *is* the ``(R, 128)`` ``u32`` block of packed
@@ -98,8 +98,8 @@ def _make_scan_kernel_u32(mask_s: int, mask_l: int, S: int, R: int):
     planes, a ladder shift by s byte positions is a plane permutation
     ``k -> (k-s) mod 4`` plus a sublane shift of ``(s+k'-k)/4`` rows,
     and the 32:1 bit-pack ORs plane bits at ``4r'+k``.  Bit-identical to
-    v1/_pack_bits by construction; the byte order inside the word is
-    proven, not assumed, by the parity gate
+    ``_pack_bits`` by construction; the byte order inside the word is
+    proven, not assumed, by the check against the XLA scan
     (:func:`fused_scan_available`) on the live runtime before
     production use.
     """
@@ -160,11 +160,12 @@ def _make_scan_kernel_u32(mask_s: int, mask_l: int, S: int, R: int):
 def _fused_candidate_words_u32(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
                                mask_s: int, mask_l: int,
                                interpret: bool = False):
-    """v2 driver: v1's ``u8`` strip matrix, read as packed words (see
-    :func:`_make_scan_kernel_u32`).
+    """The kernel over the strip matrix of ``ext_b``, read as packed
+    words (see :func:`_make_scan_kernel_u32`): ``R`` strip rows a grid
+    step, the tile ahead's last 32 rows as halo, 32 positions a word out.
 
-    Same contract as :func:`fused_candidate_words` v1: position-major
-    candidate words, bit-identical to the XLA ``_pack_bits`` path.
+    Position-major candidate words, bit-identical to the XLA
+    ``_pack_bits`` path.
 
     Packing the words in XLA ahead of the kernel is what not to do on
     the v5e: a ``(…, 4)`` u8 -> u32 bitcast pads its minor dimension of 4
@@ -173,52 +174,6 @@ def _fused_candidate_words_u32(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
     lowered as a gather over an index vector, 0.164 s a 64 MiB row each
     where everything above takes 0.003 s (PR 35).
     """
-    return _scan_strips(_make_scan_kernel_u32, "cdc_scan_fused_v2", ext_b,
-                        nv_b, mask_s, mask_l, interpret)
-
-
-def _make_scan_kernel(mask_s: int, mask_l: int, S: int, R: int):
-    def kernel(nv_ref, halo0_ref, main_ref, prev_ref, wl_ref, ws_ref):
-        b = pl.program_id(0)
-        i = pl.program_id(1)
-        # tile halo: previous tile's last 32 strip rows; tile 0 uses the
-        # cross-strip halo input (real bytes of each strip's predecessor)
-        halo = jnp.where(i > 0, prev_ref[0], halo0_ref[0])
-        byts = jnp.concatenate([halo, main_ref[0]], axis=0)  # (R+32, 128) u8
-        a = _fmix32_u32(byts.astype(jnp.uint32))
-        # 32-tap windowed gear sum by log-doubling; shifts are sublane moves
-        for t in range(5):
-            s = 1 << t
-            shifted = jnp.concatenate(
-                [jnp.zeros((s, _LANES), dtype=jnp.uint32), a[:-s]], axis=0)
-            a = a + (shifted << jnp.uint32(s))
-        h = a[_HALO_ROWS:]  # (R, 128): main rows, taps all real (halo >= 31)
-        pos = (jax.lax.broadcasted_iota(jnp.int32, (R, _LANES), 1) * S
-               + i * R
-               + jax.lax.broadcasted_iota(jnp.int32, (R, _LANES), 0))
-        valid = pos < nv_ref[b]
-        cand_l = (((h & jnp.uint32(mask_l)) == jnp.uint32(0)) & valid)
-        cand_s = cand_l & ((h & jnp.uint32(mask_s)) == jnp.uint32(0))
-        # pack 32 strip rows into one u32 word row (little-endian bit t =
-        # row offset t), still lane-per-strip
-        cl = cand_l.astype(jnp.uint32).reshape(R // 32, 32, _LANES)
-        cs = cand_s.astype(jnp.uint32).reshape(R // 32, 32, _LANES)
-        wl = jnp.zeros((R // 32, _LANES), dtype=jnp.uint32)
-        ws = jnp.zeros((R // 32, _LANES), dtype=jnp.uint32)
-        for t in range(32):
-            wl = wl | (cl[:, t, :] << jnp.uint32(t))
-            ws = ws | (cs[:, t, :] << jnp.uint32(t))
-        wl_ref[0] = wl
-        ws_ref[0] = ws
-
-    return kernel
-
-
-def _scan_strips(make_kernel, name: str, ext_b, nv_b, mask_s: int,
-                 mask_l: int, interpret: bool = False):
-    """One scan kernel over the strip matrix of ``ext_b``: both variants
-    read the same ``u8`` blocks (``R`` strip rows a grid step, the tile
-    ahead's last 32 rows as halo) and write 32 positions a word."""
     B, n = ext_b.shape
     P = n - 31
     assert P % (128 * 32) == 0, "P must be a multiple of 4096"
@@ -249,11 +204,11 @@ def _scan_strips(make_kernel, name: str, ext_b, nv_b, mask_s: int,
         ],
     )
     wl, ws = pl.pallas_call(
-        make_kernel(mask_s, mask_l, S, R),
+        _make_scan_kernel_u32(mask_s, mask_l, S, R),
         out_shape=[_words_shape(B, S, body, nv)] * 2,
         grid_spec=grid_spec,
         interpret=interpret,
-        name=name,
+        name="cdc_scan_fused_v2",
     )(nv, halo0, body, body)
     # strip-major -> position-major: word (w, l) covers positions
     # l*S + w*32 ..+31, so transposing to (l, w) and flattening yields
@@ -263,58 +218,40 @@ def _scan_strips(make_kernel, name: str, ext_b, nv_b, mask_s: int,
     return wl, ws
 
 
-# selected kernel variant; decided ONCE by fused_scan_available()'s
-# parity ladder before any production trace (the dispatcher below reads
-# it at trace time, so flipping it after a trace would go unnoticed —
-# DevicePipeline/callers always probe first)
+# True once fused_scan_available() has checked the kernel on a TPU;
+# benchmark/deployment.py reads it for its ``scan_variant`` (ROADMAP D16)
 _V2_SELECTED = False
 
 
 def fused_candidate_words(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
                           mask_s: int, mask_l: int):
-    """``(B, 31+P) u8 -> ((B, P/32) u32, (B, P/32) u32)`` candidate words.
-
-    Trace-time dispatcher over the kernel variants: v2 (the strip
-    matrix read four bytes a word) when the parity ladder selected it on
-    this runtime, else v1 (a byte a lane element).  Both are bit-identical to the XLA path's
-    ``_pack_bits(cand)``; ``P`` must be a multiple of 4096.
+    """``(B, 31+P) u8 -> ((B, P/32) u32, (B, P/32) u32)`` candidate words,
+    bit-identical to the XLA path's ``_pack_bits(cand)``; ``P`` must be
+    a multiple of 4096.  ``scan_select_batch(fused=True)`` traces
+    through here; callers check :func:`fused_scan_available` first.
     """
-    # run the ladder if no caller has yet (lru_cached: once per process)
-    # so standalone probes/scripts measure the variant production uses
-    fused_scan_available()
-    if _V2_SELECTED:
-        return _fused_candidate_words_u32(ext_b, nv_b,
-                                          mask_s=mask_s, mask_l=mask_l)
-    return _fused_candidate_words_v1(ext_b, nv_b,
-                                     mask_s=mask_s, mask_l=mask_l)
+    return _fused_candidate_words_u32(ext_b, nv_b,
+                                      mask_s=mask_s, mask_l=mask_l)
 
 
-@functools.partial(jax.jit, static_argnames=("mask_s", "mask_l"))
-def _fused_candidate_words_v1(ext_b: jnp.ndarray, nv_b: jnp.ndarray, *,
-                              mask_s: int, mask_l: int):
-    """v1 driver: the strip matrix, expanded a byte a u32 in the kernel."""
-    return _scan_strips(_make_scan_kernel, "cdc_scan_fused_v1", ext_b, nv_b,
-                        mask_s, mask_l)
-
-
-def _check_variant(fn, name: str) -> None:
-    """Run ``fn`` (a candidate-words producer) against the XLA oracle on
-    the live runtime.  A kernel that does not lower raises the compiler's
-    own error; one that lowers and disagrees raises here."""
+def _check_kernel() -> None:
+    """Run the kernel against the XLA oracle on the live runtime.  A
+    kernel that does not lower raises the compiler's own error; one that
+    lowers and disagrees raises here."""
     import numpy as np
 
     from .cdc_tpu import _candidate_words, _hash_ext_fast
 
     rng = np.random.default_rng(7)
-    # 1 MiB rows = 4 grid steps for both variants (v1 R=2048 of
-    # S=8192 rows; v2 R32=512 of S32=2048): the probe must exercise
-    # the multi-tile prev-halo path, not just tile 0's halo0 branch
+    # 1 MiB rows = 4 grid steps (R32=512 of S32=2048 word rows): the
+    # probe must exercise the multi-tile prev-halo path, not just tile
+    # 0's halo0 branch
     P = 1 << 20
     ext = rng.integers(0, 256, (2, 31 + P), dtype=np.uint8)
     nv = np.array([P, P - 12345], dtype=np.int32)
     mask_s, mask_l = 0xFFF00000, 0xFFF80000
-    wl, ws = fn(jnp.asarray(ext), jnp.asarray(nv),
-                mask_s=mask_s, mask_l=mask_l)
+    wl, ws = _fused_candidate_words_u32(jnp.asarray(ext), jnp.asarray(nv),
+                                        mask_s=mask_s, mask_l=mask_l)
     for r in range(2):
         h = _hash_ext_fast(jnp.asarray(ext[r]))
         rl, rs = _candidate_words(h, jnp.int32(nv[r]),
@@ -322,32 +259,21 @@ def _check_variant(fn, name: str) -> None:
         if not (np.array_equal(np.asarray(wl[r]), np.asarray(rl))
                 and np.array_equal(np.asarray(ws[r]), np.asarray(rs))):
             raise RuntimeError(
-                f"fused scan kernel {name} disagrees with the XLA scan "
+                "fused scan kernel disagrees with the XLA scan "
                 f"on probe row {r}")
 
 
 @functools.lru_cache(maxsize=1)
 def fused_scan_available() -> bool:
-    """True when a fused scan kernel is selected: on a TPU, after it
-    lowered and matched the XLA oracle on this runtime (checked once, on
-    first use).
-
-    v2 (packed-u32) is the default variant, ``BKW_FUSED_V2=0`` asks for
-    v1.  On the TPU the chosen variant must pass: a kernel that does not
+    """True on a TPU, after the kernel lowered and matched the XLA oracle
+    on this runtime (checked once, on first use): a kernel that does not
     lower or does not match raises rather than handing the scan to a
     slower path behind a green run.  Off the TPU (the CPU test
-    configuration) no kernel is selected.
+    configuration) False: the XLA ladder is the only form there.
     """
-    import os
-
     global _V2_SELECTED
-    if os.environ.get("BKW_FUSED", "1") == "0":
-        return False
     if jax.devices()[0].platform != "tpu":
         return False
-    _V2_SELECTED = os.environ.get("BKW_FUSED_V2", "1") != "0"
-    if _V2_SELECTED:
-        _check_variant(_fused_candidate_words_u32, "v2")
-    else:
-        _check_variant(_fused_candidate_words_v1, "v1")
+    _check_kernel()
+    _V2_SELECTED = True
     return True

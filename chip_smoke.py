@@ -41,7 +41,6 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
-import os
 import shutil
 import sys
 import tempfile
@@ -206,19 +205,14 @@ async def served_path(args, work: Path, meter: CompileMeter) -> None:
         assert engine.backend.name == "tpu", engine.backend.name
         assert engine.device_dedup is not None, "no device dedup index"
         pipe = engine.backend.pipeline  # runs the kernel probes
-        from backuwup_tpu.ops import scan_fused
+        # the platform's one selection: on a TPU the Mosaic scan and leaf
+        # kernels (checked against their XLA forms, or the pipeline raised)
         kernels = {"fused": pipe.fused, "pallas_digest": pipe.pallas_digest,
-                   "pool_digest": pipe.pool_digest,
-                   "scan_variant": ("v2" if scan_fused._V2_SELECTED else "v1")
-                   if pipe.fused else "xla",
                    "mesh_devices": int(engine.device_dedup.mesh.devices.size)}
         emit(phase="start", seconds=round(time.monotonic() - t0, 3),
              kernels=kernels, **meter.since(base))
         if not args.rehearse:
-            assert pipe.fused and pipe.pallas_digest and pipe.pool_digest, \
-                kernels
-            want = "v1" if os.environ.get("BKW_FUSED_V2", "1") == "0" else "v2"
-            assert kernels["scan_variant"] == want, kernels
+            assert pipe.fused and pipe.pallas_digest, kernels
 
         async def backup(label: str) -> dict:
             t0 = time.monotonic()
@@ -381,7 +375,7 @@ def mesh_path(args, work: Path, meter: CompileMeter) -> None:
         pipe = DevicePipeline(params, l_bucket=max(
             16, -(-params.max_size // 1024)), mesh=mesh)
         if not args.rehearse:
-            assert pipe.fused and pipe.pallas_digest and pipe.pool_digest
+            assert pipe.fused and pipe.pallas_digest
         table_devs = {s.device for s in dedup.sharded.keys.addressable_shards}
         batch_devs = set()
         rows_out, flags_out = [], []

@@ -58,6 +58,23 @@ def test_manifest_many_parity_large_batch(backends, rng=random.Random(6)):
                             tpu.manifest_many(streams))
 
 
+def test_plain_manifest_many_runs_the_mesh_program_on_every_device(
+        rng=random.Random(8)):
+    """A plain ``manifest_many`` (no device index) goes the one way a
+    pack batch goes to the chip: the bucketed streams run the mesh
+    program, a dispatch labelled on every device of the default mesh
+    (``conftest.py``: eight), and the manifests are the CPU oracle's."""
+    import jax
+
+    streams = [rng.randbytes(n) for n in (5000, 20_000, 50_000, 65_000)]
+    base = obs_profile.baseline()
+    got = TpuBackend(PARAMS).manifest_many(streams)
+    dev = obs_profile.report(base)["device_dispatches"]
+    assert sorted(dev, key=int) == [str(d) for d in range(jax.device_count())]
+    assert all(dev[d]["scan"] == 1 and dev[d]["digest"] == 1 for d in dev)
+    _assert_manifests_equal(CpuBackend(PARAMS).manifest_many(streams), got)
+
+
 def test_manifest_stream_matches_manifest(backends, rng=random.Random(7)):
     cpu, tpu = backends
     data = rng.randbytes(300_000)

@@ -1,7 +1,7 @@
 """The batched route at the shipped chunker constants, against the
 benchmark's own reference (ISSUE 34): ``DirPacker.pack`` ->
 ``TpuBackend(CDCParams()).manifest_many_classified`` ->
-``DevicePipeline.manifest_batch_classified`` with a tiered device index.
+``DevicePipeline.manifest_batch`` with a tiered device index.
 
 One small ``home_tree`` with every class of file the prepass knows
 (empty, at or under the minimum, bucketed by padded length, longer than
@@ -263,7 +263,7 @@ def first_batch(tmp_path_factory):
     backend.prepare_batches([sizes[:3], sizes[3:4], sizes[4:]], dedup)
     ahead = set(pl._RAN)
     base = obs_profile.baseline()
-    out, flags = pipe.manifest_batch_classified(streams, dedup)
+    out, flags = pipe.manifest_batch(streams, dedup)
     return {"ahead": ahead, "out": out, "streams": streams,
             "compiled": obs_profile.report(base)["compile_s"]}
 

@@ -11,7 +11,7 @@ says the chip's compiler accepts the program and how much device memory
 it plans, never that it is right or fast.
 
 What they have caught: every ``pallas_call`` under ``jax.shard_map``
-needs ``vma`` on its ``out_shape``; the scan v2 driver's ``(..., 4)`` u8
+needs ``vma`` on its ``out_shape``; a scan driver's ``(..., 4)`` u8
 -> u32 bitcast took 5-9 GB of temporaries at 8-64 MiB rows and did not
 fit the chip at 128 MiB.  What they did not catch, because a compile has
 no clock: the four stride-4 slices that replaced that bitcast fit, and
@@ -118,33 +118,29 @@ def _planned_bytes(compiled) -> int:
             + mem.temp_size_in_bytes)
 
 
-def _lower_scan(variant, rows, width, one_chip):
-    fn = {"v1": scan_fused._fused_candidate_words_v1,
-          "v2": scan_fused._fused_candidate_words_u32}[variant]
-    return fn.lower(
+def _lower_scan(rows, width, one_chip):
+    return scan_fused._fused_candidate_words_u32.lower(
         jax.ShapeDtypeStruct((rows, 31 + width), jnp.uint8, sharding=one_chip),
         jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip),
         mask_s=PARAMS.mask_s, mask_l=PARAMS.mask_l)
 
 
-@pytest.mark.parametrize("variant", ["v1", "v2"])
 @pytest.mark.parametrize("rows,width", [WIDE, MANY, CELL],
                          ids=["wide", "many", "cell"])
-def test_scan_kernel_compiles_at_dispatch_widths(one_chip, variant, rows,
-                                                 width):
+def test_scan_kernel_compiles_at_dispatch_widths(one_chip, rows, width):
     # the XLA-side strip prep must stay a small multiple of the batch
-    # (128 MiB): v2's bitcast form planned 9 GB here, then 16.5 GB
-    assert _temp_bytes(_lower_scan(variant, rows, width, one_chip)) < 1 * GiB
+    # (128 MiB): a bitcast form planned 9 GB here, then 16.5 GB
+    assert _temp_bytes(_lower_scan(rows, width, one_chip)) < 1 * GiB
 
 
 @pytest.mark.parametrize("rows,width", [WIDE, MANY, CELL],
                          ids=["wide", "many", "cell"])
 def test_scan_relayout_plans_no_gather_and_fits(one_chip, rows, width):
-    """The bytes reach the v2 kernel by copies and one transpose: no
+    """The bytes reach the scan kernel by copies and one transpose: no
     gather (what a stride-4 slice of a ``u8`` row becomes on the v5e), and
     arguments, outputs and temporaries together under 4 GiB (a ``(1, N)
     u8`` argument alone is laid out at four times its bytes)."""
-    compiled = _lower_scan("v2", rows, width, one_chip).compile()
+    compiled = _lower_scan(rows, width, one_chip).compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and " gather(" not in hlo
     assert _planned_bytes(compiled) < 4 * GiB
@@ -217,12 +213,11 @@ def test_pool_gather_plans_no_lane_loop_and_fits(one_chip, rows, width, halo):
 
 
 @pytest.mark.parametrize("n_dev", [1, 4])
-def test_mesh_manifest_compiles_with_the_pallas_kernels(meshes, monkeypatch,
-                                                        n_dev):
-    """The program the engine's default route runs: scan v2 + select +
-    leaf-pool digest with the Pallas leaf kernel, under ``shard_map``
-    with its varying-axes check on, handing dedup queries on."""
-    monkeypatch.setattr(scan_fused, "_V2_SELECTED", True)  # the TPU default
+def test_mesh_manifest_compiles_with_the_pallas_kernels(meshes, n_dev):
+    """The program the engine's one batch route runs: the scan kernel +
+    select + leaf-pool digest with the Pallas leaf kernel, under
+    ``shard_map`` with its varying-axes check on, handing dedup queries
+    on."""
     mesh = meshes[n_dev]
     rows, width = SMALLEST
     per_shard = rows // n_dev

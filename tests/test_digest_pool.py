@@ -1,10 +1,10 @@
 """Leaf-pool digest must be bit-identical to the BLAKE3 spec oracle.
 
-Covers the round-5 digest-stage redesign (`ops/digest_pool.py`): one flat
-leaf scan + tiered tree reduction replacing the ~12 per-class digest
-pipelines of `scan_digest_batch`.  The reference hashes chunks serially
-on the CPU (`dir_packer.rs:285-311`); bit-exact parity with the spec
-implementation is the correctness bar for both designs.
+Covers the batched route's digest stage (`ops/digest_pool.py`): one flat
+leaf scan + tiered tree reduction, where a stage of padded tiles a length
+class ran ~12 digest pipelines a batch.  The reference hashes chunks
+serially on the CPU (`dir_packer.rs:285-311`); bit-exact parity with the
+spec implementation is the correctness bar.
 """
 
 import numpy as np
@@ -14,13 +14,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from backuwup_tpu.ops import cdc_cpu
+from backuwup_tpu.ops import cdc_cpu, digest_pool
 from backuwup_tpu.ops.blake3_cpu import Blake3Numpy, blake3_hash
 from backuwup_tpu.ops.cdc_tpu import _HALO
 from backuwup_tpu.ops.digest_pool import (
     leaf_capacity,
     pool_digest,
-    pool_digest_available,
     tier_caps,
     tier_spans,
 )
@@ -300,15 +299,30 @@ def test_tier_plan_shapes():
 
 
 def test_pool_gate_runs():
-    # on the test runtime (CPU mesh) the XLA pool path must pass its gate
-    assert pool_digest_available(False) is True
+    # on the test runtime (CPU mesh) the XLA pool path must pass its
+    # check, which returns nothing and raises where it fails
+    assert digest_pool._pool_digest_probe(False) is None
 
 
-def test_scan_digest_batch_pool_matches_oracle():
+def test_pool_probe_that_raises_stops_the_pipeline(monkeypatch):
+    """The pool is the batched route's only digest: a probe that fails
+    stops ``DevicePipeline(...)`` with the probe's own error, on the CPU
+    configuration too, and not behind a green run on another digest."""
+    def probe(pallas):
+        raise RuntimeError("leaf-pool digest disagrees with the oracle")
+
+    monkeypatch.setattr(digest_pool, "_pool_digest_probe", probe)
+    with pytest.raises(RuntimeError, match="disagrees with the oracle"):
+        DevicePipeline(SMALL)
+
+
+@pytest.mark.parametrize("sizes", [
+    [65536, 30_000, 0, 1, 5000], [65536], [65536, 30_000, 0, 65536],
+    [1, 64, 1024]], ids=["mixed-5", "full-1", "mixed-4", "short-3"])
+def test_scan_digest_batch_pool_matches_oracle(sizes):
     P = 65536
     rng = np.random.default_rng(13)
-    rows = [rng.integers(0, 256, n, dtype=np.uint8).tobytes()
-            for n in (P, 30_000, 0, 1, 5000)]
+    rows = [rng.integers(0, 256, n, dtype=np.uint8).tobytes() for n in sizes]
     buf, nv = _stage_rows(rows, P)
     s_cap, l_cap, cut_cap = DevicePipeline(SMALL)._caps(P)
     packed, acc, ovf = scan_digest_batch_pool(

@@ -684,20 +684,18 @@ def test_repo_is_lint_clean():
 
 
 #: The whole environment surface of the package: operator and safety
-#: settings, and the four kernel switches ROADMAP D2 keeps until a cell
-#: has timed the parity ladders.  A new name here is a new option: it
-#: needs a product caller that passes more than one value.
+#: settings.  A new name here is a new option: it needs a product caller
+#: that passes more than one value.
 BKW_ENV_NAMES = {
     "BKW_FAULTS", "BKW_FSYNC", "BKW_JOURNAL", "BKW_STATUS_PORT",
     "BKW_TRACE_DIR",
-    "BKW_FUSED", "BKW_FUSED_V2", "BKW_PALLAS_DIGEST", "BKW_POOL_DIGEST",
 }
 
 
-def test_repo_environment_switches_are_the_kept_nine():
+def test_repo_environment_switches_are_the_kept_five():
     """Every ``BKW_*`` name in the package's sources (read from the
-    environment, or only mentioned) is one of the kept nine, and each of
-    the nine is still read: a quiet knob, or a dead one, fails here."""
+    environment, or only mentioned) is one of the kept five, and each of
+    the five is still read: a quiet knob, or a dead one, fails here."""
     import re
     pkg = REPO / "backuwup_tpu"
     named, read = set(), set()

@@ -1,19 +1,19 @@
-"""Zero-round-trip device manifest must be bit-identical to the oracle."""
+"""Zero-round-trip device manifest must be bit-identical to the oracle:
+the one batch driver (``DevicePipeline.manifest_segments_mesh``) on a
+mesh of one device, the deployment's shape on one chip, and of eight."""
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
 
+from backuwup_tpu.obs import profile
 from backuwup_tpu.ops import cdc_cpu
 from backuwup_tpu.ops.blake3_cpu import Blake3Numpy
 from backuwup_tpu.ops.cdc_tpu import _HALO
 from backuwup_tpu.ops.gear import CDCParams
-from backuwup_tpu.ops.manifest_device import (
-    class_caps,
-    class_leaf_sizes,
-    scan_digest_batch,
-)
 from backuwup_tpu.ops.pipeline import DevicePipeline
 
 SMALL = CDCParams.from_desired(4096)
@@ -34,49 +34,15 @@ def _stage(rows, P):
     return jnp.asarray(buf), nv
 
 
-def test_class_plan_sizes():
-    classes = class_leaf_sizes(SMALL)
-    assert classes[-1] == SMALL.max_size // 1024
-    caps = class_caps(SMALL, 1 << 20, 4)
-    assert len(caps) == len(classes)
-    assert all(c % 4 == 0 for c in caps)
-    assert caps[-1] > 0  # cascade terminus always has slots
+def _mesh(n):
+    return Mesh(np.array(jax.devices()[:n]), ("data",))
 
 
-@pytest.mark.parametrize("sizes", [
-    [65536], [65536, 30_000, 0, 65536], [1, 64, 1024]])
-def test_scan_digest_batch_matches_oracle(sizes):
-    P = 65536
-    rows = [np.random.default_rng(3 + i).integers(
-        0, 256, n, dtype=np.uint8).tobytes() for i, n in enumerate(sizes)]
-    buf, nv = _stage(rows, P)
-    pipe = DevicePipeline(SMALL)
-    s_cap, l_cap, cut_cap = pipe._caps(P)
-    classes = class_leaf_sizes(SMALL)
-    caps = class_caps(SMALL, len(rows) * P, len(rows))
-    packed, acc, ovf = scan_digest_batch(
-        buf, jnp.asarray(nv), min_size=SMALL.min_size,
-        desired_size=SMALL.desired_size, max_size=SMALL.max_size,
-        mask_s=SMALL.mask_s, mask_l=SMALL.mask_l,
-        s_cap=s_cap, l_cap=l_cap, cut_cap=cut_cap, fused=False,
-        classes=classes, caps=caps)
-    packed = np.asarray(packed)
-    acc = np.asarray(acc)
-    assert not np.asarray(ovf).any()
-    dig8 = np.ascontiguousarray(acc.astype("<u4")).view(np.uint8).reshape(
-        len(rows), cut_cap, 32)
-    for r, data in enumerate(rows):
-        ref_chunks, ref_digests = _oracle(data, SMALL)
-        assert packed[r, 0] == 0
-        n_cuts = int(packed[r, 1])
-        ends = packed[r, 2:2 + n_cuts].astype(np.int64)
-        offs = np.concatenate([[0], ends[:-1] + 1])
-        got = list(zip(offs.tolist(), (ends - offs + 1).tolist()))
-        assert got == ref_chunks
-        assert [bytes(d) for d in dig8[r, :n_cuts]] == ref_digests
-
-
-def test_manifest_segments_device_driver():
+@pytest.mark.parametrize("n_dev", [1, 8])
+def test_manifest_segments_mesh_driver(n_dev):
+    """The one batch driver on a mesh of one device (the deployment's
+    shape on one chip) and of eight: three batches through its window,
+    every row the oracle's."""
     P = 65536
     rng = np.random.default_rng(11)
     batches = []
@@ -86,10 +52,11 @@ def test_manifest_segments_device_driver():
                              dtype=np.uint8).tobytes() for _ in range(2)]
         rows_all.append(rows)
         batches.append(_stage(rows, P))
-    pipe = DevicePipeline(SMALL)
-    results = list(pipe.manifest_segments_device(iter(batches)))
+    pipe = DevicePipeline(SMALL, mesh=_mesh(n_dev))
+    results = list(pipe.manifest_segments_mesh(iter(batches)))
     assert len(results) == 3
     for rows, res in zip(rows_all, results):
+        assert len(res) == len(rows)  # the rows padded on to fill the mesh: gone
         for data, (chunks, digests) in zip(rows, res):
             ref_chunks, ref_digests = _oracle(data, SMALL)
             assert chunks == ref_chunks
@@ -100,20 +67,21 @@ def test_manifest_segments_device_driver():
     pytest.param(CDCParams(), {}, 8 << 20, id="ref-1m"),
     pytest.param(CDCParams.from_desired(64 << 10),
                  dict(l_bucket=256, b_bucket=512), 4 << 20, id="vm-64k")])
-def test_driver_is_exact_at_deployment_widths_without_the_oracle(
+def test_mesh_driver_is_exact_at_deployment_widths_without_the_oracle(
         params, kw, n):
-    """The zero-round-trip driver at the widths deployments run (the
-    shipped 256 KiB / 1 MiB / 3 MiB, and the VM-image profile's 64 KiB
-    average) over a stream with a repeated quarter.  ``strict_overflow``
-    turns every fall-back to the CPU oracle into an error, so the parity
-    below is the device path's own and not oracle against oracle."""
+    """The batch driver on a mesh of one device, at the widths
+    deployments run (the shipped 256 KiB / 1 MiB / 3 MiB, and the
+    VM-image profile's 64 KiB average) over a stream with a repeated
+    quarter.  ``strict_overflow`` turns every fall-back to the CPU oracle
+    into an error, so the parity below is the device path's own and not
+    oracle against oracle."""
     rng = np.random.default_rng(1234)
     d = rng.integers(0, 256, n, dtype=np.uint8)
     d[n // 2:n // 2 + n // 4] = d[:n // 4]
     data = d.tobytes()
     buf, nv = _stage([data], n)
-    pipe = DevicePipeline(params, **kw)
-    ((chunks, digests),), = pipe.manifest_segments_device(
+    pipe = DevicePipeline(params, mesh=_mesh(1), **kw)
+    ((chunks, digests),), = pipe.manifest_segments_mesh(
         [(buf, nv)], strict_overflow=True)
     ref_chunks, ref_digests = _oracle(data, params)
     assert chunks == ref_chunks
@@ -122,16 +90,25 @@ def test_driver_is_exact_at_deployment_widths_without_the_oracle(
     assert len({bytes(x) for x in digests}) == len(set(ref_digests))
 
 
-def test_class_overflow_falls_back():
-    # all-zero data chunks entirely at max size: the top class overflows
-    # its calibrated capacity once the batch is large enough, and the
-    # driver falls back to the host-tiled path with identical output
+def test_pool_overflow_reruns_the_shard_on_the_host_tiled_path():
+    # all-zero data chunks entirely at max size: the top tier overflows
+    # its capacity once the batch is large enough, and the driver
+    # re-runs the shard (on a mesh of one, the batch) on the host-tiled
+    # path with identical output
     P = 1 << 20
     data = b"\0" * P
     buf, nv = _stage([data], P)
-    pipe = DevicePipeline(SMALL)
-    (res,), = pipe.manifest_segments_device(iter([(buf, nv)]))
+    pipe = DevicePipeline(SMALL, mesh=_mesh(1))
+    base = profile.baseline()
+    (res,), = pipe.manifest_segments_mesh(iter([(buf, nv)]))
+    rep = profile.report(base)
+    # the mesh program's launch and the host-tiled path's scan
+    assert rep["dispatches"]["scan"] == 2
+    assert rep["device_dispatches"]["0"]["scan"] == 1
     chunks, digests = res
     ref_chunks, ref_digests = _oracle(data, SMALL)
     assert chunks == ref_chunks
     assert [bytes(d) for d in digests] == ref_digests
+    with pytest.raises(RuntimeError, match="pool capacity overflow"):
+        list(pipe.manifest_segments_mesh(iter([(buf, nv)]),
+                                         strict_overflow=True))

@@ -1,8 +1,8 @@
 """Mesh manifest plane: shard-mapped scan->digest must be bit-identical.
 
-Parity posture (ISSUE 12 / parity ladder): a mesh that mis-lowers loses
+Parity posture (ISSUE 12): a mesh that mis-lowers loses
 speed, never correctness — so every test here pins bit-exact equality
-against BOTH the single-device driver and the CPU oracle, across
+against BOTH the driver on a mesh of one device and the CPU oracle, across
 parameter sets and 1/2/8-device meshes (tests/conftest.py forces
 ``--xla_force_host_platform_device_count=8``).  The dispatch-contract
 tests hand-count launches per the obs/profile.py table: one shard_map
@@ -67,7 +67,7 @@ def test_mesh_matches_single_device_and_oracle(params, n_dev):
     rows = [rng.integers(0, 256, n, dtype=np.uint8).tobytes()
             for n in (65536, 30_000, 0, 65536)]
     buf, nv = _stage(rows, P)
-    single = list(DevicePipeline(params).manifest_segments_device(
+    single = list(DevicePipeline(params, mesh=_mesh(1)).manifest_segments_mesh(
         iter([(jnp.asarray(buf), nv)])))[0]
     pipe = DevicePipeline(params, mesh=_mesh(n_dev))
     (mesh_out,) = list(pipe.manifest_segments_mesh(iter([(buf, nv)])))
@@ -94,8 +94,6 @@ def test_mesh_per_shard_overflow_reruns_only_that_shard():
                           for _ in range(7)]
     buf, nv = _stage(rows, P)
     pipe = DevicePipeline(SMALL, mesh=_mesh(8))
-    if not pipe.pool_digest:
-        pytest.skip("leaf-pool digest unavailable on this runtime")
     base = profile.baseline()
     (out,) = list(pipe.manifest_segments_mesh(iter([(buf, nv)])))
     rep = profile.report(base)
@@ -121,8 +119,6 @@ def test_mesh_even_split_across_devices():
             for _ in range(16)]
     buf, nv = _stage(rows, P)
     pipe = DevicePipeline(SMALL, mesh=_mesh(8))
-    if not pipe.pool_digest:
-        pytest.skip("leaf-pool digest unavailable on this runtime")
     base = profile.baseline()
     list(pipe.manifest_segments_mesh(iter([(buf, nv)])))
     rep = profile.report(base)
@@ -155,8 +151,6 @@ def test_mesh_dedup_handoff_zero_host_roundtrips(tmp_path, monkeypatch):
     mesh = _mesh(8)
     dev = MeshDedupIndex(mesh, host)
     pipe = DevicePipeline(SMALL, mesh=mesh)
-    if not pipe.pool_digest:
-        pytest.skip("leaf-pool digest unavailable on this runtime")
 
     def _boom(_hashes):
         raise AssertionError("fingerprints crossed the host link")
@@ -197,7 +191,11 @@ def test_mesh_dedup_handoff_zero_host_roundtrips(tmp_path, monkeypatch):
 def test_manifest_many_classified_backend(tmp_path):
     """TpuBackend's fused manifest+classify over mixed stream shapes
     (empty / tiny / batched): hints must match the first-occurrence-new
-    rule on an empty index and be all-duplicate on a repeat call."""
+    rule on an empty index and be all-duplicate on a repeat call.  A
+    plain ``manifest_many`` goes first, on a backend no mesh was attached
+    to: the default mesh it ran on is not kept, so the index's own mesh
+    (four of the eight devices, not the default) is still taken and the
+    device decides."""
     from backuwup_tpu.ops.backend import TpuBackend
 
     rng = np.random.default_rng(41)
@@ -206,10 +204,15 @@ def test_manifest_many_classified_backend(tmp_path):
         rng.integers(0, 256, 20_000, dtype=np.uint8).tobytes()]
     keys = KeyManager.from_secret(b"\x07" * 32)
     host = BlobIndex(keys, tmp_path / "index")
-    dev = MeshDedupIndex(_mesh(8), host)
+    dev = MeshDedupIndex(_mesh(4), host)
     backend = TpuBackend(SMALL)
-    backend.attach_mesh(dev.mesh, dev.axis)
+    plain = backend.manifest_many(streams)
+    assert backend.pipeline.mesh is None
+    base = profile.baseline()
     manifests, hints = backend.manifest_many_classified(streams, dev)
+    assert backend.pipeline.mesh is dev.mesh
+    decided = profile.report(base)["batch"]["chunks"]["device_decided"]
+    assert decided == sum(len(m) for m in manifests[2:])
     refs = [r for m in manifests for r in m]
     assert len(hints) == len(refs)
     seen = set()
@@ -217,7 +220,6 @@ def test_manifest_many_classified_backend(tmp_path):
         assert hint == (ref.hash in seen)
         seen.add(ref.hash)
     # parity with the plain manifest path
-    plain = TpuBackend(SMALL).manifest_many(streams)
     assert [[(r.offset, r.length, r.hash) for r in m] for m in manifests] \
         == [[(r.offset, r.length, r.hash) for r in m] for m in plain]
     manifests2, hints2 = backend.manifest_many_classified(streams, dev)
@@ -229,6 +231,19 @@ def test_manifest_many_classified_backend(tmp_path):
     for m_idx, m in enumerate(manifests2):
         for _ in m:
             assert next(it2) == (m_idx != 1)
+
+
+def test_pipeline_names_the_benchmark_reads():
+    """``benchmark/deployment.py`` reads four names of the program for
+    its ``kernels`` line (ROADMAP D16): they stay readable, and on the
+    CPU configuration they say the XLA forms and the leaf pool."""
+    from backuwup_tpu.ops import scan_fused
+
+    pipe = DevicePipeline(SMALL)
+    assert pipe.fused is False
+    assert pipe.pallas_digest is False
+    assert pipe.pool_digest is True
+    assert scan_fused._V2_SELECTED is False  # true once a TPU checked it
 
 
 def test_nv_cache_is_lru():

@@ -1,14 +1,15 @@
-"""v2 (packed-u32) fused-scan kernel logic vs the XLA oracle.
+"""The fused-scan kernel's logic (``cdc_scan_fused_v2``, four bytes a
+u32 word) vs the XLA oracle.
 
-The Mosaic lowering itself can only be proven on TPU (the import-time
-parity ladder in ``scan_fused.fused_scan_available`` does that on the
-live runtime); here the kernel BODY runs in pallas interpret mode on
-CPU, which validates the plane-permutation ladder, halo plumbing, and
-bit-pack math that v2 reimplements, and the relayout ahead of it: v1's
-``u8`` strip matrix, read as words by ``pltpu.bitcast`` (PR 35; the
+The Mosaic lowering itself can only be proven on TPU
+(``scan_fused.fused_scan_available`` checks it there against the XLA scan
+on the live runtime); here the kernel BODY runs in pallas interpret mode
+on CPU, which validates the plane-permutation ladder, halo plumbing, and
+bit-pack math, and the relayout ahead of it: the ``u8`` strip matrix,
+read as words by ``pltpu.bitcast`` (PR 35; the
 interpreter gives the bitcast its documented meaning, four consecutive
-rows of a lane little-endian in one word, and the chip's parity gate
-holds the hardware to it).
+rows of a lane little-endian in one word, and the chip's check holds
+the hardware to it).
 """
 
 import numpy as np
